@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..keras.layers.attention import _layer_norm, _layer_norm_params
+from ..ops import dispatch
 from ..ops.attention import (flash_attention, fused_short_applicable,
                              fused_short_attention, masked_context)
 from ..ops.decode import (beam_generate, cached_attention,
@@ -259,7 +260,7 @@ class TransformerLM:
         """Causal attention for the prefill forward: the fused short-seq
         kernel when the shape qualifies (TPU, bucketed length <= 512), the
         flash path otherwise — the same cutover the training step uses."""
-        if fused_short_applicable(q.shape[-2], k.shape[-2], True):
+        if fused_short_applicable(q, k):
             return fused_short_attention(q, k, v, causal=True)
         return flash_attention(q, k, v, causal=True)
 
@@ -278,14 +279,18 @@ class TransformerLM:
         s = tokens.shape[1]
         x = params["embed"][tokens] + params["pos"][None, :s]
         kvs = []
-        for p in params["blocks"]:
-            holder = {}
+        # the params live on the estimator's mesh, so whatever runs this —
+        # generate() eagerly, the scheduler's jitted prefill — is a
+        # program over that mesh (ops/dispatch.py)
+        with dispatch.partitioned_over(self._graph.estimator.mesh):
+            for p in params["blocks"]:
+                holder = {}
 
-            def kv_fn(q, k, v, holder=holder):
-                holder["kv"] = (k, v)
-                return self._prefill_attn(q, k, v)
-            x = self._block(p, x, kv_fn)
-            kvs.append(holder["kv"])
+                def kv_fn(q, k, v, holder=holder):
+                    holder["kv"] = (k, v)
+                    return self._prefill_attn(q, k, v)
+                x = self._block(p, x, kv_fn)
+                kvs.append(holder["kv"])
         return kvs
 
     def init_slot_caches(self, slots: int):

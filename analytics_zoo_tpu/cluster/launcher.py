@@ -58,6 +58,40 @@ class PodLaunchError(RuntimeError):
         self.results = list(results)
 
 
+def require_chip_per_child(who: str, platform: str,
+                           num_children: int) -> None:
+    """Fail at once where child processes could only fail or hang.
+
+    A TPU chip belongs to one process at a time, and a JAX process on a TPU
+    host takes every chip of the host when its backend starts. Children
+    held to the CPU (``platform="cpu"``, or an inherited
+    ``JAX_PLATFORMS=cpu``) are always fine. Otherwise nothing in this
+    package hands a child a chip of its own, so more than one child cannot
+    work, and a single child cannot work while this process holds the chips
+    itself. One process drives all chips of a host (a mesh over
+    ``jax.devices()``); servers run as threads in it."""
+    resolved = (platform or os.environ.get("JAX_PLATFORMS", "")).split(",")[0]
+    if resolved == "cpu":
+        return
+    if num_children > 1:
+        raise PodLaunchError(
+            f"{who}: {num_children} worker processes on the "
+            f"{resolved or 'default'} platform would contend for the same "
+            f"chips — this launcher gives no worker a chip of its own. "
+            f"Pass platform='cpu' (simulation), or drive all chips of the "
+            f"host from one process.", [])
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        from jax._src import xla_bridge
+        if (xla_bridge.backends_are_initialized()
+                and jax.default_backend() == "tpu"):
+            raise PodLaunchError(
+                f"{who}: this process has initialised JAX on the TPU and "
+                f"holds the chip, so a worker process that needs it would "
+                f"fail or hang. Start workers before touching JAX here, or "
+                f"run the work in this process.", [])
+
+
 @dataclass
 class PodLauncher:
     """Spawn ``num_processes`` coordinated workers and wait for them.
@@ -65,8 +99,10 @@ class PodLauncher:
     Args:
       num_processes: worker count (``jax.process_count()`` inside workers).
       devices_per_process: if set, each worker gets that many *virtual CPU*
-        devices (simulation/CI); leave None on real TPU hosts.
+        devices (simulation/CI only — a count of CPU devices, not chips).
       platform: force a JAX platform inside workers ("cpu" for simulation).
+        On any other platform only one worker can run, and only while this
+        process stays off the chip: :func:`require_chip_per_child`.
       env: extra environment for workers.
       log_dir: where per-worker stdout/stderr logs go (tempdir default).
       fail_fast: on the first nonzero worker exit, terminate the rest.
@@ -94,6 +130,8 @@ class PodLauncher:
         """Run ``target`` ("module:function", called with ``*args``) in every
         worker; block until all exit. Raises :class:`PodLaunchError` if any
         worker fails (with log tails for diagnosis)."""
+        require_chip_per_child("PodLauncher", self.platform,
+                               self.num_processes)
         log_dir = self.log_dir or tempfile.mkdtemp(prefix="zoo_pod_")
         os.makedirs(log_dir, exist_ok=True)
         coord = f"127.0.0.1:{_free_port()}"

@@ -62,7 +62,7 @@ from ..common.utils import wall_clock
 from ..ops import alerts as ops_alerts
 from ..ops import events as ops_events
 from ..ops import incident as ops_incident
-from .launcher import WorkerResult, _free_port
+from .launcher import WorkerResult, _free_port, require_chip_per_child
 
 logger = logging.getLogger("analytics_zoo_tpu.cluster")
 
@@ -350,6 +350,8 @@ class ElasticSupervisor:
     spawn_grace_s: float = 60.0
 
     def run(self, timeout: Optional[float] = None) -> SupervisorResult:
+        require_chip_per_child("ElasticSupervisor", self.platform,
+                               self.num_processes)
         cfg = global_config()
         hb_s = (float(self.heartbeat_s) if self.heartbeat_s is not None
                 else float(cfg.get("cluster.heartbeat_s")))
@@ -609,6 +611,9 @@ class FleetSupervisor:
                  min_instances: int = 1, max_instances: int = 4,
                  slots: int = 1, scale_interval_s: Optional[float] = None,
                  ready_timeout_s: float = 60.0):
+        # instances are spawned processes that inherit this environment:
+        # on a TPU host each would claim every chip
+        require_chip_per_child("FleetSupervisor", "", int(max_instances))
         self.router = router
         self.root = root
         self.server_factory = server_factory

@@ -149,9 +149,6 @@ _global_config.register("data.worker_respawns", 2,
                         "replacement and resubmits the lost task; once "
                         "exhausted the consumer gets TransformWorkerError "
                         "promptly instead of hanging.")
-_global_config.register("version.check", False,
-                        "Warn on jax/jaxlib version skew at context init "
-                        "(reference: spark.analytics.zoo.versionCheck).")
 _global_config.register("data.prefetch", 2, "Device-feed prefetch depth.")
 _global_config.register("data.num_workers", 0,
                         "Default worker count for FeatureSet transforms "
@@ -188,11 +185,6 @@ _global_config.register("eval.async", True,
 _global_config.register("eval.predict_window", 2,
                         "Max in-flight predict dispatches before results "
                         "are fetched behind the dispatch frontier.")
-_global_config.register("compile.cache_dir", "",
-                        "Directory for JAX's persistent compilation cache "
-                        "('' = disabled). Warm processes skip XLA "
-                        "recompiles of programs compiled by ANY earlier "
-                        "process pointed at the same dir.")
 _global_config.register("metrics.enabled", True,
                         "Record into the process-global metrics registry "
                         "(common/metrics.py). False turns every counter/"

@@ -10,6 +10,7 @@ caches it process-wide, exactly as ``init_nncontext`` memoizes the SparkContext.
 from __future__ import annotations
 
 import logging
+import os
 import threading
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Sequence, Tuple
@@ -61,51 +62,41 @@ _context: Optional[ZooTpuContext] = None
 _cache_wired: bool = False
 
 
-def wire_compilation_cache() -> bool:
-    """Point JAX's persistent compilation cache at ``compile.cache_dir``.
+#: where the persistent compilation cache lives when nothing outside the
+#: program places it: one fixed directory inside the checkout (git-ignored).
+#: The directory is part of every cache key, so it is never built from a
+#: temp name, a pid or a time — a cache that moves never hits.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-    Idempotent; returns whether a cache dir is active. Called from context
-    init (training) and ``InferenceModel`` construction (serving — which
-    may never init a mesh context): a process restart then deserializes
-    yesterday's XLA programs from disk instead of recompiling, which turns
-    a multi-second serving cold-start into a file read. The min-size/
-    min-compile-time thresholds drop to zero so small serving programs are
-    cached too (JAX's defaults only persist big, slow compiles)."""
+
+def wire_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache is placed from
+    outside: JAX reads the variable itself and this program sets no
+    directory in code. Where it is not, the cache goes to
+    ``DEFAULT_COMPILE_CACHE_DIR``. Idempotent. Called from context init
+    (training) and ``InferenceModel`` construction (serving — which may
+    never init a mesh context): a process restart then deserializes
+    yesterday's XLA programs from disk instead of recompiling. The
+    min-size/min-compile-time thresholds drop to zero so small serving
+    programs are cached too (JAX's defaults only persist big, slow
+    compiles)."""
     global _cache_wired
-    cache_dir = global_config().get("compile.cache_dir")
-    if not cache_dir:
-        return False
-    if _cache_wired:
-        return True
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-    for flag, val in (("jax_persistent_cache_min_entry_size_bytes", 0),
-                      ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(flag, val)
-        except AttributeError:  # older jax: threshold flags absent
-            pass
-    _cache_wired = True
-    logger.info("persistent compilation cache: %s", cache_dir)
-    return True
-
-
-def _version_check() -> None:
-    """Warn on jax/jaxlib version skew (the ``spark.analytics.zoo.
-    versionCheck`` analogue): a mismatched pair is the classic source of
-    silent miscompiles and ABI crashes on TPU hosts. Opt-in via the
-    ``version.check`` config key."""
-    if not global_config().get("version.check"):
-        return
-    try:
-        import jaxlib
-        jaxlib_version = getattr(jaxlib, "__version__", "unknown")
-    except ImportError:  # pragma: no cover - jaxlib always ships with jax
-        jaxlib_version = "unavailable"
-    if jaxlib_version != jax.__version__:
-        logger.warning(
-            "version.check: jax %s != jaxlib %s — upgrade the pair in "
-            "lockstep (see the JAX compatibility table)",
-            jax.__version__, jaxlib_version)
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not _cache_wired:
+        if not placed:
+            jax.config.update("jax_compilation_cache_dir",
+                              DEFAULT_COMPILE_CACHE_DIR)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        _cache_wired = True
+        logger.info("persistent compilation cache: %s (%s)",
+                    placed or DEFAULT_COMPILE_CACHE_DIR,
+                    "JAX_COMPILATION_CACHE_DIR" if placed else "in-tree")
+    return placed or DEFAULT_COMPILE_CACHE_DIR
 
 
 def _build_mesh(devices: Sequence[jax.Device],
@@ -163,7 +154,6 @@ def init_tpu_context(mesh_shape: Optional[Tuple[int, ...]] = None,
         if conf:
             for k, v in conf.items():
                 cfg.set(k, v)
-        _version_check()
         wire_compilation_cache()
         devices = jax.devices()
         mesh = _build_mesh(devices, mesh_shape, axis_names)

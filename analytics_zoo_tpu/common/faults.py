@@ -85,8 +85,8 @@ class Site:
 #: the lint too.
 REGISTRY: Dict[str, Site] = {
     "train.step": Site(
-        "estimator train loop, once per dispatched step — models a chip/"
-        "tunnel failure surfacing as a step exception (elastic retry)"),
+        "estimator train loop, once per dispatched step — models a chip "
+        "failure surfacing as a step exception (elastic retry)"),
     "train.preempt": Site(
         "estimator train loop — simulates SIGTERM preemption notice "
         "(fence writer, final snapshot, resumable marker)", kind="flag"),

@@ -27,6 +27,7 @@ jitted train step over a device mesh:
 """
 from __future__ import annotations
 
+import functools
 import json
 import logging
 import os
@@ -53,6 +54,7 @@ from ..feature.device_feed import (DeviceFeed, masked_eval_batches,
                                    shard_payload)
 from ..keras import metrics as metrics_mod
 from ..keras.optimizers import Optimizer
+from ..ops import dispatch as _kernel_dispatch
 from ..parallel import embedding as _embed_engine
 from ..parallel.mesh import (param_sharding, replicated, shard_batch,
                              vocab_sharding_rule)
@@ -98,6 +100,17 @@ _M_CKPT_FALLBACK = zoo_metrics.counter(
 #: step-phase attribution for the train loop (host_input / dispatch /
 #: execute / fetch / compile per step) — active only under profile.enabled
 _P_TRAIN = _profiler.StepProfiler("train")
+
+
+def _traced_for_own_mesh(method):
+    """Estimator entry points trace their step programs for ``self.mesh``:
+    say so, so that pallas kernels under a several-device mesh run per
+    shard instead of being refused by the partitioner (ops/dispatch.py)."""
+    @functools.wraps(method)
+    def scoped(self, *args, **kwargs):
+        with _kernel_dispatch.partitioned_over(self.mesh):
+            return method(self, *args, **kwargs)
+    return scoped
 
 
 def _profiled_feed(feed, prof):
@@ -375,6 +388,9 @@ class Estimator:
 
         self._train_step = None
         self._multi_step = None
+        #: whether the current step fn has been dispatched once, i.e. has
+        #: traced and compiled: failures before that are never retried
+        self._step_proven = False
         self._eval_step = None
         self._predict_step = None
         self._direct_eval_step = None
@@ -755,6 +771,7 @@ class Estimator:
 
     # -- train (the InternalDistriOptimizer.train equivalent) -----------------
 
+    @_traced_for_own_mesh
     def train(self, train_set: FeatureSet, batch_size: int,
               epochs: Optional[int] = None,
               end_trigger: Optional[Trigger] = None,
@@ -786,6 +803,7 @@ class Estimator:
         finally:
             restore_handler()
 
+    @_traced_for_own_mesh
     def train_online(self, train_set: FeatureSet, batch_size: int,
                      max_steps: Optional[int] = None,
                      end_trigger: Optional[Trigger] = None,
@@ -936,6 +954,7 @@ class Estimator:
             self._frozen_at_build = frozen_now
             self._train_step = self._build_train_step()
             self._multi_step = None  # closes over _train_step
+            self._step_proven = False
             # first dispatch of a fresh step fn is compile-dominated: the
             # profiler books it as phase=compile, not dispatch
             self._prof_fresh_dispatch = True
@@ -995,6 +1014,7 @@ class Estimator:
             if group > 1:
                 if self._multi_step is None:
                     self._multi_step = self._build_multi_step()
+                    self._step_proven = False
                     self._prof_fresh_dispatch = True
                     self._prof_cost_done = False
                     self._embed_step_bytes = None
@@ -1009,16 +1029,21 @@ class Estimator:
                 feed = DeviceFeed(host_it, self.mesh)
             epoch_iter = skip
             self._epoch_offset = epoch_iter
+            building = False
             prof = _profiler.enabled()
             step_source = (_profiled_feed(feed, _P_TRAIN) if prof
                            else iter(feed))
             try:
                 for x, y in step_source:
-                    # chaos site: a firing injection models a chip/tunnel
+                    # chaos site: a firing injection models a chip
                     # failure at step dispatch — caught by the elastic
                     # retry below exactly like a real one
                     faults.inject("train.step")
                     step_start = time.perf_counter()
+                    # the first dispatch of a fresh step fn traces and
+                    # compiles it: a failure there is a fault of the
+                    # program, which no checkpoint cures
+                    building = not self._step_proven
                     if group > 1:
                         g = jax.tree_util.tree_leaves(x)[0].shape[0]
                         with time_it("train_step"):
@@ -1038,6 +1063,8 @@ class Estimator:
                                 self.params, self.opt_state, self.model_state,
                                 step_rng, x, y)
                         losses = loss
+                    building = False
+                    self._step_proven = True
                     if prof:
                         now = time.perf_counter()
                         _P_TRAIN.add(
@@ -1145,6 +1172,11 @@ class Estimator:
                     state.epoch_finished = True
                     state.epoch += 1
             except Exception:
+                if building:
+                    logger.error(
+                        "train step failed to trace or compile; not a "
+                        "device failure, so not retried from a checkpoint")
+                    raise
                 # elasticity: retry from newest checkpoint (Topology.scala:1180-1262)
                 now = time.monotonic()
                 if now - last_failure > retry_window:
@@ -1221,6 +1253,7 @@ class Estimator:
 
     # -- evaluate (Estimator.evaluate / InternalDistriOptimizer eval) ---------
 
+    @_traced_for_own_mesh
     def evaluate(self, val_set: FeatureSet, batch_size: int) -> Dict[str, float]:
         """Pipelined evaluation: host gather/shard for batch N+1 runs on the
         DeviceFeed producer thread while the device computes batch N, and
@@ -1491,6 +1524,7 @@ class Estimator:
 
     # -- predict (TFNet/Predictable equivalent) -------------------------------
 
+    @_traced_for_own_mesh
     def predict(self, x, batch_size: int = 32):
         """Pipelined prediction: batches stream through the DeviceFeed and a
         bounded window of ``eval.predict_window`` dispatches stays in
@@ -1571,7 +1605,12 @@ class Estimator:
             "params": jax.tree_util.tree_map(np.asarray, self.params),
             "opt_state": jax.tree_util.tree_map(np.asarray, self.opt_state),
             "model_state": jax.tree_util.tree_map(np.asarray, self.model_state),
-            "meta": {"global_step": self.global_step, "epoch": self.epoch},
+            # the step rng is fold_in(root_rng, global_step): a resumed
+            # run draws the dropout masks of the uninterrupted one only if
+            # it also resumes the root key
+            "meta": {"global_step": self.global_step, "epoch": self.epoch,
+                     "root_rng": np.asarray(
+                         jax.random.key_data(self.root_rng))},
         }
         ts = getattr(self, "_active_train_set", None)
         if ts is not None and hasattr(ts, "data_state"):
@@ -1819,6 +1858,13 @@ class Estimator:
         # without this a stateless model burns an rng split rebuilding it,
         # diverging the resumed dropout stream from an uninterrupted run
         self._state_resolved = True
+        if "root_rng" in tree["meta"]:
+            data = jnp.asarray(tree["meta"]["root_rng"], jnp.uint32)
+            self.root_rng = (
+                jax.random.wrap_key_data(
+                    data, impl=jax.random.key_impl(self.root_rng))
+                if jnp.issubdtype(self.root_rng.dtype, jax.dtypes.prng_key)
+                else data)
         if "data_rng" in tree["meta"]:
             rng_json = bytes(np.asarray(tree["meta"]["data_rng"])).decode()
             self._restore_data = (rng_json,

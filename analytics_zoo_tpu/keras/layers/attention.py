@@ -109,9 +109,7 @@ class MultiHeadAttention(Layer):
         from ...ops.attention import (
             FUSED_SHORT_MAX_SEQ, fused_short_applicable,
             fused_short_attention)
-        if (self.use_flash
-                and fused_short_applicable(q.shape[-2], k.shape[-2],
-                                           self.causal)):
+        if self.use_flash and fused_short_applicable(q, k):
             # short sequences on TPU: single-kernel exact attention — the
             # probability matrix never touches HBM in either direction, and
             # attention dropout runs on the in-kernel PRNG (the BERT-base
@@ -129,7 +127,14 @@ class MultiHeadAttention(Layer):
                 # streaming attention with per-block dropout: never
                 # materializes the [q, kv] probability matrix (equals
                 # post-softmax dropout exactly — see blockwise_attention)
+                from ...ops import dispatch
                 from ...ops.attention import blockwise_attention
+                if dispatch.on_tpu():
+                    dispatch.note_fallback(
+                        "flash_attention",
+                        "the streaming kernels have no attention dropout; "
+                        f"training at seq > {FUSED_SHORT_MAX_SEQ} with "
+                        "attn_drop runs blockwise_attention")
                 ctx = blockwise_attention(
                     q, k, v, bias=bias, causal=self.causal,
                     dropout_rate=self.attn_drop, dropout_rng=drop_rng)

@@ -64,7 +64,7 @@ TRACE_WRAPPERS = {
     "jax.lax.cond", "jax.lax.switch", "jax.lax.map",
     "jax.lax.associative_scan",
     "jax.experimental.pjit.pjit",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map", "jax.experimental.shard_map.shard_map",
     "jax.experimental.checkify.checkify",
 }
 

@@ -1,8 +1,11 @@
 """On-demand compilation of the native components.
 
-No build step at install time: the first import compiles the .so next to the
-source with the system ``g++`` (cached by mtime), the way JAX itself JITs its
-kernels. Failure to build is non-fatal — callers fall back to pure Python.
+No build step at install time and no binary in the repository: the first
+use compiles ``native/<name>.cpp`` with the system ``g++`` into
+``native/lib<name>.so`` next to the source (git-ignored, rebuilt when the
+source is newer), the way JAX itself JITs its kernels. Where that cannot be
+done — no compiler, a read-only install — callers use their pure-Python
+implementation, and the log says which one is active and why.
 """
 from __future__ import annotations
 
@@ -10,7 +13,6 @@ import ctypes
 import logging
 import os
 import subprocess
-import tempfile
 import threading
 from typing import Optional
 
@@ -21,23 +23,26 @@ _lock = threading.Lock()
 _cache = {}
 
 
-def _build(src: str, out: str) -> bool:
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", out, src]
+def _build(src: str, out: str) -> Optional[str]:
+    """Compile ``src`` to ``out``; returns why it could not, else None.
+    Builds beside the target and renames, so a process that starts while
+    another one is building never loads half a library."""
+    tmp = f"{out[:-3]}.{os.getpid()}.tmp.so"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, src]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
     except (OSError, subprocess.TimeoutExpired) as e:
-        logger.warning("native build unavailable (%s); using Python fallback", e)
-        return False
+        return f"g++ unavailable ({e})"
     if proc.returncode != 0:
-        logger.warning("native build failed; using Python fallback:\n%s",
-                       proc.stderr[-2000:])
-        return False
-    return True
+        return f"g++ failed:\n{proc.stderr[-2000:]}"
+    os.replace(tmp, out)
+    return None
 
 
 def load_library(name: str) -> Optional[ctypes.CDLL]:
-    """Load (building if stale/missing) ``native/<name>.cpp`` as a CDLL.
-    Returns None when no compiler is available — callers must fall back."""
+    """Load (building if stale or missing) ``native/<name>.cpp`` as a CDLL.
+    Returns None when it cannot be built or loaded — callers then use their
+    pure-Python implementation. Either way one log line says which."""
     with _lock:
         if name in _cache:
             return _cache[name]
@@ -45,21 +50,22 @@ def load_library(name: str) -> Optional[ctypes.CDLL]:
         out = os.path.join(_DIR, f"lib{name}.so")
         if not os.path.exists(src):
             raise FileNotFoundError(src)
-        ok = True
-        if (not os.path.exists(out)
-                or os.path.getmtime(out) < os.path.getmtime(src)):
-            # build into the package dir when writable, else a temp dir
-            target = out
-            if not os.access(_DIR, os.W_OK):
-                target = os.path.join(tempfile.gettempdir(),
-                                      f"zoo_native_lib{name}.so")
-            ok = _build(src, target)
-            out = target
+        why = None
+        built = (not os.path.exists(out)
+                 or os.path.getmtime(out) < os.path.getmtime(src))
+        if built:
+            why = _build(src, out)
         lib = None
-        if ok:
+        if why is None:
             try:
                 lib = ctypes.CDLL(out)
             except OSError as e:
-                logger.warning("could not load %s (%s); Python fallback", out, e)
+                why = f"could not load {out} ({e})"
+        if lib is not None:
+            logger.info("native %s: %s %s", name,
+                        "built" if built else "loaded", out)
+        else:
+            logger.warning("native %s unavailable, pure-Python "
+                           "implementation in use: %s", name, why)
         _cache[name] = lib
         return lib

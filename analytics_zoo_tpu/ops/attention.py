@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import dispatch
+
 # v5e-tuned (scripts/sweep_flash_blocks.py, seq 4096 fwd+bwd train step,
 # dispatch-cancelled differenced timing): 512/1024 is the consistent best
 # across sweeps; q blocks >= 2048 overflow VMEM/registers in the exp2
@@ -286,10 +288,7 @@ def _vma_struct(shape, dtype, like):
     """ShapeDtypeStruct carrying the input's varying-manual-axes so
     pallas_call outputs satisfy shard_map's vma check (ulysses/ring run the
     kernel inside shard_map)."""
-    try:
-        vma = jax.typeof(like).vma
-    except Exception:
-        vma = None
+    vma = jax.typeof(like).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -301,7 +300,7 @@ def _flash_fwd_pallas(q, k, v, scale: float, causal: bool,
                       return_lse: bool = False):
     """``key_bias``: optional [batch, kv_len] additive per-key bias (the
     padding-mask form) applied inside the kernel. ``return_lse`` also
-    returns the per-row logsumexp ``[bh, q_len]`` (the backward kernels'
+    returns the per-row logsumexp ``[b, h, q_len]`` (the backward kernels'
     residual); only supported without ``key_bias``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -372,7 +371,7 @@ def _flash_fwd_pallas(q, k, v, scale: float, causal: bool,
     )(*operands)
     if return_lse:
         o, lse = out
-        return o.reshape(b, h, q_len, d), lse.reshape(bh, q_len)
+        return o.reshape(b, h, q_len, d), lse.reshape(b, h, q_len)
     return out.reshape(b, h, q_len, d)
 
 
@@ -886,24 +885,69 @@ def _fused_short_call(q, k, v, key_bias, scale, rate, seed, causal=False,
             dv.reshape(b, h, s, d))
 
 
+# operand layouts for dispatch.per_shard: [batch, heads, seq, head_dim]
+# tensors, [batch, heads, seq] logsumexp rows, [batch, kv] key bias
+_BHSD = (dispatch.BATCH, dispatch.HEADS, None, None)
+_BHS = (dispatch.BATCH, dispatch.HEADS, None)
+_BK = (dispatch.BATCH, None)
+
+
+def _kernel_ok(kernel: str, q) -> bool:
+    """On the TPU, and (under a several-device mesh) batch and heads
+    divide over it so the kernel can run per shard; a failed shard rule is
+    logged, since the reference then runs on the TPU."""
+    if not dispatch.on_tpu():
+        return False
+    rule = dispatch.shard_rule(q.shape[0], q.shape[1])
+    if rule is not None:
+        dispatch.note_fallback(kernel, rule)
+    return rule is None
+
+
+def _fused_short_sharded(q, k, v, key_bias, seed, scale, rate, causal,
+                         do=None):
+    """``_fused_short_call`` per shard (forward, or backward when ``do`` is
+    given). Each shard numbers its grid programs from 0, so the dropout
+    seed is moved on by the shard's index times its program count: no two
+    programs of one step draw the same mask."""
+    def call(seed_, q_, k_, v_, *rest):
+        rest = list(rest)
+        kb_ = rest.pop(0) if key_bias is not None else None
+        do_ = rest.pop(0) if do is not None else None
+        if rate > 0.0:
+            seed_ = seed_ + dispatch.shard_index() * (
+                q_.shape[0] * q_.shape[1])
+        return _fused_short_call(q_, k_, v_, kb_, scale, rate, seed_,
+                                 causal=causal, fwd=do is None, do=do_)
+
+    args, dims = [seed, q, k, v], [(), _BHSD, _BHSD, _BHSD]
+    if key_bias is not None:
+        args.append(key_bias)
+        dims.append(_BK)
+    if do is not None:
+        args.append(do)
+        dims.append(_BHSD)
+    return dispatch.per_shard(call, args, dims,
+                              _BHSD if do is None else (_BHSD,) * 3)
+
+
 # seed rides as a (traced) int32 array argument — it cannot be a
 # nondiff_argnum (those must be static) — and gets a None cotangent
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _fused_short(q, k, v, key_bias, seed, scale, rate, causal):
-    return _fused_short_call(q, k, v, key_bias, scale, rate, seed,
-                             causal=causal, fwd=True)
+    return _fused_short_sharded(q, k, v, key_bias, seed, scale, rate,
+                                causal)
 
 
 def _fused_short_fwd(q, k, v, key_bias, seed, scale, rate, causal):
-    out = _fused_short_call(q, k, v, key_bias, scale, rate, seed,
-                            causal=causal, fwd=True)
+    out = _fused_short_sharded(q, k, v, key_bias, seed, scale, rate, causal)
     return out, (q, k, v, key_bias, seed)
 
 
 def _fused_short_bwd(scale, rate, causal, residuals, g):
     q, k, v, key_bias, seed = residuals
-    dq, dk, dv = _fused_short_call(q, k, v, key_bias, scale, rate, seed,
-                                   causal=causal, fwd=False, do=g)
+    dq, dk, dv = _fused_short_sharded(q, k, v, key_bias, seed, scale, rate,
+                                      causal, do=g)
     dbias = None if key_bias is None else jnp.zeros_like(key_bias)
     return dq, dk, dv, dbias, None
 
@@ -942,24 +986,49 @@ def fused_short_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return _fused_short(q, k, v, key_bias, seed, scale, rate, causal)
 
 
-def fused_short_applicable(q_len: int, kv_len: int, causal: bool) -> bool:
-    del causal  # the kernel masks in-VMEM since the generative-serving PR
-    return (_on_tpu() and q_len == kv_len
-            and kv_len <= FUSED_SHORT_MAX_SEQ)
+def fused_short_applicable(q, k) -> bool:
+    """Self-attention over at most ``FUSED_SHORT_MAX_SEQ`` positions, on
+    the TPU (``q``, ``k``: ``[batch, heads, seq, head_dim]``)."""
+    return (q.shape[-2] == k.shape[-2]
+            and k.shape[-2] <= FUSED_SHORT_MAX_SEQ
+            and _kernel_ok("fused_short_attention", q))
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def _flash_fwd_sharded(q, k, v, scale, causal, q_block, kv_block,
+                       key_bias=None, return_lse=False):
+    """``_flash_fwd_pallas`` per shard of the mesh in scope."""
+    def call(q_, k_, v_, *bias):
+        return _flash_fwd_pallas(q_, k_, v_, scale, causal, q_block,
+                                 kv_block, key_bias=bias[0] if bias else None,
+                                 return_lse=return_lse)
+    with_bias = key_bias is not None
+    return dispatch.per_shard(
+        call, (q, k, v) + ((key_bias,) if with_bias else ()),
+        (_BHSD,) * 3 + ((_BK,) if with_bias else ()),
+        (_BHSD, _BHS) if return_lse else _BHSD)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, scale, causal, q_block, kv_block):
-    if _on_tpu():
-        return _flash_fwd_pallas(q, k, v, scale, causal, q_block, kv_block)
+def _flash_bwd_sharded(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
+                       glse=None):
+    """``_flash_bwd_pallas`` per shard of the mesh in scope."""
+    def call(q_, k_, v_, o_, lse_, g_, *glse_):
+        return _flash_bwd_pallas(q_, k_, v_, o_, lse_, g_, scale, causal,
+                                 q_block, kv_block,
+                                 glse=glse_[0] if glse_ else None)
+    with_glse = glse is not None
+    return dispatch.per_shard(
+        call, (q, k, v, o, lse, g) + ((glse,) if with_glse else ()),
+        (_BHSD,) * 4 + (_BHS, _BHSD) + ((_BHS,) if with_glse else ()),
+        (_BHSD,) * 3)
+
+
+def _flash_primal(q, k, v, scale, causal, q_block, kv_block):
+    if _kernel_ok("flash_attention", q):
+        return _flash_fwd_sharded(q, k, v, scale, causal, q_block, kv_block)
     return blockwise_attention(q, k, v, None, causal, scale, q_block, kv_block)
+
+
+_flash = jax.custom_vjp(_flash_primal, nondiff_argnums=(3, 4, 5, 6))
 
 
 def _lse_tile_ok(q_len: int, q_block: int) -> bool:
@@ -970,24 +1039,35 @@ def _lse_tile_ok(q_len: int, q_block: int) -> bool:
     return bq == q_len or bq % 128 == 0
 
 
+def _lse_tile_rule(q_len: int, q_block: int) -> str:
+    return (f"q tile {_largest_divisor_leq(q_len, q_block)} of q_len "
+            f"{q_len} is neither a multiple of 128 nor the whole row, so "
+            f"the logsumexp residual has no legal (1, 1, bq) tile")
+
+
 def _flash_fwd(q, k, v, scale, causal, q_block, kv_block):
-    if _on_tpu() and _lse_tile_ok(q.shape[-2], q_block):
-        out, lse = _flash_fwd_pallas(q, k, v, scale, causal, q_block,
-                                     kv_block, return_lse=True)
+    kernel = _kernel_ok("flash_attention", q)
+    if kernel and _lse_tile_ok(q.shape[-2], q_block):
+        out, lse = _flash_fwd_sharded(q, k, v, scale, causal, q_block,
+                                      kv_block, return_lse=True)
         return out, (q, k, v, out, lse)
-    out = (_flash_fwd_pallas(q, k, v, scale, causal, q_block, kv_block)
-           if _on_tpu() else
-           blockwise_attention(q, k, v, None, causal, scale, q_block,
-                               kv_block))
-    return out, (q, k, v, None, None)
+    if kernel:
+        # stated rule: the forward kernel still runs, the backward
+        # recomputes through blockwise_attention
+        dispatch.note_fallback(
+            "flash_attention backward",
+            _lse_tile_rule(q.shape[-2], q_block)
+            + "; backward differentiates blockwise_attention")
+    return _flash_primal(q, k, v, scale, causal, q_block, kv_block), (
+        q, k, v, None, None)
 
 
 def _flash_bwd(scale, causal, q_block, kv_block, residuals, g):
     q, k, v, o, lse = residuals
     if lse is not None:
-        return _flash_bwd_pallas(q, k, v, o, lse, g, scale, causal,
-                                 q_block, kv_block)
-    # off-TPU: recompute-based backward through the blockwise path
+        return _flash_bwd_sharded(q, k, v, o, lse, g, scale, causal,
+                                  q_block, kv_block)
+    # off-TPU, or no legal lse tile: recompute through the blockwise path
     _, vjp = jax.vjp(
         lambda q_, k_, v_: blockwise_attention(
             q_, k_, v_, None, causal, scale, q_block, kv_block), q, k, v)
@@ -1003,12 +1083,17 @@ def _flash_lse(q, k, v, scale, causal, q_block, kv_block):
 
 
 def _flash_lse_fwd(q, k, v, scale, causal, q_block, kv_block):
-    b, h, q_len, _ = q.shape
-    if _on_tpu() and _lse_tile_ok(q_len, q_block):
-        out, lse = _flash_fwd_pallas(q, k, v, scale, causal, q_block,
-                                     kv_block, return_lse=True)
-        return ((out, lse.reshape(b, h, q_len)),
-                (q, k, v, out, lse, True))
+    q_len = q.shape[-2]
+    kernel = _kernel_ok("flash_attention_lse", q)
+    if kernel and _lse_tile_ok(q_len, q_block):
+        out, lse = _flash_fwd_sharded(q, k, v, scale, causal, q_block,
+                                      kv_block, return_lse=True)
+        return (out, lse), (q, k, v, out, lse, True)
+    if kernel:
+        dispatch.note_fallback(
+            "flash_attention_lse",
+            _lse_tile_rule(q_len, q_block)
+            + "; forward and backward run blockwise_attention")
     out, lse = blockwise_attention(q, k, v, None, causal, scale, q_block,
                                    kv_block, return_lse=True)
     # the fallback backward recomputes via vjp: only q/k/v are needed, so
@@ -1020,8 +1105,8 @@ def _flash_lse_bwd(scale, causal, q_block, kv_block, residuals, gs):
     q, k, v, o, lse, used_pallas = residuals
     go, glse = gs
     if used_pallas:
-        return _flash_bwd_pallas(q, k, v, o, lse, go, scale, causal,
-                                 q_block, kv_block, glse=glse)
+        return _flash_bwd_sharded(q, k, v, o, lse, go, scale, causal,
+                                  q_block, kv_block, glse=glse)
     # off-TPU: autodiff through the blockwise lse path
     _, vjp = jax.vjp(
         lambda q_, k_, v_: blockwise_attention(
@@ -1054,14 +1139,20 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _flash_keybias(q, k, v, key_bias, scale, causal, q_block, kv_block):
-    if _on_tpu():
-        return _flash_fwd_pallas(q, k, v, scale, causal, q_block, kv_block,
-                                 key_bias=key_bias)
+    if _kernel_ok("flash_attention key-bias", q):
+        return _flash_fwd_sharded(q, k, v, scale, causal, q_block, kv_block,
+                                  key_bias=key_bias)
     return blockwise_attention(q, k, v, key_bias[:, None, None, :], causal,
                                scale, q_block, kv_block)
 
 
 def _flash_keybias_fwd(q, k, v, key_bias, scale, causal, q_block, kv_block):
+    if dispatch.on_tpu():
+        # stated rule: there is no backward kernel for the key-bias form
+        dispatch.note_fallback(
+            "flash_attention key-bias backward",
+            "the backward kernels take no bias operand; backward "
+            "differentiates blockwise_attention")
     return (_flash_keybias(q, k, v, key_bias, scale, causal, q_block,
                            kv_block), (q, k, v, key_bias))
 
@@ -1101,6 +1192,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 and _keybias_block(kv_len, kv_block) is not None:
             return _flash_keybias(q, k, v, bias[:, 0, 0, :], scale, causal,
                                   q_block, kv_block)
+        if dispatch.on_tpu():
+            dispatch.note_fallback(
+                "flash_attention",
+                f"bias of shape {tuple(bias.shape)} is not a [b, 1, 1, kv] "
+                f"key bias with a legal kv tile; blockwise_attention runs")
         return blockwise_attention(q, k, v, bias, causal, scale,
                                    q_block, kv_block)
     return _flash(q, k, v, scale, causal, q_block, kv_block)

@@ -18,19 +18,24 @@ single passes:
   ``[vocab, dim]`` materialization, never a one-hot matmul.
 * **int8** — :func:`quantize_table` / :func:`gather_pool_int8`: tables
   live symmetric-int8 in HBM using the ``ops/int8_dataflow`` delayed-
-  scaling recipe (same running-amax, same scale math), halving the bytes
-  the gather actually moves; rows dequantize in-kernel (TPU) or right at
-  the gather (fallback). Bound: ``|deq - f32| <= scale / 2`` per element,
+  scaling recipe (same running-amax, same scale math), a quarter of the
+  f32 table's bytes resident; rows dequantize in-kernel (TPU) or right at
+  the gather (lax path). Bound: ``|deq - f32| <= scale / 2`` per element,
   ``<= bag * scale / 2`` after sum pooling (:func:`int8_error_bound`).
 
 On TPU the per-row work runs as pallas kernels (scalar-prefetched ids
 driving double-buffered row DMAs out of HBM, VMEM accumulators for the
-pooling — see docs/embeddings.md "Fused kernels" for the tiling scheme).
-Everywhere else — and whenever the table shape misses the TPU lane tiling
-(dim % 128) — the SAME functions trace the exact lax ops of the historical
-unfused layers, in the same order, so the fused path is bit-identical
-(f32) to the reference by construction; tests/test_fused_embedding.py
-asserts that through real Estimator training, sharded and unsharded.
+pooling — see docs/embeddings.md "Fused kernels" for the tiling scheme)
+where the TPU compiler accepts them: float32 tables of dim exactly 128,
+int8 tables of dim % 128 (``_table_rule`` / ``_scatter_rule`` hold the
+rules, tests/test_tpu_compile.py compiles each kernel for a v5e). At any
+other shape on the TPU — which includes every width the repo's own recsys
+models use (NCF 20..64, Wide&Deep 8) — and everywhere off it, the SAME
+functions trace the exact lax ops of the historical unfused layers, in the
+same order, so the fused path is bit-identical (f32) to the reference by
+construction; tests/test_fused_embedding.py asserts that through real
+Estimator training, sharded and unsharded. A kernel that gives way to the
+lax path on the TPU says so once in the log (``ops/dispatch.py``).
 
 Everything here is gated by the ``kernels.fused_embedding`` config knob
 (docs/configuration.md); the unfused layer code stays in-tree as the
@@ -48,16 +53,27 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import dispatch
 from .int8_dataflow import (dequant_int8, next_amax, quant_int8,
                             scale_of_amax)
 
-#: rows gathered per pallas grid step (the scalar-prefetch block); clamped
-#: down to a divisor of the id count at call time.
+#: most rows gathered per pallas grid step; ``_gather_block`` clamps it
+#: down to a divisor of the output row count at call time.
 DEFAULT_GATHER_BLOCK = 256
 
-#: pallas scatter-add keeps the whole output shard in VMEM; above this
-#: many bytes the lax scatter (XLA's native s32 scatter-add) runs instead.
+#: pallas scatter-add keeps the incoming grads and the whole output shard
+#: in VMEM; above this many bytes together the lax scatter (XLA's native
+#: s32 scatter-add) runs instead.
 SCATTER_VMEM_BYTES = 8 * 1024 * 1024
+
+#: the ids of one call ride scalar prefetch, i.e. sit whole in the chip's
+#: 1 MiB of scalar memory next to the compiler's own scalars; above this
+#: many bytes of int32 ids the lax path runs instead.
+PREFETCH_IDS_BYTES = 512 * 1024
+
+#: int8 tables lie in HBM in (8, 128) tiles of 4-row-packed words, so the
+#: smallest block a DMA can address is 8 whole rows.
+INT8_ROW_TILE = 8
 
 
 def fused_enabled() -> bool:
@@ -68,38 +84,102 @@ def fused_enabled() -> bool:
     return bool(global_config().get("kernels.fused_embedding"))
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def _prefetch_rule(n_ids: int) -> Optional[str]:
+    if n_ids * 4 > PREFETCH_IDS_BYTES:
+        return (f"{n_ids} ids exceed the {PREFETCH_IDS_BYTES}-byte scalar "
+                f"prefetch budget")
+    return None
 
 
-def _lane_ok(table) -> bool:
-    """TPU kernels want the feature dim lane-aligned; anything else takes
-    the lax fallback (documented in docs/embeddings.md)."""
-    return table.ndim == 2 and table.shape[1] % 128 == 0
-
-
-def _use_pallas(table) -> bool:
-    return _on_tpu() and _lane_ok(table)
-
-
-def _largest_divisor_leq(n: int, cap: int) -> int:
-    for c in range(min(n, cap), 0, -1):
+def _gather_block(n: int) -> Optional[int]:
+    """Output rows per grid step: at most ``DEFAULT_GATHER_BLOCK``, a
+    divisor of ``n``, and (sublane tiling of the output block) a multiple
+    of 8 unless it is all of ``n``. None when no such block exists."""
+    if n <= DEFAULT_GATHER_BLOCK:
+        return n
+    for c in range(DEFAULT_GATHER_BLOCK, 7, -8):
         if n % c == 0:
             return c
-    return 1
+    return None
+
+
+def _table_rule(table, n_ids: int, bag: int = 1) -> Optional[str]:
+    """Why the row-DMA kernels cannot take ``table`` for ``n_ids`` ids in
+    bags of ``bag`` (None: they can). The rules are the TPU compiler's,
+    found by compiling for a v5e (tests/test_tpu_compile.py) —
+    docs/embeddings.md "Fused kernels"."""
+    rows, dim = table.shape[0], table.shape[-1]
+    if _gather_block(n_ids // bag) is None:
+        return (f"{n_ids // bag} output rows have no divisor <= "
+                f"{DEFAULT_GATHER_BLOCK} that is a multiple of 8")
+    if table.dtype == jnp.int8:
+        if table.ndim != 2 or dim % 128:
+            return (f"int8 table dim {dim} is not a multiple of 128 (TPU "
+                    f"lane tiling)")
+        if rows % INT8_ROW_TILE:
+            return (f"int8 table rows {rows} are not a multiple of "
+                    f"{INT8_ROW_TILE} (HBM tile of packed rows)")
+    elif table.dtype.itemsize != 4:
+        return (f"table dtype {table.dtype} is not 32-bit (packed rows "
+                f"cannot be moved or stored one at a time)")
+    elif table.ndim != 2 or dim != 128:
+        return (f"table dim {dim} is not 128 (one row must be one "
+                f"contiguous lane tile in HBM for a single-row DMA)")
+    return _prefetch_rule(n_ids)
+
+
+def _scatter_rule(g_flat, n_ids: int, num_rows: int) -> Optional[str]:
+    """Why the VMEM-resident scatter-add cannot run (None: it can)."""
+    dim = g_flat.shape[-1]
+    if g_flat.ndim != 2 or dim % 128:
+        return f"grad dim {dim} is not a multiple of 128 (TPU lane tiling)"
+    if g_flat.dtype.itemsize != 4:
+        return (f"grad dtype {g_flat.dtype} is not 32-bit (packed rows "
+                f"cannot be stored one at a time)")
+    resident = (g_flat.shape[0] + num_rows) * dim * 4
+    if resident > SCATTER_VMEM_BYTES:
+        return (f"grads + output shard are {resident} bytes, over the "
+                f"{SCATTER_VMEM_BYTES}-byte VMEM budget")
+    if dispatch.partitioned():
+        # outside the sharded-embedding engine (whose shard_map makes this
+        # per-shard code already) nothing says how a scatter-add splits
+        return ("scatter-add under a several-device mesh runs as a kernel "
+                "only inside the sharded-embedding engine's shard_map")
+    return _prefetch_rule(n_ids)
+
+
+def _use_pallas(kernel: str, rule: Optional[str]) -> bool:
+    """Kernel on the TPU when its rule holds (``rule`` is None); otherwise
+    the lax path, and on the TPU that is logged once with the rule."""
+    if not dispatch.on_tpu():
+        return False
+    if rule is not None:
+        dispatch.note_fallback(kernel, rule)
+    return rule is None
+
+
+def _gather_rule(table, n_ids: int, bag: int = 1) -> Optional[str]:
+    """``_table_rule`` for the ids one shard sees: under a several-device
+    mesh the output rows are split over the data axis (``_per_shard``)."""
+    return (dispatch.shard_rule(n_ids // bag)
+            or _table_rule(table, n_ids // dispatch.shards(dispatch.BATCH),
+                           bag))
+
+
+def _per_shard(call, table, ids, *scalars):
+    """``call(table, ids, *scalars)`` with the leading axis of ``ids`` (and
+    of the gathered rows) split over the data axis, the table whole."""
+    rows = (dispatch.BATCH,) + (None,) * (ids.ndim - 1)
+    return dispatch.per_shard(
+        call, (table, ids) + scalars,
+        ((None, None), rows) + ((),) * len(scalars), (dispatch.BATCH, None))
 
 
 def _vma_struct(shape, dtype, like):
     """ShapeDtypeStruct carrying the input's varying-manual-axes so
     pallas_call outputs satisfy shard_map's vma check (the sharded lookup
     runs these kernels inside shard_map)."""
-    try:
-        vma = jax.typeof(like).vma
-    except Exception:
-        vma = None
+    vma = jax.typeof(like).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -110,8 +190,9 @@ def _int_zeros(x):
 
 
 # ---------------------------------------------------------------------------
-# pallas TPU kernels (never traced off-TPU; ids ride scalar prefetch and
-# drive double-buffered per-row DMAs out of HBM)
+# pallas TPU kernels (ids ride scalar prefetch, flat, and drive
+# double-buffered per-row DMAs out of HBM). Off the TPU they are traced only
+# by tests/test_tpu_compile.py, which compiles each for a described v5e.
 # ---------------------------------------------------------------------------
 
 
@@ -157,19 +238,22 @@ def _gather_kernel(ids_ref, table_ref, out_ref, scratch_ref, sem_ref, *,
 
 def _gather_int8_kernel(ids_ref, table_ref, scale_ref, out_ref, scratch_ref,
                         sem_ref, *, block: int):
-    """int8 row gather with dequant-in-kernel: the DMA moves 1 byte per
-    element out of HBM (half the f32/bf16 bytes — the real roofline for
-    gather-bound steps); the ``q * scale`` upcast happens on the row
-    already sitting in VMEM."""
+    """int8 row gather with dequant-in-kernel. A packed int8 row cannot be
+    addressed alone in HBM, so each DMA moves the aligned
+    ``INT8_ROW_TILE``-row tile that holds the wanted row (1 KiB at dim
+    128, against 512 B for one f32 row) and the row is picked out of the
+    tile in VMEM, where the ``q * scale`` upcast also happens."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     nrows = table_ref.shape[0]
     base = pl.program_id(0) * block
+    tile = INT8_ROW_TILE
 
     def _dma(slot, j):
         row = jnp.clip(ids_ref[base + j], 0, nrows - 1)
-        return pltpu.make_async_copy(table_ref.at[pl.ds(row, 1), :],
+        start = pl.multiple_of((row // tile) * tile, tile)
+        return pltpu.make_async_copy(table_ref.at[pl.ds(start, tile), :],
                                      scratch_ref.at[slot],
                                      sem_ref.at[slot])
 
@@ -185,8 +269,11 @@ def _gather_int8_kernel(ids_ref, table_ref, scale_ref, out_ref, scratch_ref,
         _dma(slot, j).wait()
         row = ids_ref[base + j]
         ok = (row >= 0) & (row < nrows)
-        deq = scratch_ref[slot, 0].astype(jnp.float32) * scale_ref[0, 0]
-        out_ref[j, :] = jnp.where(ok, deq, jnp.zeros_like(deq))
+        rows = scratch_ref[slot].astype(jnp.float32)  # [tile, dim]
+        sub = lax.broadcasted_iota(jnp.int32, rows.shape, 0)
+        want = ok & (sub == jnp.clip(row, 0, nrows - 1) % tile)
+        out_ref[j, :] = jnp.sum(jnp.where(want, rows, 0.0),
+                                axis=0) * scale_ref[0, 0]
         return carry
 
     lax.fori_loop(0, block, _step, 0)
@@ -198,7 +285,9 @@ def _gather_pool_kernel(ids_ref, table_ref, out_ref, acc_ref, cnt_ref,
     """Fused gather + segment pooling: each output row accumulates its
     ``bag`` gathered rows in a VMEM f32 accumulator (padding ids masked,
     valid count kept for mean/sqrtn) and writes once — the unfused
-    ``[..., bag, dim]`` intermediate never exists."""
+    ``[..., bag, dim]`` intermediate never exists. The ``[n, bag]`` ids
+    arrive flattened: a 2-D scalar-prefetch operand is padded out to
+    whole (8, 128) words per row and overflows scalar memory."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -207,9 +296,7 @@ def _gather_pool_kernel(ids_ref, table_ref, out_ref, acc_ref, cnt_ref,
     total = block * bag
 
     def _dma(slot, j):
-        b = j // bag
-        k = j - b * bag
-        row = jnp.clip(ids_ref[base + b, k], 0, nrows - 1)
+        row = jnp.clip(ids_ref[base * bag + j], 0, nrows - 1)
         return pltpu.make_async_copy(table_ref.at[pl.ds(row, 1), :],
                                      scratch_ref.at[slot],
                                      sem_ref.at[slot])
@@ -226,7 +313,7 @@ def _gather_pool_kernel(ids_ref, table_ref, out_ref, acc_ref, cnt_ref,
             _dma((j + 1) % 2, j + 1).start()
 
         _dma(slot, j).wait()
-        row = ids_ref[base + b, k]
+        row = ids_ref[base * bag + j]
         ok = (row >= 0) & (row < nrows)
 
         @pl.when(k == 0)
@@ -282,13 +369,13 @@ def _gather_call(table, flat_ids, clip: bool):
     from jax.experimental.pallas import tpu as pltpu
 
     n, dim = flat_ids.shape[0], table.shape[1]
-    block = _largest_divisor_leq(n, DEFAULT_GATHER_BLOCK)
+    block = _gather_block(n)
     return pl.pallas_call(
         functools.partial(_gather_kernel, block=block, clip=clip),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n // block,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((block, dim), lambda i, *_: (i, 0),
                                    memory_space=pltpu.VMEM),
             scratch_shapes=[pltpu.VMEM((2, 1, dim), table.dtype),
@@ -302,18 +389,19 @@ def _gather_int8_call(qtable, scale, flat_ids):
     from jax.experimental.pallas import tpu as pltpu
 
     n, dim = flat_ids.shape[0], qtable.shape[1]
-    block = _largest_divisor_leq(n, DEFAULT_GATHER_BLOCK)
+    block = _gather_block(n)
     scale2 = jnp.asarray(scale, jnp.float32).reshape(1, 1)
     return pl.pallas_call(
         functools.partial(_gather_int8_kernel, block=block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n // block,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pltpu.SMEM)],
             out_specs=pl.BlockSpec((block, dim), lambda i, *_: (i, 0),
                                    memory_space=pltpu.VMEM),
-            scratch_shapes=[pltpu.VMEM((2, 1, dim), qtable.dtype),
+            scratch_shapes=[pltpu.VMEM((2, INT8_ROW_TILE, dim),
+                                       qtable.dtype),
                             pltpu.SemaphoreType.DMA((2,))]),
         out_shape=_vma_struct((n, dim), jnp.float32, qtable),
     )(flat_ids.astype(jnp.int32), qtable, scale2)
@@ -325,14 +413,14 @@ def _gather_pool_call(table, ids2d, combiner: str):
 
     n, bag = ids2d.shape
     dim = table.shape[1]
-    block = _largest_divisor_leq(n, DEFAULT_GATHER_BLOCK)
+    block = _gather_block(n)
     return pl.pallas_call(
         functools.partial(_gather_pool_kernel, block=block, bag=bag,
                           combiner=combiner),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n // block,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((block, dim), lambda i, *_: (i, 0),
                                    memory_space=pltpu.VMEM),
             scratch_shapes=[pltpu.VMEM((1, dim), jnp.float32),
@@ -340,7 +428,7 @@ def _gather_pool_call(table, ids2d, combiner: str):
                             pltpu.VMEM((2, 1, dim), table.dtype),
                             pltpu.SemaphoreType.DMA((2,))]),
         out_shape=_vma_struct((n, dim), table.dtype, table),
-    )(ids2d.astype(jnp.int32), table)
+    )(ids2d.astype(jnp.int32).reshape(-1), table)
 
 
 def _scatter_call(g_flat, rows, num_rows: int):
@@ -373,8 +461,9 @@ def gather_rows(table, flat_ids):
     """Fill-mode row gather (out-of-range -> zero row): the local-gather
     half of ``parallel.embedding._lookup_body`` after the id exchange.
     Not differentiated — the sharded lookup owns its backward."""
-    if _use_pallas(table):
-        return _gather_call(table, flat_ids, clip=False)
+    if _use_pallas("gather_rows", _gather_rule(table, flat_ids.size)):
+        return _per_shard(functools.partial(_gather_call, clip=False),
+                          table, flat_ids)
     return jnp.take(table, flat_ids, axis=0, mode="fill", fill_value=0)
 
 
@@ -383,7 +472,7 @@ def gather_rows_clip(table, ids):
     dense unsharded lookup. Differentiable: off-TPU it IS ``jnp.take``
     (native autodiff); on TPU a custom_vjp pairs the pallas gather with
     the same scatter-add XLA's take-transpose emits."""
-    if _use_pallas(table):
+    if _use_pallas("gather_rows_clip", _gather_rule(table, ids.size)):
         return _gather_clip_tpu(table, ids)
     return jnp.take(table, ids, axis=0)
 
@@ -402,8 +491,8 @@ def scatter_rows(g_flat, rows, num_rows):
     into the touched rows of the local shard block. The result IS the
     row-subset cotangent the sparse row updates consume — ``[rows_per_
     shard, dim]``, never a dense ``[vocab, dim]``; SENTINEL rows drop."""
-    if _on_tpu() and num_rows * g_flat.shape[-1] * 4 <= SCATTER_VMEM_BYTES \
-            and _lane_ok(g_flat):
+    if _use_pallas("scatter_rows",
+                   _scatter_rule(g_flat, rows.size, num_rows)):
         return _scatter_call(g_flat, rows, num_rows)
     return jnp.zeros((num_rows, g_flat.shape[-1]), g_flat.dtype).at[
         rows].add(g_flat, mode="drop")
@@ -440,20 +529,27 @@ def gather_pool(table, idx, combiner=None, mask_negative=True):
     excluded from mean/sqrtn counts) exactly like ``SparseEmbedding``;
     with it off, ids must be pre-validated (the ``_WideLinear`` contract).
     Differentiable both ways; pooled variants require ``idx.ndim >= 2``."""
-    if _use_pallas(table):
+    if _use_pallas("gather_pool", _gather_rule(
+            table, idx.size, 1 if combiner is None else idx.shape[-1])):
         return _gather_pool_tpu(table, idx, combiner, mask_negative)
     return _gather_pool_ref(table, idx, combiner, mask_negative)
 
 
 def gather_pool_int8(qtable, scale, idx, combiner=None, mask_negative=True):
     """:func:`gather_pool` over a :func:`quantize_table` table resident
-    int8 in HBM. Rows dequantize in-kernel on TPU (the DMA moves 1 byte
-    per element); the fallback dequantizes right at the gather. Forward
+    int8 in HBM. Rows dequantize in-kernel on TPU (each DMA moves the
+    8-row tile holding the row); the lax path dequantizes right at the
+    gather, and is the only one that pools. Forward
     only (quantized serving/eval path). Error vs the f32 table:
     ``<= scale/2`` per element, ``<= bag * scale/2`` after sum pooling."""
-    if _on_tpu() and _lane_ok(qtable) and combiner is None:
+    rule = (_gather_rule(qtable, idx.size) if combiner is None else
+            f"no int8 kernel pools (combiner={combiner!r}); rows are "
+            f"dequantized at a lax gather")
+    if _use_pallas("gather_pool_int8", rule):
         flat = idx.reshape(-1)
-        rows = _gather_int8_call(qtable, scale, flat)
+        rows = _per_shard(
+            lambda t, i, s: _gather_int8_call(t, s, i), qtable, flat,
+            jnp.asarray(scale, jnp.float32))
         out = rows.reshape(idx.shape + (qtable.shape[1],))
         if mask_negative:
             out = out * (idx >= 0).astype(out.dtype)[..., None]
@@ -526,7 +622,8 @@ def int8_error_bound(scale, bag_size: int = 1):
 
 @jax.custom_vjp
 def _gather_clip_tpu(table, ids):
-    rows = _gather_call(table, ids.reshape(-1), clip=True)
+    rows = _per_shard(functools.partial(_gather_call, clip=True), table,
+                      ids.reshape(-1))
     return rows.reshape(ids.shape + (table.shape[1],))
 
 
@@ -549,15 +646,17 @@ _gather_clip_tpu.defvjp(_gather_clip_tpu_fwd, _gather_clip_tpu_bwd)
 def _gather_pool_tpu(table, idx, combiner, mask_negative):
     if combiner is None:
         flat = idx.reshape(-1)
-        if mask_negative:
-            rows = _gather_call(table, flat, clip=False)  # fill == masked
-        else:
-            rows = _gather_call(table, flat, clip=True)
+        # fill == masked
+        rows = _per_shard(
+            functools.partial(_gather_call, clip=not mask_negative), table,
+            flat)
         return rows.reshape(idx.shape + (table.shape[1],))
     ids2d = idx.reshape(-1, idx.shape[-1])
     if not mask_negative:
         ids2d = jnp.clip(ids2d, 0, table.shape[0] - 1)
-    pooled = _gather_pool_call(table, ids2d, combiner)
+    pooled = _per_shard(
+        functools.partial(_gather_pool_call, combiner=combiner), table,
+        ids2d)
     return pooled.reshape(idx.shape[:-1] + (table.shape[1],))
 
 

@@ -246,7 +246,7 @@ class MoE(Layer):
         ex_mesh = _exchange_mesh(g, e, self.exchange)
         if ex_mesh is not None:
             from functools import partial
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             from .pipeline import note_collective_bytes
             tok_spec = P(EXPERT_AXIS, None, None, None)

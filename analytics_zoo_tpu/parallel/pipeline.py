@@ -101,28 +101,16 @@ def _ring_perm_rev(p: int):
 
 
 def _axis_size(axis_name: str) -> int:
-    """Static size of a bound mesh axis from inside a shard_map body.
-    ``lax.psum`` of a Python literal folds at trace time, so this is a
-    plain int — usable for perm tables and scan lengths — on every JAX
-    that can run shard_map (``lax.axis_size`` is newer than 0.4.x)."""
-    try:
-        return lax.axis_size(axis_name)
-    except AttributeError:
-        return lax.psum(1, axis_name)
+    """Static size of a bound mesh axis from inside a shard_map body: a
+    plain int, usable for perm tables and scan lengths."""
+    return lax.axis_size(axis_name)
 
 
 def _vary(a, axis_name: str):
     """Make ``a`` device-varying over ``axis_name`` — scan carries under
     shard_map must already carry the varying-axis type the ppermute
-    introduces (several JAX spellings, oldest fallback multiplies by a
-    varying zero)."""
-    try:
-        return lax.pcast(a, (axis_name,), to="varying")
-    except (AttributeError, TypeError):
-        try:
-            return lax.pvary(a, axis_name)  # older spelling
-        except AttributeError:
-            return a + jnp.zeros((), a.dtype) * lax.axis_index(axis_name)
+    introduces."""
+    return lax.pcast(a, (axis_name,), to="varying")
 
 
 def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
@@ -194,7 +182,7 @@ def gpipe(mesh, stage_fn: Callable, per_stage_params,
     """Global entry: returns ``(stacked_params, fn)`` where ``fn(params, x)``
     runs the pipelined forward over ``mesh[axis_name]`` and is fully
     differentiable (use inside a loss under ``jax.grad``)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_stages = len(per_stage_params)
@@ -372,7 +360,7 @@ def make_pipeline_loss(stage_fn: Callable, head_loss_fn: Callable, mesh,
     cotangent for ``x`` (so the embedding upstream of the pipelined trunk
     trains normally). Integer ``y`` gets a ``float0`` zero cotangent.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis_size = dict(zip(mesh.axis_names, mesh.devices.shape))[axis_name]

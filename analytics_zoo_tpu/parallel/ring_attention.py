@@ -119,7 +119,7 @@ def ring_self_attention(mesh: Mesh, q: jax.Array, k: jax.Array, v: jax.Array,
                         scale: Optional[float] = None) -> jax.Array:
     """Global entry: shards the seq axis of [b, h, s, d] over ``mesh['seq']``
     and runs the ring. Batch rides the ``data`` axis if present."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     batch_axis = "data" if "data" in mesh.axis_names else None
     spec = P(batch_axis, None, SEQ_AXIS, None)
@@ -195,7 +195,7 @@ def ring_context(mesh: Mesh, q: jax.Array, k_buf: jax.Array,
     sharded over ``mesh['seq']`` — the whole cache never materializes on
     one device. Drop-in for ``masked_context(q, k, v, visible, scale)``
     at documented float32 tolerance."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
 
     def body(qr, kc, vc, vis):

@@ -8,6 +8,10 @@ initializes.
 import os
 
 os.environ["JAX_PLATFORMS"] = "cpu"
+# the suite (and the children it starts, which inherit this) compiles
+# thousands of small CPU programs once each: keep JAX's persistent cache,
+# which the package otherwise always wires (common/context.py), out of it
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 xla_flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in xla_flags:
     os.environ["XLA_FLAGS"] = (

@@ -1,6 +1,6 @@
-"""Tests for bench.py's outage-proof harness pieces: the CPU-parity ratio
-mode (every workload must land a schema-valid record with no accelerator),
-resumable sharding (BENCH_STATE.json round-trip, --shard selection),
+"""Tests for bench.py's harness pieces: the CPU-parity ratio probes (asked
+for with --ratio, never selected automatically: without it a host with no
+TPU is a non-zero exit), resumable sharding (BENCH_STATE.json round-trip, --shard selection),
 baseline diffing, record validation, partial-record stashing, and the
 argument parser. bench.py is a script, not a package module — loaded here
 by file path."""
@@ -34,8 +34,8 @@ class TestRatioMode:
         if n in ("generate", "tp_decode") else n
         for n in sorted(bench._RATIO_PLAN)])
     def test_every_workload_lands_a_valid_record(self, name, ctx):
-        """The outage contract: with no accelerator at all, each workload
-        still produces one schema-valid ratio record. Impl results are
+        """Under --ratio each workload produces one schema-valid ratio
+        record with no accelerator at all. Impl results are
         memoized, so the parametrizations run one actual probe per impl
         key. The ``generate`` probe decodes 32 serial reference streams
         (minutes of wall time) and runs in the slow tier."""
@@ -312,10 +312,10 @@ class TestArgs:
                                   "--budget", "120.5"])
         assert args["one"] == "pipeline"  # alias resolved
         assert args["budget"] == 120.5
-        args = bench._parse_args(["--ratio", "--resume", "--full",
+        args = bench._parse_args(["--ratio", "--resume",
                                   "--write-baseline", "--shard", "1/4",
                                   "--no-gate", "eval"])
-        assert args["ratio"] and args["resume"] and args["full"]
+        assert args["ratio"] and args["resume"]
         assert args["write_baseline"]
         assert args["no_gate"]
         assert args["shard"] == (1, 4)
@@ -324,5 +324,25 @@ class TestArgs:
     def test_bad_input_rejected(self):
         with pytest.raises(SystemExit):
             bench._parse_args(["--wat"])
+        with pytest.raises(SystemExit):  # went with the automatic degrade
+            bench._parse_args(["--full"])
         with pytest.raises(SystemExit):
             bench._parse_args(["--shard", "4/4"])
+
+
+class TestNoTpuIsAnError:
+    def test_workload_without_tpu_exits_nonzero(self, tmp_path):
+        """No automatic degrade: a workload asked of a host where JAX finds
+        no TPU exits non-zero with a message, runs no CPU proxy under the
+        workload's name and writes no record."""
+        import subprocess
+        import sys as _sys
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        proc = subprocess.run([_sys.executable, _BENCH_PATH, "ncf"],
+                              capture_output=True, text=True, timeout=120,
+                              env=env, cwd=str(tmp_path))
+        assert proc.returncode == bench._NO_TPU_RC, proc.stderr[-800:]
+        assert "found no TPU" in proc.stderr
+        assert "--ratio" in proc.stderr
+        assert "cpu_ratio" not in proc.stdout
+        assert bench._MARKER not in proc.stdout

@@ -307,7 +307,7 @@ class TestInferenceModel:
 
     def test_savedmodel_stablehlo_roundtrip_serves_without_tf(self, ctx,
                                                               tmp_path):
-        # VERDICT r2 weak#7: the SERVED path must not need TF — export the
+        # the SERVED path must not need TF — export the
         # imported SavedModel to StableHLO buckets, then predict from the
         # artifact in a subprocess where importing tensorflow is a hard
         # error

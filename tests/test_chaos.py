@@ -492,7 +492,7 @@ class TestChaosSoak:
             # dies before publish (previous snapshot stays newest intact)
             faults.arm("ckpt.corrupt", at=5, budget=1)  # tear a published
             # snapshot (restore falls back past it if it is newest)
-            faults.arm("train.step", at=6, budget=1)    # chip/tunnel step
+            faults.arm("train.step", at=6, budget=1)    # chip step
             # failure — the elastic retry loop's bread and butter
             faults.arm("io.remote", p=0.05, budget=3, seed=13)  # flaky store
             faults.arm("feed.produce", at=18, budget=1)  # data plane dies

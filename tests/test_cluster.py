@@ -125,6 +125,38 @@ class TestBootstrapGuards:
             read_coordinator(str(tmp_path / "never.json"), timeout_s=0.3)
 
 
+class TestOneProcessForEachChip:
+    """A chip belongs to one process at a time and a worker on a TPU host
+    takes them all: launches that could only fail or hang there are refused
+    at once (``require_chip_per_child``); workers held to the CPU are fine."""
+
+    @pytest.mark.parametrize("platform,env,workers,refused", [
+        ("cpu", "", 4, None),             # simulation: always fine
+        ("", "cpu", 4, None),             # inherited JAX_PLATFORMS=cpu
+        ("", "", 2, "contend for the same chips"),
+        ("tpu", "cpu", 2, "contend for the same chips"),
+        ("", "", 1, None),                # one worker, parent off the chip
+    ])
+    def test_launch_guard(self, monkeypatch, platform, env, workers,
+                          refused):
+        from analytics_zoo_tpu.cluster.launcher import require_chip_per_child
+        monkeypatch.setenv("JAX_PLATFORMS", env)
+        if refused is None:
+            require_chip_per_child("launcher", platform, workers)
+        else:
+            with pytest.raises(PodLaunchError, match=refused):
+                require_chip_per_child("launcher", platform, workers)
+
+    def test_parent_holding_the_chip_is_refused(self, monkeypatch):
+        import jax
+        from analytics_zoo_tpu.cluster.launcher import require_chip_per_child
+        jax.devices()  # this process has a backend now ...
+        monkeypatch.setenv("JAX_PLATFORMS", "")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # a TPU's
+        with pytest.raises(PodLaunchError, match="holds the chip"):
+            require_chip_per_child("launcher", "", 1)
+
+
 class TestLauncherRestarts:
     @pytest.mark.pod(budget_s=45)
     def test_per_worker_retry_and_budget_exhaustion(self, tmp_path):

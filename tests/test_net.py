@@ -142,7 +142,7 @@ class TestOnnxMLP:
                                    atol=1e-4)
 
     def test_finetune_frozen_backbone(self):
-        """The VERDICT item-4 'done' bar: load ONNX MLP, freeze the
+        """An earlier review's 'done' bar: load ONNX MLP, freeze the
         backbone, fine-tune the head — backbone params must not move."""
         rs = np.random.RandomState(1)
         data, _ = _mlp_onnx(rs)

@@ -124,8 +124,8 @@ class TestElasticRetry:
         fs = FeatureSet.from_ndarrays(x, y)
         est.train(fs, batch_size=64, epochs=1)  # 4 its; snapshots at 2 and 4
 
-        # inject: the next dispatched step blows up ONCE (transient chip /
-        # tunnel failure), later steps succeed
+        # inject: the next dispatched step blows up ONCE (transient chip
+        # failure), later steps succeed
         real_step = est._train_step
         state = {"failed": False}
 
@@ -143,6 +143,34 @@ class TestElasticRetry:
         assert est.epoch == 3
         assert est.global_step == 8  # no steps lost or duplicated
         assert np.isfinite(out["loss_history"]).all()
+
+    def test_build_failure_is_not_retried(self, ctx, tmp_path):
+        """A step function that fails on its FIRST dispatch failed to trace
+        or compile: a fault of the program, which no checkpoint cures. It
+        surfaces at once instead of after ``failure.retry_times`` restores
+        (a TPU compiler refusal would otherwise be retried five times)."""
+        rs = np.random.RandomState(0)
+        x = rs.rand(128, 4).astype(np.float32)
+        y = rs.rand(128, 1).astype(np.float32)
+        est = Estimator(model=Sequential([Dense(4), Dense(1)]),
+                        loss_fn=objectives.get("mse"),
+                        optimizer=optimizers.SGD(0.01))
+        est.set_checkpoint(str(tmp_path), SeveralIteration(1))
+        fs = FeatureSet.from_ndarrays(x, y)
+        est.train(fs, batch_size=64, epochs=1)  # snapshots to restore from
+        assert est._snapshot_candidates()
+
+        calls = {"n": 0}
+
+        def refused_by_the_compiler(*args):
+            calls["n"] += 1
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+        est._build_train_step = lambda: refused_by_the_compiler
+        est._train_step = None  # a fresh step function is built and traced
+        with pytest.raises(RuntimeError, match="failed to compile"):
+            est.train(fs, batch_size=64, epochs=2)
+        assert calls["n"] == 1
 
     def test_retry_budget_exhausts(self, ctx, tmp_path):
         rs = np.random.RandomState(0)

@@ -150,21 +150,46 @@ class TestCompileWarmth:
         assert OutputQueue(src).query("w3", timeout_s=5.0) is not None
         assert im.compile_counts == {4: 1}  # first traffic: still warm
 
-    def test_compile_cache_dir_wiring(self, ctx, tmp_path):
+    @pytest.mark.parametrize("placed_from_outside", [True, False])
+    def test_compile_cache_dir_wiring(self, ctx, tmp_path, monkeypatch,
+                                      placed_from_outside):
+        """Both rules: with JAX_COMPILATION_CACHE_DIR set the program sets
+        no cache directory in code; without it the cache goes to the one
+        fixed in-tree path (never a temp name, pid or time)."""
         import jax
         from analytics_zoo_tpu.common import context as ctx_mod
-        from analytics_zoo_tpu.common.config import global_config
         from analytics_zoo_tpu.inference import InferenceModel
-        cfg = global_config()
-        cfg.set("compile.cache_dir", str(tmp_path / "xla-cache"))
+        outside = str(tmp_path / "placed-from-outside")
+        updates = []
+        real_update = jax.config.update
+
+        def spy(name, value):
+            updates.append(name)
+            real_update(name, value)
+
+        monkeypatch.setattr(ctx_mod, "_cache_wired", False)
+        monkeypatch.setattr(jax.config, "update", spy)
+        if placed_from_outside:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
         try:
             InferenceModel()  # construction wires the persistent cache
-            assert jax.config.jax_compilation_cache_dir == \
-                str(tmp_path / "xla-cache")
+            in_use = ctx_mod.wire_compilation_cache()
+            if placed_from_outside:
+                assert in_use == outside
+                assert "jax_compilation_cache_dir" not in updates
+                assert jax.config.jax_compilation_cache_dir == before
+            else:
+                repo = os.path.dirname(os.path.dirname(
+                    os.path.abspath(__file__)))
+                assert in_use == os.path.join(repo, ".jax_cache")
+                assert in_use == ctx_mod.DEFAULT_COMPILE_CACHE_DIR
+                assert jax.config.jax_compilation_cache_dir == in_use
+                assert updates.count("jax_compilation_cache_dir") == 1
         finally:
-            cfg.unset("compile.cache_dir")
-            ctx_mod._cache_wired = False
-            jax.config.update("jax_compilation_cache_dir", None)
+            real_update("jax_compilation_cache_dir", before)
 
 
 def _mean_model():

@@ -1,4 +1,4 @@
-"""Golden-validated pretrained import (VERDICT r2 item 4).
+"""Golden-validated pretrained import (an earlier review's bar).
 
 A torchvision-architecture ResNet-18 built in torch (the golden reference —
 torch computes the expected activations at test time, which is strictly

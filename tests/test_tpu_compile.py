@@ -1,0 +1,284 @@
+"""Every pallas kernel of the main path, compiled ahead of time for a TPU
+v5e that is described and not attached.
+
+Interpret mode and CPU parity tests cannot see what the chip's compiler
+refuses (a DMA slice off the HBM tiling, a scalar-prefetch operand padded
+past scalar memory); these compiles can, at the widths ``chip_smoke.py``
+runs, and cost no chip time. Nothing here RUNS: results are checked on the
+chip by ``chip_smoke.py``. Code that asks ``jax.default_backend()`` still
+sees the CPU in this process, so the ``_*_pallas`` / ``_*_call`` functions
+are compiled directly. Skipped where the installation cannot describe the
+topology.
+
+The last test drives ``chip_smoke.py``'s phase functions on the CPU at tiny
+sizes passed as arguments (the script itself has no size or device option).
+"""
+import importlib.util
+import os
+import pathlib
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+
+from analytics_zoo_tpu.ops import attention as A  # noqa: E402
+from analytics_zoo_tpu.ops import embedding_kernels as ek  # noqa: E402
+
+BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """``spec(shape, dtype)`` for one described v5e chip, with JAX's
+    persistent compilation cache off around the module: such compiles are
+    written to it but cannot be read back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e!r}")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    def spec(shape, dtype, sharding=one_chip):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    spec.devices = topo.devices  # the 2x2 host, for the four-chip cases
+    yield spec
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, *specs):
+    """Raises what the chip's compiler would raise; the kernel must be in
+    the program that comes out."""
+    text = jax.jit(fn).lower(*specs).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+# -- streaming flash attention ------------------------------------------------
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((4, 12, 2048, 64), BF16),   # head dim 64, fused single-pass backward
+    ((2, 8, 4096, 128), BF16),   # head dim 128, fused single-pass backward
+    ((1, 4, 8192, 128), BF16),   # K/V past VMEM: two-pass backward
+    ((8, 12, 128, 64), F32),     # TransformerLM.fit at GPT-2-small width
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else v.__name__)
+def test_flash_fwd_bwd(v5e, shape, dtype):
+    scale = shape[-1] ** -0.5
+    blocks = (A.DEFAULT_Q_BLOCK, A.DEFAULT_KV_BLOCK)
+    x = v5e(shape, dtype)
+    lse = v5e((shape[0] * shape[1], shape[2]), F32)
+
+    _compile(lambda q, k, v: A._flash_fwd_pallas(
+        q, k, v, scale, True, *blocks, return_lse=True), x, x, x)
+    _compile(lambda q, k, v, o, l, g: A._flash_bwd_pallas(
+        q, k, v, o, l, g, scale, True, *blocks), x, x, x, x, lse, x)
+
+
+def test_flash_fwd_key_bias(v5e):
+    """The padding-mask form (forward kernel only; its backward is the
+    stated blockwise rule)."""
+    x = v5e((4, 12, 1024, 64), BF16)
+    _compile(lambda q, k, v, b: A._flash_fwd_pallas(
+        q, k, v, 0.125, False, A.DEFAULT_Q_BLOCK, A.DEFAULT_KV_BLOCK,
+        key_bias=b), x, x, x, v5e((4, 1024), BF16))
+
+
+# -- fused short-sequence attention -------------------------------------------
+
+@pytest.mark.parametrize("shape,dtype,bias,rate,causal,bwd", [
+    # BERT-base fine-tune: padding mask + in-kernel dropout, both directions
+    ((32, 12, 128, 64), BF16, True, 0.1, False, True),
+    ((8, 12, 512, 64), BF16, False, 0.0, True, True),
+    # TransformerLM prefill (float32 params), one per end of the bucket
+    # range of capture/lm.py PREFILL_BUCKETS
+    ((1, 12, 16, 64), F32, False, 0.0, True, False),
+    ((1, 12, 32, 64), F32, False, 0.0, True, False),
+    ((1, 12, 128, 64), F32, False, 0.0, True, False),
+    ((1, 12, 512, 64), F32, False, 0.0, True, False),
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else None)
+def test_fused_short(v5e, shape, dtype, bias, rate, causal, bwd):
+    x = v5e(shape, dtype)
+    kb = v5e((shape[0], shape[2]), dtype)
+    seed = v5e((), I32)
+    scale = shape[-1] ** -0.5
+
+    def call(q, k, v, b, s, do=None):
+        return A._fused_short_call(q, k, v, b if bias else None, scale,
+                                   rate, s, causal=causal, fwd=do is None,
+                                   do=do)
+
+    _compile(call, x, x, x, kb, seed)
+    if bwd:
+        _compile(call, x, x, x, kb, seed, x)
+
+
+# -- four chips: kernels inside a partitioned program -------------------------
+
+@pytest.mark.parametrize("axis", ["data", "model"])
+def test_kernels_run_per_shard_on_four_chips(v5e, monkeypatch, axis):
+    """JAX refuses a Mosaic kernel in a program partitioned automatically
+    over several devices; under the owner's ``partitioned_over`` scope the
+    public entry points wrap it per shard (batch over ``data``, heads over
+    the tensor axis) and the four-chip program compiles with the kernel in
+    it. The backend question is steered here, in the test."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from analytics_zoo_tpu.ops import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    mesh = Mesh(np.asarray(v5e.devices), (axis,))
+    split = P(axis) if axis == "data" else P(None, axis)
+    x = v5e((8, 12, 128, 64), BF16, NamedSharding(mesh, split))
+
+    def step(q, k, v):
+        def loss(q, k, v):
+            short = A.fused_short_attention(q, k, v, causal=True)
+            return jnp.sum((short + A.flash_attention(q, k, v, causal=True)
+                            ).astype(F32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        # no scope: refused, loudly (a function of its own, since jit
+        # would hand the scoped call below this one's trace)
+        jax.jit(lambda q, k, v: step(q, k, v)).lower(x, x, x)
+    with dispatch.partitioned_over(mesh):
+        assert A.fused_short_applicable(x, x)
+        text = jax.jit(step).lower(x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= 4  # two kernels, both directions
+
+
+# -- embedding kernels --------------------------------------------------------
+
+_TABLE = (2 ** 20, 128)
+
+
+@pytest.mark.parametrize("kernel", ["gather", "gather_pool", "gather_int8",
+                                    "scatter"])
+def test_embedding_kernel(v5e, kernel):
+    if kernel == "gather":
+        assert ek._table_rule(v5e(_TABLE, F32), 8192) is None
+        _compile(lambda t, i: ek._gather_call(t, i, clip=False),
+                 v5e(_TABLE, F32), v5e((8192,), I32))
+    elif kernel == "gather_pool":
+        assert ek._table_rule(v5e(_TABLE, F32), 8192 * 4, bag=4) is None
+        _compile(lambda t, i: ek._gather_pool_call(t, i, "mean"),
+                 v5e(_TABLE, F32), v5e((8192, 4), I32))
+    elif kernel == "gather_int8":
+        assert ek._table_rule(v5e(_TABLE, I8), 8192) is None
+        _compile(ek._gather_int8_call, v5e(_TABLE, I8), v5e((), F32),
+                 v5e((8192,), I32))
+    else:
+        assert ek._scatter_rule(v5e((8192, 128), F32), 8192, 4096) is None
+        _compile(lambda g, r: ek._scatter_call(g, r, 4096),
+                 v5e((8192, 128), F32), v5e((8192,), I32))
+
+
+@pytest.mark.parametrize("table,n_ids,bag,why", [
+    ((1000, 64), 256, 1, "dim 64 is not 128"),          # NCF / Wide&Deep
+    ((1000, 256), 256, 1, "dim 256 is not 128"),        # row spans two tiles
+    ((1000, 128), 514, 1, "no divisor"),                # 2 x 257 rows
+    ((1000, 128), 2 ** 18, 1, "scalar prefetch budget"),
+])
+def test_embedding_rule_matches_compiler(v5e, table, n_ids, bag, why):
+    """Each shape ``_table_rule`` turns away is one the compiler refuses
+    (or that overflows scalar memory), so the rule is not merely cautious."""
+    t, ids = v5e(table, F32), v5e((n_ids,), I32)
+    assert why in ek._table_rule(t, n_ids, bag)
+    with pytest.raises(Exception):
+        jax.jit(lambda t, i: ek._gather_call(t, i, clip=True)).lower(
+            t, ids).compile()
+
+
+# -- which branch ran: the reason strings, on the CPU -------------------------
+
+def test_fallback_reasons_are_logged_once(monkeypatch, caplog):
+    """Where a kernel gives way to its reference ON the TPU, one log line
+    names the rule. The backend question is steered here, in the test;
+    nothing is lowered, so tracing the kernels on the CPU is fine."""
+    from analytics_zoo_tpu.ops import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    monkeypatch.setattr(dispatch, "_seen", set())
+    q = jnp.ones((1, 2, 640, 64), BF16)
+    table = jnp.ones((100, 64), F32)
+    ids = jnp.zeros((4, 2), I32)
+    with caplog.at_level("WARNING", logger="analytics_zoo_tpu.ops"):
+        for _ in range(2):
+            jax.make_jaxpr(jax.grad(lambda q: A.flash_attention(
+                q, q, q, q_block=320).astype(F32).sum()))(q)
+            jax.make_jaxpr(lambda t: ek.gather_pool(t, ids, "sum"))(table)
+    seen = dict(dispatch.fallbacks_seen())
+    assert "q tile 320 of q_len 640" in seen["flash_attention backward"]
+    assert "blockwise_attention" in seen["flash_attention backward"]
+    assert "table dim 64 is not 128" in seen["gather_pool"]
+    assert len(caplog.records) == 2  # once each, not once per trace
+
+
+def test_no_fallback_note_off_tpu(monkeypatch):
+    """Off the TPU the lax path is the implementation, not a fallback."""
+    from analytics_zoo_tpu.ops import dispatch
+    monkeypatch.setattr(dispatch, "_seen", set())
+    ek.gather_pool(jnp.ones((100, 64), F32), jnp.zeros((4, 2), I32), "sum")
+    assert dispatch.fallbacks_seen() == []
+
+
+# -- chip_smoke.py's phases at tiny sizes, on the CPU -------------------------
+
+def _load_chip_smoke():
+    path = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+_TINY_BERT = dict(vocab=64, hidden_size=32, n_block=1, n_head=8,
+                  max_position_len=32, intermediate_size=64)
+_TINY_LM = dict(vocab_size=64, hidden=32, n_block=1, n_head=8, max_len=64)
+
+
+def test_chip_smoke_one_chip_phases_on_cpu(ctx, tmp_path):
+    """kernels, train and serve at tiny sizes: the paths, arguments and
+    control flow of the script, with the reference branches the CPU takes."""
+    smoke = _load_chip_smoke()
+    checks = smoke.phase_kernels(dict(
+        flash=[((1, 2, 64, 16), "float32")],
+        flash_bias=((1, 2, 32, 16), "float32"),
+        short_bias=((2, 2, 16, 16), "float32"),
+        short_causal=[((1, 2, 16, 16), "float32")],
+        table=(64, 128), ids=16, bag=2, scatter_rows=8),
+        expect_pallas=False)
+    assert {c["branch"] for c in checks} == {"reference"}
+    losses = smoke.phase_train(
+        dict(bert=_TINY_BERT, seq=16, batch=8, steps=2, lr=1e-3),
+        str(tmp_path),
+        expect_pallas=False)
+    assert losses["epoch_2_resumed"] == losses["epoch_2_straight"]
+    served = smoke.phase_serve(
+        dict(lm=_TINY_LM, alphabet=8, fit_steps=2, fit_seq=16, fit_batch=8,
+             lr=1e-3, must_learn=False, prompt_lens=(17, 18), max_new=4,
+             slots=2),
+        str(tmp_path), expect_pallas=False)
+    assert len(served) == 2
+
+
+@pytest.mark.slow  # the four-chip rehearsal on virtual devices: ~25 s
+def test_chip_smoke_cross_chip_phases_on_cpu(ctx):
+    """``--chips 4``'s two comparisons over the suite's virtual devices:
+    meshes, sharding rules and the placement checks."""
+    smoke = _load_chip_smoke()
+    n = len(jax.devices())
+    smoke.phase_data_parallel(
+        dict(bert=_TINY_BERT, seq=16, batch=2 * n, steps=2, lr=1e-3),
+        expect_pallas=False)
+    smoke.phase_tensor_parallel(
+        dict(lm=dict(_TINY_LM, n_head=n), alphabet=8, fit_steps=2, seq=16,
+             batch=8, lr=1e-3, compare_steps=2, must_learn=False,
+             prompt_len=5, max_new=4), expect_pallas=False)
