@@ -1,0 +1,6 @@
+"""Device time inside the compiled prefill programs over device busy time."""
+from perfbench.harness import readers
+
+
+def read(ctx):
+    return readers.module_share_pct(ctx, readers.PREFILL)
