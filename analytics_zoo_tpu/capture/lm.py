@@ -166,35 +166,54 @@ class TransformerLM:
             0, 2, 1, 3)
 
     def _block(self, p, x, kv_fn):
-        h = _layer_norm(p["ln1"], x)
-        qkv = h @ p["qkv"]["kernel"] + p["qkv"]["bias"]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        ctx = kv_fn(self._split_heads(q), self._split_heads(k),
-                    self._split_heads(v))
-        b, _, s, _ = ctx.shape
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, self.hidden)
-        x = x + ctx @ p["attn_out"]["kernel"] + p["attn_out"]["bias"]
-        h = _layer_norm(p["ln2"], x)
-        h = jax.nn.gelu(h @ p["fc1"]["kernel"] + p["fc1"]["bias"])
-        return x + h @ p["fc2"]["kernel"] + p["fc2"]["bias"]
+        """One block. Scope names on the device (docs/observability.md):
+        ``layer_norm``, ``attention`` (projections and layout), ``ffn``;
+        what ``kv_fn`` runs names itself beside them (``attn_*`` kernels,
+        ``kv_write`` / ``kv_gather`` / ``kv_attend`` of ``ops/decode.py``)."""
+        with jax.named_scope("layer_norm"):
+            h = _layer_norm(p["ln1"], x)
+        with jax.named_scope("attention"):
+            qkv = h @ p["qkv"]["kernel"] + p["qkv"]["bias"]
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q, k, v = (self._split_heads(q), self._split_heads(k),
+                       self._split_heads(v))
+        ctx = kv_fn(q, k, v)
+        with jax.named_scope("attention"):
+            b, _, s, _ = ctx.shape
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, self.hidden)
+            x = x + ctx @ p["attn_out"]["kernel"] + p["attn_out"]["bias"]
+        with jax.named_scope("layer_norm"):
+            h = _layer_norm(p["ln2"], x)
+        with jax.named_scope("ffn"):
+            h = jax.nn.gelu(h @ p["fc1"]["kernel"] + p["fc1"]["bias"])
+            return x + h @ p["fc2"]["kernel"] + p["fc2"]["bias"]
+
+    def _head(self, params, x, last_only: bool):
+        """Final norm and the tied output product: logits of every
+        position, or of the last one only (decode)."""
+        with jax.named_scope("layer_norm"):
+            x = _layer_norm(params["ln_f"], x)
+        with jax.named_scope("head"):
+            return (x[:, -1] if last_only else x) @ params["embed"].T
 
     def _forward(self, params, tokens) -> jax.Array:
         tokens = tokens.astype(jnp.int32)
         s = tokens.shape[1]
-        x = params["embed"][tokens] + params["pos"][None, :s]
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens] + params["pos"][None, :s]
         for p in params["blocks"]:
             x = self._block(
                 p, x, lambda q, k, v: flash_attention(q, k, v, causal=True))
-        x = _layer_norm(params["ln_f"], x)
-        return x @ params["embed"].T  # tied logits [B, S, V]
+        return self._head(params, x, last_only=False)  # tied [B, S, V]
 
     def _loss(self, params, x, y=None):
         tokens = x.astype(jnp.int32)
         logits = self._forward(params, tokens[:, :-1])
         targets = tokens[:, 1:]
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
-        return jnp.mean(nll)
+        with jax.named_scope("loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
+            return jnp.mean(nll)
 
     # -- pipelined training (1F1B over the pipe mesh axis) --------------------
 
@@ -277,7 +296,8 @@ class TransformerLM:
         it token by token)."""
         tokens = tokens.astype(jnp.int32)
         s = tokens.shape[1]
-        x = params["embed"][tokens] + params["pos"][None, :s]
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens] + params["pos"][None, :s]
         kvs = []
         # the params live on the estimator's mesh, so whatever runs this —
         # generate() eagerly, the scheduler's jitted prefill — is a
@@ -308,8 +328,9 @@ class TransformerLM:
         and lengths are DATA, so the scheduler jits this once and never
         recompiles as streams join and leave."""
         tokens = jnp.asarray(tokens, jnp.int32)
-        x = (params["embed"][tokens][:, None]
-             + params["pos"][lengths][:, None])
+        with jax.named_scope("embed"):
+            x = (params["embed"][tokens][:, None]
+                 + params["pos"][lengths][:, None])
         new_caches = []
         for p, cache in zip(params["blocks"], caches):
             holder = {}
@@ -320,8 +341,7 @@ class TransformerLM:
                 return ctx
             x = self._block(p, x, kv_fn)
             new_caches.append(holder["cache"])
-        x = _layer_norm(params["ln_f"], x)
-        return (x[:, -1] @ params["embed"].T), new_caches
+        return self._head(params, x, last_only=True), new_caches
 
     # -- paged decode + speculative verify ------------------------------------
 
@@ -342,8 +362,9 @@ class TransformerLM:
         gathered buffer differs from the contiguous one only at
         masked-to-exact-zero positions)."""
         tokens = jnp.asarray(tokens, jnp.int32)
-        x = (params["embed"][tokens][:, None]
-             + params["pos"][lengths][:, None])
+        with jax.named_scope("embed"):
+            x = (params["embed"][tokens][:, None]
+                 + params["pos"][lengths][:, None])
         new_caches = []
         for p, cache in zip(params["blocks"], caches):
             holder = {}
@@ -354,8 +375,7 @@ class TransformerLM:
                 return ctx
             x = self._block(p, x, kv_fn)
             new_caches.append(holder["cache"])
-        x = _layer_norm(params["ln_f"], x)
-        return (x[:, -1] @ params["embed"].T), new_caches
+        return self._head(params, x, last_only=True), new_caches
 
     def verify_step(self, params, blocks, lengths, table, caches):
         """Speculative verify: feed ``blocks`` [S, T] (last committed token
@@ -367,8 +387,9 @@ class TransformerLM:
         blocks = jnp.asarray(blocks, jnp.int32)
         t = blocks.shape[1]
         positions = lengths[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
-        x = (params["embed"][blocks]
-             + params["pos"][jnp.minimum(positions, self.max_len - 1)])
+        with jax.named_scope("embed"):
+            x = (params["embed"][blocks]
+                 + params["pos"][jnp.minimum(positions, self.max_len - 1)])
         new_caches = []
         for p, cache in zip(params["blocks"], caches):
             holder = {}
@@ -379,8 +400,7 @@ class TransformerLM:
                 return ctx
             x = self._block(p, x, kv_fn)
             new_caches.append(holder["cache"])
-        x = _layer_norm(params["ln_f"], x)
-        return (x @ params["embed"].T), new_caches
+        return self._head(params, x, last_only=False), new_caches
 
     def prefill_kv_suffix(self, params, tokens, prefix_kvs, prefix_len):
         """Causal forward over a right-padded SUFFIX block [B, Tb] whose
@@ -392,8 +412,9 @@ class TransformerLM:
         burns a prefill forward."""
         tokens = tokens.astype(jnp.int32)
         s = tokens.shape[1]
-        x = (params["embed"][tokens]
-             + params["pos"][None, prefix_len:prefix_len + s])
+        with jax.named_scope("embed"):
+            x = (params["embed"][tokens]
+                 + params["pos"][None, prefix_len:prefix_len + s])
         row_pos = jnp.arange(s, dtype=jnp.int32)
         kvs = []
         for p, (pk, pv) in zip(params["blocks"], prefix_kvs):
@@ -476,9 +497,10 @@ class TransformerLM:
             """Feed ``tokens`` [B, T] through all blocks with caches;
             returns (next-token logits [B, V], caches)."""
             start = caches[0]["length"]
-            x = params["embed"][tokens] + jax.lax.dynamic_slice(
-                params["pos"], (start, 0),
-                (tokens.shape[1], self.hidden))[None]
+            with jax.named_scope("embed"):
+                x = params["embed"][tokens] + jax.lax.dynamic_slice(
+                    params["pos"], (start, 0),
+                    (tokens.shape[1], self.hidden))[None]
             new_caches = []
             for p, cache in zip(params["blocks"], caches):
                 holder = {}
@@ -488,8 +510,7 @@ class TransformerLM:
                     return ctx
                 x = self._block(p, x, kv_fn)
                 new_caches.append(holder["cache"])
-            x = _layer_norm(params["ln_f"], x)
-            return (x[:, -1] @ params["embed"].T), new_caches
+            return self._head(params, x, last_only=True), new_caches
 
         if s > 1:
             # prefill everything except the last prompt token through the

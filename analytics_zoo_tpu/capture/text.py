@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional, Sequence
 
+import jax
 import numpy as np
 
 from ..keras import Sequential
@@ -36,6 +37,17 @@ class _BERTTask(Sequential):
     """Sequential over [BERT, head...] that still takes the 4-input pack."""
 
 
+class _Head(Dense):
+    """A task's head over the encoder: a ``Dense`` whose operations carry
+    the scope name ``classifier`` on the device (docs/observability.md);
+    the encoder names its own parts."""
+
+    def call(self, params, state, inputs, *, training=False, rng=None):
+        with jax.named_scope("classifier"):
+            return super().call(params, state, inputs, training=training,
+                                rng=rng)
+
+
 def _make_bert(bert_config: Dict[str, Any]) -> BERT:
     defaults = dict(vocab=30522, hidden_size=768, n_block=12, n_head=12,
                     max_position_len=512, intermediate_size=3072,
@@ -56,7 +68,7 @@ class BERTClassifier:
             bert,
             Lambda(lambda outs: outs[-1], name="take_pooled"),
             Dropout(dropout),
-            Dense(num_classes, activation="softmax", name="classifier"),
+            _Head(num_classes, activation="softmax", name="classifier"),
         ])
         self.model.compile(optimizer, "sparse_categorical_crossentropy",
                            metrics=["accuracy"])
@@ -90,7 +102,7 @@ class BERTNER:
             bert,
             Lambda(lambda outs: outs[0], name="take_states"),
             Dropout(dropout),
-            Dense(num_entities, activation="softmax", name="tagger"),
+            _Head(num_entities, activation="softmax", name="tagger"),
         ])
         self.model.compile(optimizer, "sparse_categorical_crossentropy")
 

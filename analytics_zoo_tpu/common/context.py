@@ -12,6 +12,7 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -19,6 +20,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from . import metrics as _metrics
+from . import utils as _utils
 from .config import global_config
 
 logger = logging.getLogger("analytics_zoo_tpu")
@@ -61,6 +64,43 @@ _context_lock = threading.Lock()
 _context: Optional[ZooTpuContext] = None
 _cache_wired: bool = False
 
+#: what JAX reports of its own compiles (``jax.monitoring``), counted from
+#: :func:`wire_compilation_cache` on. A program that XLA builds and one that
+#: is read back from the persistent cache both leave a ``compile.backend``
+#: span: either costs time where it happens.
+_M_CACHE_HITS = _metrics.counter(
+    "compile.cache_hits_total",
+    "Compiled programs read back from the persistent compilation cache.")
+_M_CACHE_MISSES = _metrics.counter(
+    "compile.cache_misses_total",
+    "Programs the persistent compilation cache did not hold, compiled by "
+    "XLA and written to it.")
+_M_BACKEND_COMPILE = _metrics.histogram(
+    "compile.backend_seconds",
+    "Time to get one executable from the backend: XLA's compile, or the "
+    "read from the persistent cache in its place.")
+_CACHE_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": _M_CACHE_HITS,
+    "/jax/compilation_cache/cache_misses": _M_CACHE_MISSES,
+}
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_compile_event(event: str, **_) -> None:
+    counter = _CACHE_COUNTERS.get(event)
+    if counter is not None:
+        counter.inc()
+
+
+def _on_compile_duration(event: str, seconds: float, **_) -> None:
+    if event != _BACKEND_COMPILE:
+        return
+    _M_BACKEND_COMPILE.observe(seconds)
+    if _utils.span_hooks:
+        # JAX reports a duration once it is over: the span ends now
+        _utils.offer_span("compile.backend", time.perf_counter() - seconds,
+                          seconds)
+
 
 #: where the persistent compilation cache lives when nothing outside the
 #: program places it: one fixed directory inside the checkout (git-ignored).
@@ -83,7 +123,13 @@ def wire_compilation_cache() -> str:
     yesterday's XLA programs from disk instead of recompiling. The
     min-size/min-compile-time thresholds drop to zero so small serving
     programs are cached too (JAX's defaults only persist big, slow
-    compiles)."""
+    compiles). The names of a program's operations (``jax.named_scope``
+    paths) become part of its key, so that what a device trace names is
+    the code that ran; source lines and call stacks are kept out of the
+    program, and so out of the key: an edit that only moves lines, another
+    entry script or another checkout compiles nothing anew. The first call
+    also starts counting JAX's compiles (the ``compile.*`` metrics and the
+    ``compile.backend`` span)."""
     global _cache_wired
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not _cache_wired:
@@ -92,6 +138,20 @@ def wire_compilation_cache() -> str:
                               DEFAULT_COMPILE_CACHE_DIR)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        # the cache's key leaves out a program's debug information unless
+        # told otherwise, and the executable it hands back carries the
+        # ``jax.named_scope`` paths of whatever code compiled it first: a
+        # device trace would then show yesterday's names. All of the debug
+        # information enters the key or none, so the part that changes with
+        # every edit (the Python call stack of each operation, ten frames
+        # of file and line) is left out of the program: what stays is the
+        # scope path and the primitive of each operation
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
+        jax.config.update("jax_traceback_in_locations_limit", 0)
+        jax.monitoring.register_event_listener(_on_compile_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_duration)
         _cache_wired = True
         logger.info("persistent compilation cache: %s (%s)",
                     placed or DEFAULT_COMPILE_CACHE_DIR,

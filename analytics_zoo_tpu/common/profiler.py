@@ -45,6 +45,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 from . import metrics as _metrics
 from . import utils as _utils
 from .config import global_config
+from .utils import NULL_SPAN as _NULL_SPAN
 
 #: the closed phase vocabulary (the ``phase`` label of
 #: ``profile.phase_seconds`` only ever takes these values)
@@ -146,29 +147,16 @@ def _loop_child(metric, tag: str, loop: str):
 def record_phase(loop: str, phase: str, seconds: float,
                  start: Optional[float] = None) -> None:
     """Attribute ``seconds`` of ``loop``'s time to ``phase``. With a
-    ``start`` perf_counter stamp the span is also offered to any live
-    trace session (``profile.<loop>.<phase>`` on the Perfetto timeline).
-    <1µs no-op while the profiler is disabled."""
+    ``start`` perf_counter stamp the span is also offered to whoever
+    listens on ``span_hooks`` (``profile.<loop>.<phase>`` on the Perfetto
+    timeline), whether or not the profiler is enabled: the flag decides
+    on the phase histograms alone. <1µs no-op while it is disabled and
+    nobody listens."""
+    if start is not None and _utils.span_hooks:
+        _utils.offer_span("profile.%s.%s" % (loop, phase), start, seconds)
     if not _enabled:
         return
     _phase_child(loop, phase).observe(seconds)
-    if start is not None and _utils.span_hooks:
-        name = "profile.%s.%s" % (loop, phase)
-        for hook in tuple(_utils.span_hooks):
-            hook(name, start, seconds)
-
-
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
 
 
 class _PhaseSpan:
@@ -236,9 +224,8 @@ class StepProfiler:
             return
         self._acc[phase] = self._acc.get(phase, 0.0) + seconds
         if start is not None and _utils.span_hooks:
-            name = "profile.%s.%s" % (self.loop, phase)
-            for hook in tuple(_utils.span_hooks):
-                hook(name, start, seconds)
+            _utils.offer_span("profile.%s.%s" % (self.loop, phase), start,
+                              seconds)
 
     def phase(self, name: str):
         """``with sp.phase("fetch"): ...`` — times the block into ``name``."""

@@ -1,68 +1,75 @@
-"""Small runtime utilities: micro-profiler, tree helpers, file IO.
+"""Small runtime utilities: host spans, tree helpers, file IO.
 
 ``time_it`` mirrors the reference's ``Utils.timeIt`` wall-time micro-profiler
 (``zoo/.../common/Utils.scala``) used around every hot call
-(``tfpark/GraphRunner.scala:112,132``); here it also aggregates per-name stats so
-the Estimator can report phase timings the way BigDL's ``Metrics`` does.
+(``tfpark/GraphRunner.scala:112,132``). Here a span is not logged but
+offered to whoever listens on ``span_hooks`` (a ``utils.trace`` session, a
+benchmark's recorder); while nobody does, a span takes no clock.
 """
 from __future__ import annotations
 
-import contextlib
 import logging
-import threading
 import time
-from collections import defaultdict
-from typing import Dict, Iterator, Tuple
 
 import jax
 import numpy as np
 
 logger = logging.getLogger("analytics_zoo_tpu")
 
-
-class _TimerRegistry:
-    def __init__(self) -> None:
-        self._lock = threading.Lock()
-        self._totals: Dict[str, float] = defaultdict(float)
-        self._counts: Dict[str, int] = defaultdict(int)
-
-    def add(self, name: str, seconds: float) -> None:
-        with self._lock:
-            self._totals[name] += seconds
-            self._counts[name] += 1
-
-    def stats(self) -> Dict[str, Tuple[float, int]]:
-        with self._lock:
-            return {k: (self._totals[k], self._counts[k]) for k in self._totals}
-
-    def reset(self) -> None:
-        with self._lock:
-            self._totals.clear()
-            self._counts.clear()
-
-
-timers = _TimerRegistry()
-
-# span observers (utils/trace.py chrome-trace recorder registers here);
-# called as fn(name, start_perf_counter, elapsed_seconds)
+# span observers (a utils/trace.py session registers here while it is
+# open); called as fn(name, start_perf_counter, elapsed_seconds). Empty
+# while nobody listens, so that emitters pay one truthiness check.
 span_hooks: list = []
 
 
-@contextlib.contextmanager
-def time_it(name: str, log: bool = False) -> Iterator[None]:
-    start = time.perf_counter()
-    try:
-        yield
-    finally:
-        elapsed = time.perf_counter() - start
-        timers.add(name, elapsed)
-        # iterate a SNAPSHOT: a hook registered/removed concurrently from
-        # another thread must not break this in-flight span exit (list
-        # mutation during iteration raises / skips entries)
-        for hook in tuple(span_hooks):
-            hook(name, start, elapsed)
-        if log:
-            logger.info("%s: %.3fms", name, elapsed * 1e3)
+def offer_span(name: str, start: float, seconds: float) -> None:
+    """Hand one finished span to every hook: ``start`` on
+    ``time.perf_counter``. For a stretch that is no block of code (a
+    request's wait, a duration JAX reports); callers on a hot path check
+    ``if span_hooks:`` before they take a clock for it."""
+    # iterate a SNAPSHOT: a hook registered/removed concurrently from
+    # another thread must not break this in-flight span exit (list
+    # mutation during iteration raises / skips entries)
+    for hook in tuple(span_hooks):
+        hook(name, start, seconds)
+
+
+class _NullSpan:
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+class _Span:
+    __slots__ = ("_name", "_start")
+
+    def __init__(self, name: str):
+        self._name = name
+
+    def __enter__(self):
+        self._start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        offer_span(self._name, self._start,
+                   time.perf_counter() - self._start)
+        return False
+
+
+NULL_SPAN = _NullSpan()
+
+
+def time_it(name: str):
+    """``with time_it("serve.post"): ...`` offers the block to
+    ``span_hooks`` as one span. With no hook registered when the block is
+    entered it is a shared no-op: one truthiness check, no clock."""
+    if not span_hooks:
+        return NULL_SPAN
+    return _Span(name)
 
 
 def wall_clock() -> float:

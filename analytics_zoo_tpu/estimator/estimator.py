@@ -621,10 +621,19 @@ class Estimator:
                 y_pred, new_state = model.call(p, model_state, cast(x),
                                                training=True, rng=rng)
                 # loss in float32 regardless of activation dtype
-                y_pred = jax.tree_util.tree_map(
-                    lambda t: t.astype(jnp.float32), y_pred)
-                return fold_aux(loss_fn(y, y_pred), new_state), new_state
+                with jax.named_scope("loss"):
+                    y_pred = jax.tree_util.tree_map(
+                        lambda t: t.astype(jnp.float32), y_pred)
+                    return fold_aux(loss_fn(y, y_pred), new_state), new_state
 
+            # scope names on the device (docs/observability.md): the model
+            # names its own parts and JAX wraps them in jvp(...) /
+            # transpose(jvp(...)), which tells forward from backward. No
+            # scope goes around this call: it would come between
+            # ``transpose`` and the kernels' own scope, and XLA would name
+            # the backward attention kernel like the forward one. The
+            # gradient's reduction over the data axis is XLA's, not a call
+            # of this program: it shows as all-reduce operations.
             (loss, new_state), grads = jax.value_and_grad(
                 compute_loss, has_aux=True)(params)
             # sharded embedding layers stash their forward exchange blob in
@@ -632,14 +641,18 @@ class Estimator:
             # carry structure) whether or not the sparse update consumes it
             rows_map, new_state = _embed_engine.pop_stashed_rows(new_state)
             if clip is not None:
-                grads, _ = clip.update(grads, clip.init(params), params)
+                with jax.named_scope("optimizer"):
+                    grads, _ = clip.update(grads, clip.init(params), params)
             if not plan:
-                updates, opt_state = optimizer.update(grads, opt_state, params)
-                if frozen:
-                    updates = {k: jax.tree_util.tree_map(jnp.zeros_like, u)
-                               if k in frozen else u
-                               for k, u in updates.items()}
-                params = optax.apply_updates(params, updates)
+                with jax.named_scope("optimizer"):
+                    updates, opt_state = optimizer.update(grads, opt_state,
+                                                          params)
+                    if frozen:
+                        updates = {
+                            k: jax.tree_util.tree_map(jnp.zeros_like, u)
+                            if k in frozen else u
+                            for k, u in updates.items()}
+                    params = optax.apply_updates(params, updates)
                 return params, opt_state, new_state, loss
 
             # sparse path: dense optax over the non-plan leaves, row-subset
@@ -654,13 +667,14 @@ class Estimator:
                                 if (ln, k) not in plan}
                            for ln, sub in grads.items()}
             dense_grads = {ln: sub for ln, sub in dense_grads.items() if sub}
-            updates, dense_opt = optimizer.update(
-                dense_grads, opt_state["dense"], dense_params)
-            if frozen:
-                updates = {k: jax.tree_util.tree_map(jnp.zeros_like, u)
-                           if k in frozen else u
-                           for k, u in updates.items()}
-            new_dense = optax.apply_updates(dense_params, updates)
+            with jax.named_scope("optimizer"):
+                updates, dense_opt = optimizer.update(
+                    dense_grads, opt_state["dense"], dense_params)
+                if frozen:
+                    updates = {k: jax.tree_util.tree_map(jnp.zeros_like, u)
+                               if k in frozen else u
+                               for k, u in updates.items()}
+                new_dense = optax.apply_updates(dense_params, updates)
             out_params = {ln: dict(sub) for ln, sub in params.items()}
             for ln, sub in new_dense.items():
                 for k, v in sub.items():
@@ -675,14 +689,18 @@ class Estimator:
                 if ln in frozen:
                     new_table, new_rstate = table, rstate
                 elif blob is not None:
-                    new_table, new_rstate = _embed_engine.apply_row_update(
-                        kind, hyper, spec, table, g, blob, rstate)
+                    with jax.named_scope("optimizer"):
+                        new_table, new_rstate = \
+                            _embed_engine.apply_row_update(
+                                kind, hyper, spec, table, g, blob, rstate)
                 else:
                     # lookup fell back to the dense gather this step (id
                     # count not divisible over the shards): same optimizer
                     # arithmetic applied to the whole (sharded) table
-                    new_table, new_rstate = _embed_engine.apply_dense_update(
-                        kind, hyper, table, g, rstate)
+                    with jax.named_scope("optimizer"):
+                        new_table, new_rstate = \
+                            _embed_engine.apply_dense_update(
+                                kind, hyper, table, g, rstate)
                 out_params[ln][key] = new_table
                 embed_opt[ln][key] = new_rstate
             return (out_params, {"dense": dense_opt, "embed": embed_opt},

@@ -30,6 +30,7 @@ from jax.sharding import Mesh
 from ..common import faults
 from ..common import metrics as _metrics
 from ..common import profiler as _profiler
+from ..common import utils as _utils
 from ..common.config import global_config
 from ..parallel.mesh import shard_batch
 
@@ -160,6 +161,8 @@ class DeviceFeed:
         item = self._queue.get()
         dt = time.perf_counter() - t0
         _M_STALL.inc(dt)
+        if _utils.span_hooks:
+            _utils.offer_span("train.feed_wait", t0, dt)
         if self._profile_loop is not None:
             _profiler.record_phase(self._profile_loop, "host_input", dt,
                                    start=t0)

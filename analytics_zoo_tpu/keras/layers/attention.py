@@ -7,6 +7,15 @@ states + pooled first-token output). The compute path is TPU-native: heads
 are one batched ``[b, h, s, d]`` tensor driving the fused attention kernels
 in ``ops/attention.py`` (pallas flash kernel on TPU), bf16-friendly, no
 per-head Python loops.
+
+Device operations carry ``jax.named_scope`` names from the vocabulary in
+docs/observability.md: ``embeddings``, and per block ``attention``,
+``layer_norm``, ``ffn``. The kernel (or its reference) is NOT nested under
+``attention``: ``ops/attention.py`` names it ``attn_short`` / ``attn_flash``
+/ ``attn_reference`` beside it, because XLA names a Mosaic call after the
+innermost scope and JAX wraps ``jvp(...)`` / ``transpose(...)`` around the
+outermost scope inside a ``grad`` — with nothing around the kernel's scope
+the backward call is still told from the forward one by its name.
 """
 from __future__ import annotations
 
@@ -94,15 +103,17 @@ class MultiHeadAttention(Layer):
                rng=None):
         b, sq, _ = x_q.shape
         h, dh = self.n_head, self.hidden_size // self.n_head
-        q = _dense(params["q"], x_q).reshape(b, sq, h, dh).transpose(0, 2, 1, 3)
-        k = _dense(params["k"], x_kv).reshape(
-            b, x_kv.shape[1], h, dh).transpose(0, 2, 1, 3)
-        v = _dense(params["v"], x_kv).reshape(
-            b, x_kv.shape[1], h, dh).transpose(0, 2, 1, 3)
-        bias = None
-        if mask is not None:
-            bias = ((1.0 - mask[:, None, None, :].astype(jnp.float32))
-                    * -1e9).astype(x_q.dtype)
+        with jax.named_scope("attention"):
+            q = _dense(params["q"], x_q).reshape(
+                b, sq, h, dh).transpose(0, 2, 1, 3)
+            k = _dense(params["k"], x_kv).reshape(
+                b, x_kv.shape[1], h, dh).transpose(0, 2, 1, 3)
+            v = _dense(params["v"], x_kv).reshape(
+                b, x_kv.shape[1], h, dh).transpose(0, 2, 1, 3)
+            bias = None
+            if mask is not None:
+                bias = ((1.0 - mask[:, None, None, :].astype(jnp.float32))
+                        * -1e9).astype(x_q.dtype)
         drop_rng = None
         if training and self.attn_drop > 0.0 and rng is not None:
             rng, drop_rng = jax.random.split(rng)
@@ -150,9 +161,10 @@ class MultiHeadAttention(Layer):
             ctx = flash_attention(q, k, v, bias=bias, causal=self.causal)
         else:
             ctx = dot_product_attention(q, k, v, bias=bias, causal=self.causal)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, sq, self.hidden_size)
-        out = _dense(params["o"], ctx)
-        return _dropout(out, self.output_drop, rng, training)
+        with jax.named_scope("attention"):
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, sq, self.hidden_size)
+            out = _dense(params["o"], ctx)
+            return _dropout(out, self.output_drop, rng, training)
 
     def call(self, params, state, inputs, *, training=False, rng=None):
         """Inputs: one tensor (self-attention), [q, kv], or [q, kv, mask]."""
@@ -211,17 +223,22 @@ class _TransformerBase(Layer):
     def _run_block(self, p, x, mask, training, rng):
         r1, r2 = (jax.random.split(rng) if rng is not None else (None, None))
         a = self.attn.attend(p["attn"], x, x, mask, training=training, rng=r1)
-        x = _layer_norm(p["ln1"], x + a)
-        hmid = jax.nn.gelu(_dense(p["ffn_in"], x))
-        h = _dropout(_dense(p["ffn_out"], hmid), self.hidden_drop, r2, training)
-        return _layer_norm(p["ln2"], x + h)
+        with jax.named_scope("layer_norm"):
+            x = _layer_norm(p["ln1"], x + a)
+        with jax.named_scope("ffn"):
+            hmid = jax.nn.gelu(_dense(p["ffn_in"], x))
+            h = _dropout(_dense(p["ffn_out"], hmid), self.hidden_drop, r2,
+                         training)
+        with jax.named_scope("layer_norm"):
+            return _layer_norm(p["ln2"], x + h)
 
     def _pooler_params(self, rng):
         return _dense_params(rng, self.hidden_size, self.hidden_size,
                              self.init_range)
 
     def _pool(self, p, states):
-        return jnp.tanh(_dense(p, states[:, 0]))
+        with jax.named_scope("classifier"):
+            return jnp.tanh(_dense(p, states[:, 0]))
 
     def _stack_output_shape(self, seq):
         states = (None, seq, self.hidden_size)
@@ -278,9 +295,10 @@ class TransformerLayer(_TransformerBase):
                 jnp.arange(tokens.shape[1]), tokens.shape)
         tokens = tokens.astype(jnp.int32)
         positions = positions.astype(jnp.int32)
-        x = params["word_emb"][tokens] + params["pos_emb"][positions]
-        if self.compute_dtype is not None:
-            x = x.astype(self.compute_dtype)
+        with jax.named_scope("embeddings"):
+            x = params["word_emb"][tokens] + params["pos_emb"][positions]
+            if self.compute_dtype is not None:
+                x = x.astype(self.compute_dtype)
         all_states = []
         for i in range(self.n_block):
             sub = None
@@ -342,14 +360,15 @@ class BERT(_TransformerBase):
         tokens = tokens.astype(jnp.int32)
         types = types.astype(jnp.int32)
         positions = positions.astype(jnp.int32)
-        x = (params["word_emb"][tokens] + params["pos_emb"][positions]
-             + params["type_emb"][types])
-        if self.compute_dtype is not None:
-            x = x.astype(self.compute_dtype)
-        x = _layer_norm(params["emb_ln"], x)
-        if rng is not None:
-            rng, sub = jax.random.split(rng)
-            x = _dropout(x, self.hidden_drop, sub, training)
+        with jax.named_scope("embeddings"):
+            x = (params["word_emb"][tokens] + params["pos_emb"][positions]
+                 + params["type_emb"][types])
+            if self.compute_dtype is not None:
+                x = x.astype(self.compute_dtype)
+            x = _layer_norm(params["emb_ln"], x)
+            if rng is not None:
+                rng, sub = jax.random.split(rng)
+                x = _dropout(x, self.hidden_drop, sub, training)
         all_states = []
         for i in range(self.n_block):
             sub = None

@@ -54,6 +54,7 @@ def _largest_divisor_leq(n: int, cap: int) -> int:
     return 1
 
 
+@jax.named_scope("kv_attend")
 def masked_context(q: jax.Array, k_buf: jax.Array, v_buf: jax.Array,
                    visible: jax.Array, scale: float) -> jax.Array:
     """THE decode-cache attention arithmetic, shared verbatim by every KV
@@ -81,6 +82,7 @@ def masked_context(q: jax.Array, k_buf: jax.Array, v_buf: jax.Array,
     return ctx.astype(q.dtype)
 
 
+@jax.named_scope("attn_reference")
 def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           bias: Optional[jax.Array] = None,
                           causal: bool = False,
@@ -108,6 +110,7 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return jnp.einsum("...qk,...kd->...qd", probs.astype(v.dtype), v)
 
 
+@jax.named_scope("attn_reference")
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         bias: Optional[jax.Array] = None,
                         causal: bool = False,
@@ -294,6 +297,7 @@ def _vma_struct(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+@jax.named_scope("attn_flash")
 def _flash_fwd_pallas(q, k, v, scale: float, causal: bool,
                       q_block: int, kv_block: int,
                       key_bias: Optional[jax.Array] = None,
@@ -631,6 +635,7 @@ def _flash_bwd_fused(q, k, v, o, lse, g, scale: float, causal: bool,
             dv.astype(v.dtype).reshape(b, h, kv_len, d))
 
 
+@jax.named_scope("attn_flash")
 def _flash_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
                       q_block: int, kv_block: int, glse=None):
     """Full flash backward on TPU. Preferred path: the fused single-pass
@@ -823,6 +828,11 @@ def _fused_short_bwd_kernel(*refs, scale2: float, has_bias: bool,
         preferred_element_type=jnp.float32) * _LN2).astype(dk_ref.dtype)
 
 
+# the scope is the innermost name around the Mosaic call, on one chip and
+# inside dispatch.per_shard's body alike: XLA names the call after it
+# (jvp_attn_short_ / transpose_jvp_attn_short__ under a grad on one chip,
+# attn_short per shard), so no scope may enclose this one inside a grad
+@jax.named_scope("attn_short")
 def _fused_short_call(q, k, v, key_bias, scale, rate, seed, causal=False,
                       fwd=True, do=None):
     from jax.experimental import pallas as pl

@@ -62,10 +62,11 @@ def cached_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
         raise ValueError(
             f"KV cache overflow: writing {t} tokens at position "
             f"{int(start)} exceeds max_len={max_len}")
-    k_buf = lax.dynamic_update_slice(cache["k"], k_new.astype(cache["k"].dtype),
-                                     (0, 0, start, 0))
-    v_buf = lax.dynamic_update_slice(cache["v"], v_new.astype(cache["v"].dtype),
-                                     (0, 0, start, 0))
+    with jax.named_scope("kv_write"):
+        k_buf = lax.dynamic_update_slice(
+            cache["k"], k_new.astype(cache["k"].dtype), (0, 0, start, 0))
+        v_buf = lax.dynamic_update_slice(
+            cache["v"], v_new.astype(cache["v"].dtype), (0, 0, start, 0))
     # visibility: cached prefix [0, start) plus the causal part of the new
     # block [start, start+t)
     key_pos = lax.broadcasted_iota(jnp.int32, (t, max_len), 1)
@@ -157,6 +158,7 @@ def slot_evict(state: Dict[str, jax.Array], mask) -> Dict[str, jax.Array]:
             "active": state["active"] & ~mask}
 
 
+@jax.named_scope("kv_write")
 def slot_insert(cache: SlotCache, slot, k_new: jax.Array, v_new: jax.Array
                 ) -> SlotCache:
     """Write a prefilled K/V block ``[H, T, D]`` into ``slot`` at position
@@ -190,8 +192,9 @@ def slot_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     write = jax.vmap(
         lambda buf, new, pos: lax.dynamic_update_slice(buf, new,
                                                        (0, pos, 0)))
-    k_buf = write(cache["k"], k_new.astype(cache["k"].dtype), lengths)
-    v_buf = write(cache["v"], v_new.astype(cache["v"].dtype), lengths)
+    with jax.named_scope("kv_write"):
+        k_buf = write(cache["k"], k_new.astype(cache["k"].dtype), lengths)
+        v_buf = write(cache["v"], v_new.astype(cache["v"].dtype), lengths)
     # visibility per slot: prefix [0, length] inclusive — the just-written
     # position IS visible, exactly as cached_attention's t=1 decode row
     key_pos = lax.broadcasted_iota(jnp.int32, (t, max_len), 1)
@@ -314,6 +317,7 @@ def page_table_clear(table: jax.Array, mask) -> jax.Array:
     return jnp.where(jnp.asarray(mask)[:, None], 0, table)
 
 
+@jax.named_scope("kv_write")
 def page_copy(cache: PagedCache, src, dst) -> PagedCache:
     """Copy page ``src`` into page ``dst`` (copy-on-write: a stream that
     would append into a shared, partially-filled prefix tail page gets a
@@ -340,6 +344,7 @@ def _page_positions(table: jax.Array, positions: jax.Array, page_len: int
     return page, positions % page_len
 
 
+@jax.named_scope("kv_write")
 def _paged_write(cache: PagedCache, pages: jax.Array, offs: jax.Array,
                  k_rows: jax.Array, v_rows: jax.Array,
                  inline_amax: bool) -> PagedCache:
@@ -373,6 +378,7 @@ def _paged_write(cache: PagedCache, pages: jax.Array, offs: jax.Array,
             "amax_v": next_amax(cache["amax_v"], seen_v)}
 
 
+@jax.named_scope("kv_gather")
 def paged_gather(cache: PagedCache, table: jax.Array
                  ) -> Tuple[jax.Array, jax.Array]:
     """Gather per-slot pages back into logical order: ``table`` [S, C] →
@@ -393,6 +399,7 @@ def paged_gather(cache: PagedCache, table: jax.Array
     return k, v
 
 
+@jax.named_scope("kv_write")
 def paged_insert(cache: PagedCache, table_row: jax.Array, k_new: jax.Array,
                  v_new: jax.Array, start: int = 0) -> PagedCache:
     """Write a prefilled K/V block ``[H, T, D]`` into the pages named by

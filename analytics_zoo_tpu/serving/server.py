@@ -32,6 +32,7 @@ import numpy as np
 from ..common import faults, file_io
 from ..common import metrics as _metrics
 from ..common import profiler as _profiler
+from ..common import utils as _utils
 from ..common.config import global_config
 from ..common.utils import time_it, wall_clock
 from ..inference.inference_model import InferenceModel
@@ -95,6 +96,11 @@ _M_CLAIM_AGE = _metrics.gauge(
 _M_TTFT = _metrics.histogram(
     "serving.ttft_seconds",
     "Enqueue-to-first-token latency of generative streams.",
+    labels=("server",))
+_M_QUEUE_WAIT = _metrics.histogram(
+    "serving.queue_wait_seconds",
+    "Enqueue-to-claim wait of generative requests (client-stamped "
+    "enqueue_t): the part of time to first token spent in the queue.",
     labels=("server",))
 _M_TOKENS = _metrics.counter(
     "serving.tokens_total",
@@ -1252,6 +1258,7 @@ class GenerativeServing:
                     f"max_len={lm.max_len} + spec_k={self._spec_k} "
                     f"transient draft positions")
 
+        @jax.named_scope("select")
         def _select(logits, keys):
             if filter_logits is None:
                 return jnp.argmax(logits, axis=-1)
@@ -1381,6 +1388,7 @@ class GenerativeServing:
         self._expires: List[Optional[float]] = [None] * s
         self._enqueue_t = [0.0] * s
         self._first_t: List[Optional[float]] = [None] * s
+        self._claim_pc = [0.0] * s  # perf_counter at the stream's claim
         self._streamed = [0] * s
         self._keys: List[Optional[np.ndarray]] = [None] * s
         self._next_tokens = np.zeros(s, np.int32)
@@ -1401,6 +1409,7 @@ class GenerativeServing:
         self._m_in_flight = _M_IN_FLIGHT.labels(server=self.metrics_label)
         self._m_claim_age = _M_CLAIM_AGE.labels(server=self.metrics_label)
         self._m_ttft = _M_TTFT.labels(server=self.metrics_label)
+        self._m_queue_wait = _M_QUEUE_WAIT.labels(server=self.metrics_label)
         self._m_tokens = _M_TOKENS.labels(server=self.metrics_label)
         self._m_slots = _M_SLOTS.labels(server=self.metrics_label)
         self._m_pages_free = _M_PAGES_FREE.labels(server=self.metrics_label)
@@ -1456,7 +1465,8 @@ class GenerativeServing:
             value["retriable"] = value["error"] in (SHED_ERROR,
                                                     PAGE_SHED_ERROR)
         try:
-            self.queue.put_result(uri, value)
+            with time_it("serve.put_result"):
+                self.queue.put_result(uri, value)
         except Exception:
             logger.exception("posting result for %s failed", uri)
         with self._counter_lock:
@@ -1834,6 +1844,8 @@ class GenerativeServing:
             return False
         full = prompt + prefix
         t_full = len(full)
+        # the profiler's phase for what follows keeps the name it has in
+        # ClusterServing, host_input; here it is the prefill's dispatch
         t0 = time.perf_counter()
         if self._paged:
             if not self._join_paged(slot, uri, full, t_full,
@@ -1887,9 +1899,15 @@ class GenerativeServing:
         free = [i for i in range(self.slots) if not self._active_host[i]]
         if not free:
             return
+        with time_it("serve.admit"):
+            self._admit_into(free)
+
+    def _admit_into(self, free: List[int]) -> None:
+        """Shed, claim up to ``len(free)`` requests and join each."""
         self._shed()
         try:
-            got = self.queue.claim_batch(len(free))
+            with time_it("serve.claim"):
+                got = self.queue.claim_batch(len(free))
             self._claim_fail_streak = 0
         except OSError as e:
             self._count("claim_faults")
@@ -1904,6 +1922,7 @@ class GenerativeServing:
             return
         self._last_claim_m = time.monotonic()
         now = wall_clock()
+        claim_pc = time.perf_counter()
         with self._counter_lock:
             self._in_flight += len(got)
             in_flight = self._in_flight
@@ -1911,12 +1930,26 @@ class GenerativeServing:
                 self._meta[uri] = (float(rec.get("enqueue_t") or now),
                                    rec.get("trace_id"))
         self._m_in_flight.set(in_flight)
-        if _trace.tracing():
+        for uri, rec in got:
+            # the request's wait in the queue, moved onto perf_counter by
+            # the one pair of clock reads above
+            waited = max(now - float(rec.get("enqueue_t") or now), 0.0)
+            self._m_queue_wait.observe(waited)
+            if _utils.span_hooks:
+                _utils.offer_span("serve.queue_wait", claim_pc - waited,
+                                  waited)
+        tracing = _trace.tracing()
+        if tracing:
             for uri, rec in got:
                 _trace.flow_point(rec.get("trace_id"), "serving.claim", "t")
         for uri, rec in got:
             slot = free.pop(0)
-            if not self._join(slot, uri, rec, now):
+            self._claim_pc[slot] = claim_pc
+            if tracing:
+                _trace.flow_point(rec.get("trace_id"), "serving.join", "t")
+            with time_it("serve.join"):
+                joined = self._join(slot, uri, rec, now)
+            if not joined:
                 free.insert(0, slot)
 
     # -- the step loop -------------------------------------------------------
@@ -1962,9 +1995,9 @@ class GenerativeServing:
             self._tokens[i].append(tok)
             self._next_tokens[i] = tok
             n_tok += 1
-            if self._first_t[i] is None:
-                self._first_t[i] = now
-                self._m_ttft.observe(max(now - self._enqueue_t[i], 0.0))
+            first = self._first_t[i] is None
+            if first:
+                self._first_token_seen(i, now)
             if (len(self._tokens[i]) >= self._budget[i]
                     or (cfg.eos_id is not None and tok == cfg.eos_id)):
                 finished[i] = True
@@ -1973,16 +2006,38 @@ class GenerativeServing:
             elif (stream_stride > 0
                   and (len(self._tokens[i]) - self._streamed[i]
                        >= stream_stride)):
-                try:
-                    self.queue.put_result(self._uri[i], self._partial(i))
-                    self._streamed[i] = len(self._tokens[i])
-                except Exception:
-                    logger.exception("partial result for %s failed",
-                                     self._uri[i])
+                self._post_partial(i)
+            if first and _utils.span_hooks:
+                self._first_token_posted(i)
         if n_tok:
             self._m_tokens.inc(n_tok)
         if finished.any():
             self._evict_slots(finished)
+
+    def _first_token_seen(self, slot: int, now: float) -> None:
+        """A stream's first decoded token: TTFT, and the flow chain's
+        ``serving.first_token`` point."""
+        self._first_t[slot] = now
+        self._m_ttft.observe(max(now - self._enqueue_t[slot], 0.0))
+        if _trace.tracing():
+            meta = self._meta.get(self._uri[slot])
+            if meta is not None:
+                _trace.flow_point(meta[1], "serving.first_token", "t")
+
+    def _first_token_posted(self, slot: int) -> None:
+        """The span from a stream's claim to its first token posted (the
+        write just before this call)."""
+        _utils.offer_span("serve.first_token", self._claim_pc[slot],
+                          time.perf_counter() - self._claim_pc[slot])
+
+    def _post_partial(self, slot: int) -> None:
+        try:
+            with time_it("serve.put_result"):
+                self.queue.put_result(self._uri[slot], self._partial(slot))
+            self._streamed[slot] = len(self._tokens[slot])
+        except Exception:
+            logger.exception("partial result for %s failed",
+                             self._uri[slot])
 
     def _partial(self, slot: int) -> Dict[str, Any]:
         """A stream-progress record: accumulated tokens + the sampling seed
@@ -2023,9 +2078,9 @@ class GenerativeServing:
             self._tokens[i].extend(toks)
             self._next_tokens[i] = toks[-1]
             n_tok += len(toks)
-            if self._first_t[i] is None:
-                self._first_t[i] = now
-                self._m_ttft.observe(max(now - self._enqueue_t[i], 0.0))
+            first = self._first_t[i] is None
+            if first:
+                self._first_token_seen(i, now)
             if (len(self._tokens[i]) >= self._budget[i]
                     or (cfg.eos_id is not None and toks[-1] == cfg.eos_id)):
                 finished[i] = True
@@ -2034,12 +2089,9 @@ class GenerativeServing:
             elif (stream_stride > 0
                   and (len(self._tokens[i]) - self._streamed[i]
                        >= stream_stride)):
-                try:
-                    self.queue.put_result(self._uri[i], self._partial(i))
-                    self._streamed[i] = len(self._tokens[i])
-                except Exception:
-                    logger.exception("partial result for %s failed",
-                                     self._uri[i])
+                self._post_partial(i)
+            if first and _utils.span_hooks:
+                self._first_token_posted(i)
         if n_tok:
             self._m_tokens.inc(n_tok)
         if finished.any():
@@ -2051,8 +2103,18 @@ class GenerativeServing:
         step over every occupied slot, stream/terminate per token. Returns
         the number of streams stepped — the single-step form tests and
         the bench drive directly; :meth:`run` loops it."""
+        if not _utils.span_hooks:
+            return self._serve_step()
+        t0 = time.perf_counter()
+        stepped = self._serve_step()
+        if stepped:  # an iteration that dispatched a step: parent of the rest
+            _utils.offer_span("serve.step", t0, time.perf_counter() - t0)
+        return stepped
+
+    def _serve_step(self) -> int:
         self._maybe_write_health()
-        self._expire_slots()
+        with time_it("serve.expire"):
+            self._expire_slots()
         if not self._draining.is_set():
             self._admit()
         n_active = int(np.sum(self._active_host))
@@ -2088,12 +2150,14 @@ class GenerativeServing:
                                   else 0.8 * self._ewma_token_s + 0.2 * per)
             self._m_spec_accept.set(float(np.mean(np.maximum(
                 n_host[self._active_host] - 1, 0))) / self._spec_k)
-            self._post_tokens_spec(em_host, n_host)
+            with time_it("serve.post"):
+                self._post_tokens_spec(em_host, n_host)
             return n_active
         per = (time.perf_counter() - t_step) / n_active
         self._ewma_token_s = (per if self._ewma_token_s == 0.0
                               else 0.8 * self._ewma_token_s + 0.2 * per)
-        self._post_tokens(nxt_host)
+        with time_it("serve.post"):
+            self._post_tokens(nxt_host)
         return n_active
 
     # -- lifecycle (mirrors ClusterServing) ----------------------------------
@@ -2112,7 +2176,10 @@ class GenerativeServing:
                 if self._draining.is_set() and stepped == 0:
                     return  # drained: every in-flight stream finished
                 if stepped == 0:
-                    time.sleep(poll_interval_s)
+                    # no stream to step: the device waits for a request,
+                    # not for the host
+                    with time_it("serve.idle"):
+                        time.sleep(poll_interval_s)
         finally:
             self._loop_running = False
             if self._stop.is_set():
@@ -2308,6 +2375,9 @@ class GenerativeServing:
             "ttft_ms": {"p50": _pct(self._m_ttft, 0.50),
                         "p99": _pct(self._m_ttft, 0.99),
                         "window": self._m_ttft.count()},
+            "queue_wait_ms": {"p50": _pct(self._m_queue_wait, 0.50),
+                              "p99": _pct(self._m_queue_wait, 0.99),
+                              "window": self._m_queue_wait.count()},
             "latency_ms": {"p50": _pct(self._m_latency, 0.50),
                            "p99": _pct(self._m_latency, 0.99),
                            "window": self._m_latency.count()},
@@ -2336,7 +2406,8 @@ class GenerativeServing:
         now = time.monotonic()
         if now - self._last_health_m >= self.config.health_interval_s:
             self._last_health_m = now
-            self._write_health()
+            with time_it("serve.health"):
+                self._write_health()
 
 
 def main() -> None:

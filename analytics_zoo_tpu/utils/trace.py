@@ -22,7 +22,8 @@ Three capabilities beyond the original recorder:
   on Linux, shared across processes, so child timestamps line up.)
 - **Flow events.** :func:`flow_point` stamps Chrome flow-phase events
   (``s``/``t``/``f``) so one request's lifecycle — enqueue → claim →
-  decode → dispatch → result — draws as a single arrowed chain across
+  decode → dispatch → result, or for a generated stream enqueue → claim →
+  join → first token → result — draws as a single arrowed chain across
   threads and processes in Perfetto. The serving stack calls it with the
   ``trace_id`` the client stamps at enqueue.
 
@@ -37,8 +38,9 @@ Usage::
         estimator.train(fs, batch_size=..., epochs=1)
     # open https://ui.perfetto.dev and load the file
 
-Recording costs one list-append per span; when no session is active the
-hook is a no-op.
+Recording costs one list-append per span. The recorder is on
+``common.utils.span_hooks`` only while a session is open: outside one,
+nothing listens and a ``time_it`` span takes no clock.
 """
 from __future__ import annotations
 
@@ -188,6 +190,7 @@ def _process_label() -> str:
 #: nested trace() calls merge instead of the inner silently dropping the
 #: outer's spans
 _sessions: List[_TraceSession] = []
+_sessions_lock = threading.Lock()
 
 
 def tracing() -> bool:
@@ -200,7 +203,23 @@ def _record(name: str, start: float, elapsed: float) -> None:
         session.add(name, start, elapsed)
 
 
-_utils.span_hooks.append(_record)  # no-op while no session is active
+def _open(session: _TraceSession) -> None:
+    """The first open session puts the recorder on ``span_hooks``."""
+    with _sessions_lock:
+        _sessions.append(session)
+        if len(_sessions) == 1:
+            _utils.span_hooks.append(_record)
+
+
+def _close(session: _TraceSession) -> None:
+    """The last session to close takes the recorder off again."""
+    with _sessions_lock:
+        try:
+            _sessions.remove(session)
+        except ValueError:  # pragma: no cover - double-exit safety
+            return
+        if not _sessions:
+            _utils.span_hooks.remove(_record)
 
 
 def flow_point(flow_id: Optional[int], stage: str, phase: str) -> None:
@@ -228,13 +247,10 @@ def trace(path: str) -> Iterator[_TraceSession]:
     then write Chrome-trace JSON to ``path``. Sessions NEST by merging:
     spans recorded during an inner session land in both traces."""
     session = _TraceSession()
-    _sessions.append(session)
+    _open(session)
     try:
         yield session
     finally:
-        try:
-            _sessions.remove(session)
-        except ValueError:  # pragma: no cover - double-exit safety
-            pass
+        _close(session)
         count = session.dump(path)
         _utils.logger.info("trace: wrote %d spans to %s", count, path)

@@ -804,15 +804,9 @@ def main(argv=None):
         from analytics_zoo_tpu.ops import dispatch
         # a compiler refusal must surface at once, not after five retries
         global_config().set("failure.retry_times", 0)
+        # from here on the program counts JAX's compiles itself
+        # (compile.cache_hits_total / compile.cache_misses_total)
         cache_dir = wire_compilation_cache()
-        cache = {"hits": 0, "misses": 0}
-
-        def count(event, **_):
-            for kind in cache:
-                if event.endswith("/cache_" + kind):
-                    cache[kind] += 1
-
-        jax.monitoring.register_event_listener(count)
         entries_before = _cache_entries(cache_dir)
         init_tpu_context()
         emit("setup", jax=jax.__version__, compile_cache_dir=cache_dir,
@@ -829,9 +823,13 @@ def main(argv=None):
             phase_data_parallel(DP)
             phase_tensor_parallel(TP)
 
+        from analytics_zoo_tpu.common.metrics import metrics_snapshot
+        counted = metrics_snapshot()
         emit("teardown", cache_entries_before=entries_before,
              cache_entries_after=_cache_entries(cache_dir),
-             cache_hits=cache["hits"], cache_misses=cache["misses"],
+             cache_hits=int(counted["compile.cache_hits_total"]["value"]),
+             cache_misses=int(
+                 counted["compile.cache_misses_total"]["value"]),
              reference_paths_taken_on_tpu=[
                  {"kernel": k, "rule": r}
                  for k, r in dispatch.fallbacks_seen()])

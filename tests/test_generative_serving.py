@@ -197,6 +197,13 @@ class TestPerTokenSLO:
         srv.start()
         try:
             assert outq.query("d0", timeout_s=30) is not None
+            # d0's slot is free; a drain stops admission, so let the loop
+            # claim the third request into it first (a few ms, or one
+            # compile of the eviction on a cold process)
+            deadline = time.monotonic() + 10
+            while (srv.health_snapshot()["queue_pending"]
+                   and time.monotonic() < deadline):
+                time.sleep(0.005)
             srv.drain(timeout_s=30)
             for i in range(3):
                 res = outq.query(f"d{i}", timeout_s=5)

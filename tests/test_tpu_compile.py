@@ -155,6 +155,131 @@ def test_kernels_run_per_shard_on_four_chips(v5e, monkeypatch, axis):
     assert text.count("tpu_custom_call") >= 4  # two kernels, both directions
 
 
+# -- names on the device (docs/observability.md "Scope names on the device") ----
+
+def _mosaic_calls(text):
+    """``(instruction name, op_name)`` of each Mosaic call of a compiled
+    program."""
+    import re
+    found = []
+    for line in text.splitlines():
+        if "custom-call(" in line and "tpu_custom_call" in line:
+            name = re.match(r"\s*(?:ROOT )?%(\S+) = ", line).group(1)
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            found.append((name, op_name.group(1) if op_name else ""))
+    return found
+
+
+def _attention_layer_step(dtype):
+    """``value_and_grad`` through the attention layer's fused-short call,
+    with an update under the ``optimizer`` scope, as the train step has it."""
+    from analytics_zoo_tpu.keras.layers.attention import MultiHeadAttention
+    layer = MultiHeadAttention(12, 768)
+    params, _ = layer.build(jax.random.PRNGKey(0), (None, 128, 768))
+    params = jax.tree_util.tree_map(lambda a: a.astype(dtype), params)
+
+    def step(p, x, mask):
+        loss, grads = jax.value_and_grad(lambda p: jnp.sum(
+            layer.attend(p, x, x, mask).astype(F32)))(p)
+        with jax.named_scope("optimizer"):
+            return loss, jax.tree_util.tree_map(
+                lambda a, g: a - 0.1 * g.astype(a.dtype), p, grads)
+    return params, step
+
+
+def test_scopes_name_the_short_kernel_forward_and_backward(v5e, monkeypatch):
+    """One chip: XLA names a Mosaic call after the innermost scope, and JAX
+    wraps ``jvp`` / ``transpose`` around it, so the benchmark's reader still
+    tells the backward call from the forward one by its name; the
+    projections carry ``attention``, the update ``optimizer``."""
+    from analytics_zoo_tpu.ops import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    params, step = _attention_layer_step(BF16)
+    p = jax.tree_util.tree_map(lambda a: v5e(a.shape, a.dtype), params)
+    text = jax.jit(step).lower(
+        p, v5e((8, 128, 768), BF16), v5e((8, 128), F32)).compile().as_text()
+    calls = dict(_mosaic_calls(text))
+    assert len(calls) == 2, calls
+    forward = [n for n in calls if n.startswith("jvp_attn_short")]
+    backward = [n for n in calls if n.startswith("transpose_jvp_attn_short")]
+    assert len(forward) == 1 and len(backward) == 1, calls
+    assert calls[forward[0]].endswith("/jvp(attn_short)/pallas_call")
+    assert calls[backward[0]].endswith(
+        "/transpose(jvp(attn_short))/pallas_call")
+    assert "/jvp(attention)/dot_general" in text
+    assert "/transpose(jvp(attention))/dot_general" in text
+    assert "/optimizer/" in text
+
+
+def test_scopes_name_the_kernels_in_the_estimators_own_step(v5e, monkeypatch):
+    """The step that ``Estimator`` builds for a BERT classifier, not one
+    made here: a scope put around its ``value_and_grad``, or around the
+    kernel's own by a model, would come between ``transpose`` and
+    ``attn_short``, XLA would name the backward call like the forward one,
+    and the benchmark's ``attn_short_roofline.train`` would read nothing."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from analytics_zoo_tpu.capture.text import (BERTClassifier,
+                                                bert_input_pack)
+    from analytics_zoo_tpu.keras.engine import init_model
+    from analytics_zoo_tpu.keras.optimizers import AdamWeightDecay
+    from analytics_zoo_tpu.ops import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    clf = BERTClassifier(
+        2, dropout=0.0, optimizer=AdamWeightDecay(5e-5),
+        bert_config=dict(n_block=1, hidden_p_drop=0.0, attn_p_drop=0.0,
+                         compute_dtype=BF16))
+    est = clf.model.get_estimator()
+    est.mesh = Mesh(np.asarray(v5e.devices[:1]), ("data",))
+    whole = NamedSharding(est.mesh, P())
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: v5e(a.shape, a.dtype, whole), tree)
+
+    x = bert_input_pack(np.ones((8, 128), np.int32))
+    params, state = jax.eval_shape(
+        lambda r: init_model(clf.model, r, x), jax.random.PRNGKey(0))
+    text = est._build_train_step().lower(
+        described(params), described(jax.eval_shape(est._init_opt_state,
+                                                    params)),
+        described(state), v5e((2,), jnp.uint32, whole),
+        [v5e(a.shape, a.dtype, whole) for a in x],
+        v5e((8,), F32, whole)).compile().as_text()
+    calls = dict(_mosaic_calls(text))
+    assert len(calls) == 2, calls
+    assert sorted(n.split("attn_short")[0] for n in calls) == \
+        ["jvp_", "transpose_jvp_"], calls
+    for scope in ("jvp(embeddings)", "transpose(jvp(attention))",
+                  "transpose(jvp(ffn))", "jvp(layer_norm)",
+                  "jvp(classifier)", "jvp(loss)", "optimizer"):
+        assert "/" + scope + "/" in text, scope
+
+
+def test_scopes_name_the_short_kernel_per_shard_on_four_chips(
+        v5e, monkeypatch):
+    """Under the four-chip data mesh of
+    ``test_kernels_run_per_shard_on_four_chips`` the scope sits inside the
+    per-shard body, so both calls are ``attn_short`` (without it they are
+    ``shard_map``); the direction is in the ``op_name``."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from analytics_zoo_tpu.ops import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    mesh = Mesh(np.asarray(v5e.devices), ("data",))
+    whole, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    params, step = _attention_layer_step(BF16)
+    p = jax.tree_util.tree_map(lambda a: v5e(a.shape, a.dtype, whole), params)
+    with dispatch.partitioned_over(mesh):
+        text = jax.jit(step).lower(
+            p, v5e((8, 128, 768), BF16, rows),
+            v5e((8, 128), F32, rows)).compile().as_text()
+    calls = _mosaic_calls(text)
+    assert len(calls) == 2, calls
+    assert all(name.startswith("attn_short") for name, _ in calls), calls
+    assert all(op.endswith("/shard_map/attn_short/pallas_call")
+               for _, op in calls), calls
+    assert sorted("transpose(" in op for _, op in calls) == [False, True]
+
+
 # -- embedding kernels --------------------------------------------------------
 
 _TABLE = (2 ** 20, 128)
