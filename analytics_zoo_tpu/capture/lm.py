@@ -347,7 +347,10 @@ class TransformerLM:
 
     def init_paged_caches(self, num_pages: int, page_len: int,
                           int8: bool = False):
-        """One paged KV pool per block (page 0 is the shared null page)."""
+        """One paged KV pool per block, ``[num_pages, page_len, H*D]``
+        (page 0 is the shared null page). A program that returns the pools
+        should be given them (``jax.jit(..., donate_argnames=...)``): the
+        chip's compiler then writes the new rows in place."""
         if self.max_len % page_len:
             raise ValueError(f"page_len {page_len} must divide "
                              f"max_len {self.max_len}")

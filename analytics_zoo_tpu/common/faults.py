@@ -132,7 +132,8 @@ REGISTRY: Dict[str, Site] = {
     "serving.decode_step": Site(
         "generative scheduler, once per fused decode step — a failed step "
         "must error every active stream (their one terminal result) and "
-        "keep the scheduler serving new requests"),
+        "keep the scheduler serving new requests; raised before the "
+        "dispatch, so the donated KV pools stay whole"),
     "serving.page_alloc": Site(
         "paged KV allocator, at stream join — simulates pool exhaustion; "
         "the request must be SHED with a terminal page-shed error while "

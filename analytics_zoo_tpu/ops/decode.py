@@ -209,10 +209,24 @@ def slot_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
 # per block: HBM pays for max_len whether a stream uses it or not. The paged
 # engine (vLLM's PagedAttention transplanted onto the traced-index slot
 # machinery) replaces the rectangles with ONE global pool of fixed-size
-# pages [P, H, page_len, D] plus a per-slot page TABLE [S, W] of pool
-# indices in logical order — a stream only holds the pages its prompt +
-# budget actually need, and identical prompt prefixes can share refcounted
-# pages (copy-on-write, managed by the scheduler in serving/server.py).
+# pages plus a per-slot page TABLE [S, W] of pool indices in logical order —
+# a stream only holds the pages its prompt + budget actually need, and
+# identical prompt prefixes can share refcounted pages (copy-on-write,
+# managed by the scheduler in serving/server.py).
+#
+# A pool is STORED as [P, page_len, H*D]: one row of H*D values per token
+# position, heads folded into the minor axis. The shape is chosen for the
+# TPU's compiler, which tiles the two minor axes of an array by (8, 128).
+# Over [P, H, page_len, D] with D = 64 that tile would pad every page to
+# twice its size, so the compiler stores such a pool pages-minor-most and
+# wraps every write in two pool-sized copies (layout in, layout back). Over
+# [page_len, H*D] = [16, 768] the tile fits without padding, the pool keeps
+# the plain row-major layout, and the scatter of token rows at
+# [page, offset] updates it IN PLACE when the caller DONATES the pool to
+# the program (serving/server.py donates it to every program that returns
+# it; tests/test_tpu_compile.py compiles those programs for the chip and
+# fails on a pool-shaped copy). Callers still hand rows in and get views
+# back as [..., H, D]; only the stored shape is flat.
 #
 # Page 0 is the NULL page: never allocated to a stream, it absorbs the
 # writes of inactive slots and of positions past a slot's allocation (the
@@ -235,28 +249,27 @@ PagedCache = Dict[str, Any]
 def init_paged_pool(num_pages: int, heads: int, page_len: int,
                     head_dim: int, dtype=jnp.float32,
                     int8: bool = False) -> PagedCache:
-    """Global K/V page pool ``[P, H, page_len, D]`` (per transformer
-    block). Page 0 is reserved as the null page — allocators hand out ids
-    ``1..P-1``. With ``int8=True`` the pool stores int8 payloads plus a
-    per-position f32 scale ``[P, page_len]`` and per-pool running amax
-    scalars (delayed scaling, seeded at 1.0 so the cold-start scale is
-    sane for layer-normed activations)."""
+    """Global K/V page pool ``[P, page_len, H*D]`` (per transformer
+    block; the block comment above says why that shape). Page 0 is
+    reserved as the null page — allocators hand out ids ``1..P-1``. With
+    ``int8=True`` the pool stores int8 payloads plus a per-position f32
+    scale ``[P, page_len]`` and per-pool running amax scalars (delayed
+    scaling, seeded at 1.0 so the cold-start scale is sane for
+    layer-normed activations)."""
     if num_pages < 2:
         raise ValueError(f"num_pages must be >= 2 (page 0 is the reserved "
                          f"null page), got {num_pages}")
     if page_len < 1:
         raise ValueError(f"page_len must be >= 1, got {page_len}")
+    shape = (num_pages, page_len, heads * head_dim)
     if int8:
-        return {"k": jnp.zeros((num_pages, heads, page_len, head_dim),
-                               jnp.int8),
-                "v": jnp.zeros((num_pages, heads, page_len, head_dim),
-                               jnp.int8),
+        return {"k": jnp.zeros(shape, jnp.int8),
+                "v": jnp.zeros(shape, jnp.int8),
                 "scale_k": jnp.zeros((num_pages, page_len), jnp.float32),
                 "scale_v": jnp.zeros((num_pages, page_len), jnp.float32),
                 "amax_k": jnp.ones((), jnp.float32),
                 "amax_v": jnp.ones((), jnp.float32)}
-    return {"k": jnp.zeros((num_pages, heads, page_len, head_dim), dtype),
-            "v": jnp.zeros((num_pages, heads, page_len, head_dim), dtype)}
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 #: mesh axis the paged pool's page dimension shards over
@@ -321,7 +334,8 @@ def page_table_clear(table: jax.Array, mask) -> jax.Array:
 def page_copy(cache: PagedCache, src, dst) -> PagedCache:
     """Copy page ``src`` into page ``dst`` (copy-on-write: a stream that
     would append into a shared, partially-filled prefix tail page gets a
-    private copy instead). Indices may be traced."""
+    private copy instead). Indices may be traced. One page moves; the
+    pool itself is updated in place when the caller donates it."""
     new = {"k": cache["k"].at[dst].set(cache["k"][src]),
            "v": cache["v"].at[dst].set(cache["v"][src])}
     if "scale_k" in cache:
@@ -349,14 +363,17 @@ def _paged_write(cache: PagedCache, pages: jax.Array, offs: jax.Array,
                  k_rows: jax.Array, v_rows: jax.Array,
                  inline_amax: bool) -> PagedCache:
     """Scatter token rows (``[..., H, D]``, leading dims matching
-    ``pages``/``offs``) into the pool. int8 pools quantize on the way in:
+    ``pages``/``offs``) into the pool, one flat ``H*D`` row per
+    ``[page, offset]``. int8 pools quantize on the way in:
     ``inline_amax=True`` (prefill/join path, off the token hot loop) folds
     the block's own amax into the scale; ``inline_amax=False`` (decode hot
     path) uses the DELAYED running scale — no max pass over the write."""
+    k_rows = k_rows.reshape(pages.shape + (-1,))
+    v_rows = v_rows.reshape(pages.shape + (-1,))
     if "scale_k" not in cache:
-        return {"k": cache["k"].at[pages, :, offs, :].set(
+        return {"k": cache["k"].at[pages, offs].set(
                     k_rows.astype(cache["k"].dtype)),
-                "v": cache["v"].at[pages, :, offs, :].set(
+                "v": cache["v"].at[pages, offs].set(
                     v_rows.astype(cache["v"].dtype))}
     kf = k_rows.astype(jnp.float32)
     vf = v_rows.astype(jnp.float32)
@@ -368,8 +385,8 @@ def _paged_write(cache: PagedCache, pages: jax.Array, offs: jax.Array,
               else cache["amax_v"])
     sk = scale_of_amax(amax_k)
     sv = scale_of_amax(amax_v)
-    return {"k": cache["k"].at[pages, :, offs, :].set(quant_int8(kf, sk)),
-            "v": cache["v"].at[pages, :, offs, :].set(quant_int8(vf, sv)),
+    return {"k": cache["k"].at[pages, offs].set(quant_int8(kf, sk)),
+            "v": cache["v"].at[pages, offs].set(quant_int8(vf, sv)),
             "scale_k": cache["scale_k"].at[pages, offs].set(
                 jnp.broadcast_to(sk, pages.shape)),
             "scale_v": cache["scale_v"].at[pages, offs].set(
@@ -379,23 +396,24 @@ def _paged_write(cache: PagedCache, pages: jax.Array, offs: jax.Array,
 
 
 @jax.named_scope("kv_gather")
-def paged_gather(cache: PagedCache, table: jax.Array
+def paged_gather(cache: PagedCache, table: jax.Array, heads: int
                  ) -> Tuple[jax.Array, jax.Array]:
     """Gather per-slot pages back into logical order: ``table`` [S, C] →
-    K/V ``[S, H, C*page_len, D]`` (dequantized to f32 for int8 pools).
-    This materializes the logical view as a TRANSIENT activation — the
-    persistent HBM footprint is the pool; a production TPU kernel would
-    fuse the gather into the attention read (pallas follow-up)."""
-    k = jnp.take(cache["k"], table, axis=0)   # [S, C, H, page_len, D]
+    K/V ``[S, H, C*page_len, D]`` (dequantized to f32 for int8 pools);
+    ``heads`` unfolds the pool's flat ``H*D`` rows. This materializes the
+    logical view as a TRANSIENT activation — the persistent HBM footprint
+    is the pool; a production TPU kernel would fuse the gather into the
+    attention read (pallas follow-up)."""
+    k = jnp.take(cache["k"], table, axis=0)   # [S, C, page_len, H*D]
     v = jnp.take(cache["v"], table, axis=0)
     if "scale_k" in cache:
         sk = jnp.take(cache["scale_k"], table, axis=0)  # [S, C, page_len]
         sv = jnp.take(cache["scale_v"], table, axis=0)
-        k = k.astype(jnp.float32) * sk[:, :, None, :, None]
-        v = v.astype(jnp.float32) * sv[:, :, None, :, None]
-    s, c, h, pl, d = k.shape
-    k = k.transpose(0, 2, 1, 3, 4).reshape(s, h, c * pl, d)
-    v = v.transpose(0, 2, 1, 3, 4).reshape(s, h, c * pl, d)
+        k = k.astype(jnp.float32) * sk[..., None]
+        v = v.astype(jnp.float32) * sv[..., None]
+    s, c, pl, hd = k.shape
+    k = k.reshape(s, c * pl, heads, hd // heads).transpose(0, 2, 1, 3)
+    v = v.reshape(s, c * pl, heads, hd // heads).transpose(0, 2, 1, 3)
     return k, v
 
 
@@ -408,11 +426,12 @@ def paged_insert(cache: PagedCache, table_row: jax.Array, k_new: jax.Array,
     one compile per bucket covers every join; positions past the row's
     width (bucket padding beyond the stream's allocation) fall onto the
     null page. ``start`` is a static offset for shared-prefix suffix
-    prefills."""
+    prefills. The rows go into the pool itself when the caller donates
+    it: a prefill moves its own T rows, not the pool."""
     t = k_new.shape[1]
     positions = start + lax.broadcasted_iota(jnp.int32, (1, t), 1)
     pages, offs = _page_positions(table_row[None], positions,
-                                  cache["k"].shape[2])
+                                  cache["k"].shape[1])
     return _paged_write(cache, pages, offs,
                         k_new.transpose(1, 0, 2)[None],
                         v_new.transpose(1, 0, 2)[None], inline_amax=True)
@@ -435,12 +454,13 @@ def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     The caller advances lengths once after every block attended, exactly
     as with the contiguous engine."""
     _, _, t, d = q.shape
-    page_len = cache["k"].shape[2]
+    page_len = cache["k"].shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     pages, offs = _page_positions(table, lengths[:, None], page_len)
     cache = _paged_write(cache, pages, offs, k_new.transpose(0, 2, 1, 3),
                          v_new.transpose(0, 2, 1, 3), inline_amax=False)
-    k_buf, v_buf = paged_gather(cache, table[:, :max_len // page_len])
+    k_buf, v_buf = paged_gather(cache, table[:, :max_len // page_len],
+                                q.shape[1])
     key_pos = lax.broadcasted_iota(jnp.int32, (t, max_len), 1)
     visible = key_pos[None] <= lengths[:, None, None]   # [S, 1, max_len]
     ctx = masked_context(q, k_buf, v_buf, visible[:, None], scale)
@@ -467,14 +487,14 @@ def paged_verify_attention(q: jax.Array, k_new: jax.Array,
     caller-side ACCEPTED count, not T — rejected positions hold stale K/V
     that the next round overwrites at the same positions."""
     _, _, t, d = q.shape
-    page_len = cache["k"].shape[2]
+    page_len = cache["k"].shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     positions = (lengths[:, None]
                  + lax.broadcasted_iota(jnp.int32, (q.shape[0], t), 1))
     pages, offs = _page_positions(table, positions, page_len)
     cache = _paged_write(cache, pages, offs, k_new.transpose(0, 2, 1, 3),
                          v_new.transpose(0, 2, 1, 3), inline_amax=False)
-    k_buf, v_buf = paged_gather(cache, table)
+    k_buf, v_buf = paged_gather(cache, table, q.shape[1])
     kcols = table.shape[1] * page_len
     key_pos = lax.broadcasted_iota(jnp.int32, (t, kcols), 1)
     row_pos = lax.broadcasted_iota(jnp.int32, (t, kcols), 0)

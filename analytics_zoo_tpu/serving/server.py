@@ -117,6 +117,12 @@ _M_PAGE_EVICT = _metrics.counter(
     "serving.kv_page_evictions_total",
     "KV pages returned to the pool by stream retirement.",
     labels=("server",))
+_M_POOL_REBUILDS = _metrics.counter(
+    "serving.kv_pool_rebuilds_total",
+    "Times the KV caches were made anew after a failed program that had "
+    "been given them (every resident stream errored, prefixes prefilled "
+    "again).",
+    labels=("server",))
 _M_SPEC_ACCEPT = _metrics.gauge(
     "serving.spec_accept_ratio",
     "Mean fraction of draft tokens accepted in the last verify round.",
@@ -1170,7 +1176,7 @@ class GenerativeServing:
         import jax
         import jax.numpy as jnp
 
-        from ..ops.decode import (init_slot_state, make_logit_filter,
+        from ..ops.decode import (make_logit_filter,
                                   page_copy, page_table_clear,
                                   page_table_set, paged_gather, paged_insert,
                                   slot_evict, slot_insert, slot_join,
@@ -1225,38 +1231,19 @@ class GenerativeServing:
             # stream owns only within its allocation; beyond it, the null
             # page absorbs them)
             self._table_w = (lm.max_len + self._spec_k + pl - 1) // pl
-            self._caches = lm.init_paged_caches(num_pages, pl,
-                                                int8=config.kv_int8)
             self._kv_shard = int(getattr(config, "kv_shard", 1) or 1)
-            if self._kv_shard > 1:
-                from ..ops.decode import shard_paged_pool
-                # page axis spread over kv_shard devices; decode gathers
-                # each stream's pages to the compute device, so tokens
-                # stay bit-identical to the single-device pool
-                self._caches = shard_paged_pool(self._caches,
-                                                self._kv_shard)
-            self._table = jnp.zeros((self.slots, self._table_w), jnp.int32)
-            # host-side allocator: free-page stack, refcounts, and the
-            # pages each slot holds (shared prefix pages appear in many)
-            self._free_pages = self._initial_free_pages(num_pages,
-                                                        self._kv_shard)
-            self._page_refs = np.zeros(num_pages, np.int64)
-            self._slot_pages: List[List[int]] = [[] for _ in
-                                                 range(self.slots)]
-            self._prefixes: List[Dict[str, Any]] = []
         else:
             self._kv_shard = 1
-            self._caches = lm.init_slot_caches(self.slots)
-        self._state = init_slot_state(self.slots)
+        self._prefixes: List[Dict[str, Any]] = []  # paged engine only
         if self._spec:
             self.draft_lm = draft_lm
             self._dparams = draft_lm.params
-            self._dcaches = draft_lm.init_slot_caches(self.slots)
             if draft_lm.max_len < lm.max_len + self._spec_k:
                 raise ValueError(
                     f"draft max_len={draft_lm.max_len} must cover "
                     f"max_len={lm.max_len} + spec_k={self._spec_k} "
                     f"transient draft positions")
+        self._fresh_device_state()
 
         @jax.named_scope("select")
         def _select(logits, keys):
@@ -1343,7 +1330,7 @@ class GenerativeServing:
                             slot, length, plen):
             # gather the shared prefix K/V (refcounted pages, prefilled
             # once) and run only the divergent suffix forward
-            pref = [paged_gather(c, prow[None]) for c in caches]
+            pref = [paged_gather(c, prow[None], lm.n_head) for c in caches]
             pref = [(k[:, :, :plen], v[:, :, :plen]) for k, v in pref]
             kvs = lm.prefill_kv_suffix(params, padded, pref, plen)
             caches = [paged_insert(c, row, k[0], v[0], start=plen)
@@ -1359,19 +1346,31 @@ class GenerativeServing:
         def _copy_pages(caches, src, dst):
             return [page_copy(c, src, dst) for c in caches]
 
+        # every program that takes the page pools and returns them is
+        # GIVEN them (donated): with the pool layout of ops/decode.py the
+        # chip's compiler then writes the token rows in place, and no
+        # program copies a pool. The handles passed in are dead once the
+        # call returns, so each caller below rebinds the results at once.
+        # The slot engine's programs are not donated.
+        pools = ("caches",)
         if self._spec:
-            self._step_fn = jax.jit(_step_spec)
-            self._prefill_spec_fn = jax.jit(_prefill_spec)
+            both = ("caches", "dcaches")
+            self._step_fn = jax.jit(_step_spec, donate_argnames=both)
+            self._prefill_spec_fn = jax.jit(_prefill_spec,
+                                            donate_argnames=both)
         elif self._paged:
-            self._step_fn = jax.jit(_step_paged)
+            self._step_fn = jax.jit(_step_paged, donate_argnames=pools)
         else:
             self._step_fn = jax.jit(_step)
         if self._paged:
-            self._prefill_paged_fn = jax.jit(_prefill_paged)
+            self._prefill_paged_fn = jax.jit(_prefill_paged,
+                                             donate_argnames=pools)
             self._prefill_suffix_fn = jax.jit(_prefill_suffix,
-                                              static_argnames=("plen",))
-            self._prefill_prefix_fn = jax.jit(_prefill_prefix)
-            self._copy_fn = jax.jit(_copy_pages)
+                                              static_argnames=("plen",),
+                                              donate_argnames=pools)
+            self._prefill_prefix_fn = jax.jit(_prefill_prefix,
+                                              donate_argnames=pools)
+            self._copy_fn = jax.jit(_copy_pages, donate_argnames=pools)
             self._table_set_fn = jax.jit(page_table_set)
             self._table_clear_fn = jax.jit(page_table_clear)
         else:
@@ -1414,6 +1413,8 @@ class GenerativeServing:
         self._m_slots = _M_SLOTS.labels(server=self.metrics_label)
         self._m_pages_free = _M_PAGES_FREE.labels(server=self.metrics_label)
         self._m_page_evict = _M_PAGE_EVICT.labels(server=self.metrics_label)
+        self._m_pool_rebuilds = _M_POOL_REBUILDS.labels(
+            server=self.metrics_label)
         self._m_spec_accept = _M_SPEC_ACCEPT.labels(
             server=self.metrics_label)
         self._m_brownout = _M_BROWNOUT.labels(server=self.metrics_label)
@@ -1518,6 +1519,55 @@ class GenerativeServing:
             self._release_pages(slot)
         self._clear_slot(slot)
 
+    def _fresh_device_state(self) -> None:
+        """KV caches, slot occupancy, page table and page allocator as a
+        server starts with them: at construction, and again after a failed
+        program that had been given the caches (:meth:`_rebuild_pools`)."""
+        import jax.numpy as jnp
+
+        from ..ops.decode import init_slot_state, shard_paged_pool
+        if self._paged:
+            self._caches = self.lm.init_paged_caches(
+                self.num_pages, self.page_len, int8=self.config.kv_int8)
+            if self._kv_shard > 1:
+                # page axis spread over kv_shard devices; decode gathers
+                # each stream's pages to the compute device, so tokens
+                # stay bit-identical to the single-device pool
+                self._caches = shard_paged_pool(self._caches,
+                                                self._kv_shard)
+            self._table = jnp.zeros((self.slots, self._table_w), jnp.int32)
+            # host-side allocator: free-page stack, refcounts, and the
+            # pages each slot holds (shared prefix pages appear in many)
+            self._free_pages = self._initial_free_pages(self.num_pages,
+                                                        self._kv_shard)
+            self._page_refs = np.zeros(self.num_pages, np.int64)
+            self._slot_pages: List[List[int]] = [[] for _ in
+                                                 range(self.slots)]
+        else:
+            self._caches = self.lm.init_slot_caches(self.slots)
+        self._state = init_slot_state(self.slots)
+        if self._spec:
+            self._dcaches = self.draft_lm.init_slot_caches(self.slots)
+
+    def _rebuild_pools(self) -> None:
+        """What a failed program costs once it was given the caches: the
+        donated handles are dead (or hold a failed computation's results),
+        so nothing of the old device state is read again. The caller has
+        errored every resident stream; here the caches, the table and the
+        allocator start over and every registered prefix is prefilled
+        again into fresh pages. A second failure in here is not caught:
+        the loop dies and the health snapshot says ``crashed``."""
+        self._caches = None  # the old pools go before the new are made
+        prefixes, self._prefixes = self._prefixes, []
+        self._fresh_device_state()
+        for pfx in prefixes:
+            self.register_prefix(pfx["tokens"])
+        self._m_pool_rebuilds.inc()
+        if self._paged:
+            self._m_pages_free.set(len(self._free_pages))
+        logger.warning("kv caches rebuilt after a failed dispatch "
+                       "(%d prefixes prefilled again)", len(prefixes))
+
     @staticmethod
     def _initial_free_pages(num_pages: int, kv_shard: int):
         """Allocatable pages ``1..num_pages-1`` as a pop()-able stack.
@@ -1562,20 +1612,23 @@ class GenerativeServing:
     # -- device hot path (policed by scripts/check_hot_path_syncs.py) ------
 
     def _dispatch_step(self, tokens, keys):
-        # chaos site: a failed fused step must error every active stream
-        # (their one terminal result) and keep the scheduler serving
-        faults.inject("serving.decode_step")
+        """Dispatch one fused step and rebind the device state to its
+        results before anything else runs: the caches passed in were given
+        to the program. Returns what the host fetches."""
         t0 = time.perf_counter()
         if self._spec:
-            out = self._step_fn(self._params, self._dparams, tokens,
-                                self._state, self._table, self._caches,
-                                self._dcaches)
+            (emitted, n_acc, self._state, self._caches,
+             self._dcaches) = self._step_fn(
+                self._params, self._dparams, tokens, self._state,
+                self._table, self._caches, self._dcaches)
+            out = (emitted, n_acc)
         elif self._paged:
-            out = self._step_fn(self._params, tokens, keys, self._state,
-                                self._table, self._caches)
+            out, self._state, self._caches = self._step_fn(
+                self._params, tokens, keys, self._state, self._table,
+                self._caches)
         else:
-            out = self._step_fn(self._params, tokens, keys, self._state,
-                                self._caches)
+            out, self._state, self._caches = self._step_fn(
+                self._params, tokens, keys, self._state, self._caches)
         _profiler.record_phase("serving", "dispatch",
                                time.perf_counter() - t0, start=t0)
         return out
@@ -1726,7 +1779,9 @@ class GenerativeServing:
         """Allocate pages for a validated request and prefill it into
         ``slot``. Pool exhaustion (or the armed ``serving.page_alloc``
         fault) SHEDS the request — its one terminal result is the page
-        shed error — and every resident stream keeps decoding."""
+        shed error — and every resident stream keeps decoding. A prefill
+        that FAILS takes the donated pools with it: see
+        :meth:`_rebuild_pools`."""
         from ..capture.lm import prefill_bucket
         pl = self.page_len
         pfx = self._match_prefix(prompt) if not self._spec else None
@@ -1763,35 +1818,45 @@ class GenerativeServing:
             self._page_refs[p] = 1
         self._slot_pages[slot] = shared + fresh
         self._m_pages_free.set(len(self._free_pages))
-        if pfx and rem:
-            # CoW: the stream appends into logical page ``full``, which
-            # still holds shared prefix tail tokens — give it a private
-            # copy (fresh[0] occupies that table position)
-            self._copy_page_device(pfx["pages"][full], fresh[0])
-        if fed > plen:
-            padded = np.zeros((1, tb), np.int32)
-            padded[0, :fed - plen] = prompt[plen:fed]
-            if pfx:
-                prow = np.asarray(pfx["pages"], np.int32)
-                self._insert_suffix_paged(padded, row, prow,
-                                          np.int32(slot), np.int32(fed),
-                                          plen)
-            elif self._spec:
-                dtb = prefill_bucket(fed, self.draft_lm.max_len)
-                dpadded = np.zeros((1, dtb), np.int32)
-                dpadded[0, :fed] = prompt[:fed]
-                self._insert_request_spec(padded, dpadded, row,
-                                          np.int32(slot), np.int32(fed))
+        try:
+            if pfx and rem:
+                # CoW: the stream appends into logical page ``full``, which
+                # still holds shared prefix tail tokens — give it a private
+                # copy (fresh[0] occupies that table position)
+                self._copy_page_device(pfx["pages"][full], fresh[0])
+            if fed > plen:
+                padded = np.zeros((1, tb), np.int32)
+                padded[0, :fed - plen] = prompt[plen:fed]
+                if pfx:
+                    prow = np.asarray(pfx["pages"], np.int32)
+                    self._insert_suffix_paged(padded, row, prow,
+                                              np.int32(slot), np.int32(fed),
+                                              plen)
+                elif self._spec:
+                    dtb = prefill_bucket(fed, self.draft_lm.max_len)
+                    dpadded = np.zeros((1, dtb), np.int32)
+                    dpadded[0, :fed] = prompt[:fed]
+                    self._insert_request_spec(padded, dpadded, row,
+                                              np.int32(slot), np.int32(fed))
+                else:
+                    self._insert_request_paged(padded, row, np.int32(slot),
+                                               np.int32(fed))
             else:
-                self._insert_request_paged(padded, row, np.int32(slot),
-                                           np.int32(fed))
-        else:
-            # nothing to prefill (one-token prompt, or the prompt is
-            # prefix + one token): join + install the table row
-            self._state = self._join_fn(self._state, np.int32(slot),
-                                        np.int32(fed))
-            self._table = self._table_set_fn(self._table, np.int32(slot),
-                                             row)
+                # nothing to prefill (one-token prompt, or the prompt is
+                # prefix + one token): join + install the table row
+                self._state = self._join_fn(self._state, np.int32(slot),
+                                            np.int32(fed))
+                self._table = self._table_set_fn(self._table, np.int32(slot),
+                                                 row)
+        except Exception as e:
+            # a program that was given the pools failed: this request and
+            # every resident stream get their one terminal, the pools start
+            # over, and the requests claimed with this one join after it
+            logger.exception("prefill of %s failed", uri)
+            self._post_terminal(uri, {"error": repr(e)})
+            self._count("errors")
+            self._fail_active(repr(e), rebuild=True)
+            return False
         return True
 
     def _join(self, slot: int, uri: str, rec: Dict[str, Any],
@@ -1968,13 +2033,18 @@ class GenerativeServing:
         if mask.any():
             self._evict_slots(mask)
 
-    def _fail_active(self, message: str) -> None:
+    def _fail_active(self, message: str, rebuild: bool = False) -> None:
+        """Error every active stream (its one terminal). ``rebuild``: the
+        failure came at or after a dispatch that was given the caches, so
+        the device state starts over instead of being evicted from."""
         mask = np.zeros(self.slots, bool)
         for i in range(self.slots):
             if self._active_host[i]:
                 mask[i] = True
                 self._retire(i, {"error": message}, counter="errors")
-        if mask.any():
+        if rebuild:
+            self._rebuild_pools()
+        elif mask.any():
             self._evict_slots(mask)
 
     def _post_tokens(self, nxt: np.ndarray) -> None:
@@ -2128,22 +2198,24 @@ class GenerativeServing:
                 if self._active_host[i]:
                     keys[i] = self._keys[i][len(self._tokens[i])]
         t_step = time.perf_counter()
+        given = False
         try:
+            # chaos site, raised BEFORE the dispatch: the step errors every
+            # active stream (their one terminal) and the caches stay whole
+            faults.inject("serving.decode_step")
+            given = True
             if self._spec:
-                emitted, n_acc, state, caches, dcaches = \
-                    self._dispatch_step(tokens, keys)
+                emitted, n_acc = self._dispatch_step(tokens, keys)
                 em_host = self._fetch_tokens(emitted)
                 n_host = self._fetch_tokens(n_acc)
             else:
-                nxt, state, caches = self._dispatch_step(tokens, keys)
-                nxt_host = self._fetch_tokens(nxt)
+                nxt_host = self._fetch_tokens(
+                    self._dispatch_step(tokens, keys))
         except Exception as e:
             logger.exception("decode step failed for %d streams", n_active)
-            self._fail_active(repr(e))
+            self._fail_active(repr(e), rebuild=given)
             return 0
-        self._state, self._caches = state, caches
         if self._spec:
-            self._dcaches = dcaches
             n_emitted = int(np.sum(n_host[self._active_host]))
             per = (time.perf_counter() - t_step) / max(n_emitted, 1)
             self._ewma_token_s = (per if self._ewma_token_s == 0.0
@@ -2364,6 +2436,7 @@ class GenerativeServing:
             "kv_pages_free": (len(self._free_pages) if self._paged
                               else None),
             "kv_shards": (self._kv_shard if self._paged else None),
+            "kv_pool_rebuilds": int(self._m_pool_rebuilds.value()),
             "kv_pages_free_min_shard": (
                 min(self._pages_free_per_shard())
                 if self._paged and self._kv_shard > 1 else None),

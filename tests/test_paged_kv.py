@@ -62,7 +62,7 @@ class TestPagedBitIdentity:
                                           np.asarray(ctx_p))
             lengths = lengths + 1
         # the pool holds exactly what the rectangles hold, page-gathered
-        k_log, v_log = paged_gather(paged_c, table)
+        k_log, v_log = paged_gather(paged_c, table, H)
         np.testing.assert_array_equal(np.asarray(k_log),
                                       np.asarray(slot_c["k"]))
         np.testing.assert_array_equal(np.asarray(v_log),
@@ -75,7 +75,7 @@ class TestPagedBitIdentity:
         k = jnp.asarray(rs.randn(H, 13, D), jnp.float32)
         v = jnp.asarray(rs.randn(H, 13, D), jnp.float32)
         cache = paged_insert(cache, table[0], k, v)
-        k_log, v_log = paged_gather(cache, table)
+        k_log, v_log = paged_gather(cache, table, H)
         np.testing.assert_array_equal(np.asarray(k_log[0, :, :13]),
                                       np.asarray(k))
         np.testing.assert_array_equal(np.asarray(v_log[0, :, :13]),
@@ -83,7 +83,7 @@ class TestPagedBitIdentity:
         # the start offset lands a suffix block at its logical positions
         k2 = jnp.asarray(rs.randn(H, 3, D), jnp.float32)
         cache = paged_insert(cache, table[0], k2, k2, start=13)
-        k_log, _ = paged_gather(cache, table)
+        k_log, _ = paged_gather(cache, table, H)
         np.testing.assert_array_equal(np.asarray(k_log[0, :, 13:16]),
                                       np.asarray(k2))
         # positions 0..12 are untouched by the suffix write
@@ -146,7 +146,7 @@ class TestInt8PagedPool:
         v = rs.randn(H, MAX_LEN, D).astype(np.float32)
         cache = paged_insert(cache, table[0], jnp.asarray(k),
                              jnp.asarray(v))
-        k_log, v_log = paged_gather(cache, table)
+        k_log, v_log = paged_gather(cache, table, H)
         # the inline scale is scalar per write (block amax / 127), so the
         # round-trip error is bounded by half a quantization step
         half_k = max(1.0, np.abs(k).max()) / 127.0 / 2.0

@@ -1,7 +1,8 @@
 """Paged KV scheduler: parity with the contiguous engine and with serial
 generate, copy-on-write shared prefixes (prefilled ONCE), speculative
 draft/verify token-identity, page-pool exhaustion chaos
-(``serving.page_alloc``), and the paged metrics plane.
+(``serving.page_alloc``), the paged metrics plane, and what a failed
+program costs now that the pools are donated to it.
 
 Op-level paged invariants live in tests/test_paged_kv.py; the contiguous
 scheduler's own parity suite is tests/test_generative_serving.py.
@@ -25,8 +26,9 @@ def _clean_faults():
     faults.reset()
 
 
-pytestmark = pytest.mark.slow  # scheduler-level suite; tier-1 covers the op layer
-
+# the scheduler-level classes are slow (tier-1 covers the op layer in
+# tests/test_paged_kv.py); the donated pools' failure path at the end of
+# the file is not
 _LM_CACHE = {}
 
 
@@ -65,6 +67,7 @@ def _paged_cfg(src, **kw):
     return ServingConfig(data_src=src, **kw)
 
 
+@pytest.mark.slow
 class TestPagedParity:
     @pytest.mark.slow
     def test_greedy_bit_identical_with_midstream_joins(self, ctx, tmp_path):
@@ -130,6 +133,7 @@ class TestPagedParity:
             assert res is not None and res["value"] == want
 
 
+@pytest.mark.slow
 class TestSharedPrefixCoW:
     @pytest.mark.slow
     def test_prefix_prefilled_once_and_bit_identical(self, ctx, tmp_path,
@@ -204,6 +208,7 @@ class TestSharedPrefixCoW:
         assert len(scalls) >= 1         # joins ran the SUFFIX path only
 
 
+@pytest.mark.slow
 class TestSpeculative:
     @pytest.mark.slow
     def test_spec_token_identical_to_serial_greedy(self, ctx, tmp_path):
@@ -263,6 +268,7 @@ class TestSpeculative:
                               lm, draft_lm=draft)
 
 
+@pytest.mark.slow
 class TestPagePoolChaos:
     def test_page_alloc_fault_sheds_join_keeps_serving(self, ctx, tmp_path):
         """The armed ``serving.page_alloc`` site simulates pool exhaustion
@@ -334,6 +340,7 @@ class TestPagePoolChaos:
         assert snap["spec_accept_ratio"] is None   # not a spec server
 
 
+@pytest.mark.slow
 class TestShardedPool:
     """``kv_shard``: the page pool's PAGE axis spread across devices —
     decode gathers each stream's pages to the compute device, so the
@@ -370,3 +377,139 @@ class TestShardedPool:
         with pytest.raises(ValueError, match="kv shard"):
             GenerativeServing(
                 _paged_cfg(_src(tmp_path), kv_pages=15, kv_shard=4), lm)
+
+
+class TestDonatedPoolFailure:
+    """Every program that returns the pools is given them, so a failure at
+    or after its dispatch leaves dead handles: each resident stream gets
+    its one terminal, the pools, table and allocator start over, the
+    registered prefixes are prefilled again, and the next request is
+    served as if nothing had happened. A failure raised BEFORE the
+    dispatch (the armed ``serving.decode_step`` site) consumes nothing."""
+
+    PREFIX = [3, 7, 2, 9, 5]                            # 5 tokens: CoW tail
+
+    def _server(self, tmp_path, monkeypatch, engine):
+        lm = _lm()
+        src = _src(tmp_path)
+        if engine == "spec":
+            srv = GenerativeServing(_paged_cfg(src, spec_k=3), lm,
+                                    draft_lm=_lm(max_len=64, seed=1))
+        else:
+            srv = GenerativeServing(
+                _paged_cfg(src, kv_int8=engine == "int8",
+                           kv_shard=4 if engine == "shard" else 1), lm)
+        terminals = {}
+        put = srv.queue.put_result
+
+        def counting(uri, value):
+            if "error" in value or value.get("done"):
+                terminals[uri] = terminals.get(uri, 0) + 1
+            return put(uri, value)
+        monkeypatch.setattr(srv.queue, "put_result", counting)
+        return lm, srv, InputQueue(src), OutputQueue(src), terminals
+
+    @staticmethod
+    def _serial(lm, prompt):
+        return lm.generate(np.asarray([prompt]),
+                           max_new_tokens=8)[0].tolist()
+
+    def _served_again(self, lm, srv, inq, outq, prompts, free0):
+        want = [self._serial(lm, p) for p in prompts]
+        for i, p in enumerate(prompts):
+            inq.enqueue_prompt(f"after{i}", p)
+        _drive(srv)
+        for i, w in enumerate(want):
+            assert outq.query(f"after{i}", timeout_s=5)["value"] == w
+        snap = srv.health_snapshot()
+        assert snap["slots_occupied"] == 0 and snap["in_flight"] == 0
+        assert snap["kv_pages_free"] == free0
+        return snap
+
+    @pytest.mark.parametrize("engine", ["paged", "spec", "shard"])
+    def test_failed_fetch_after_dispatch(self, ctx, tmp_path, monkeypatch,
+                                         engine):
+        lm, srv, inq, outq, terminals = self._server(tmp_path, monkeypatch,
+                                                     engine)
+        shared = engine != "spec"   # prefixes are not wired into spec
+        if shared:
+            srv.register_prefix(self.PREFIX)
+        free0 = srv.health_snapshot()["kv_pages_free"]
+        inq.enqueue_prompt("a", self.PREFIX + [1])
+        inq.enqueue_prompt("b", [2, 3, 5])
+        assert srv.serve_step() == 2
+        given = srv._caches[0]["k"]
+        fetch = srv._fetch_tokens
+
+        def failing(nxt):
+            raise RuntimeError("fetch failed")
+        monkeypatch.setattr(srv, "_fetch_tokens", failing)
+        assert srv.serve_step() == 0
+        monkeypatch.setattr(srv, "_fetch_tokens", fetch)
+        assert given.is_deleted()         # the step was given the pools
+        for uri in ("a", "b"):
+            assert "fetch failed" in outq.query(uri, timeout_s=2)["error"]
+        assert terminals == {"a": 1, "b": 1}
+        assert srv.counters["errors"] == 2
+        assert srv.health_snapshot()["kv_pages_free"] == free0
+        # prefix + one token has no forward of its own: its tokens are
+        # right only if the prefix was prefilled again into fresh pages
+        snap = self._served_again(lm, srv, inq, outq,
+                                  [self.PREFIX + [4], [2, 3, 5]], free0)
+        assert len(srv._prefixes) == (1 if shared else 0)
+        assert snap["kv_pool_rebuilds"] == 1
+        assert terminals == {"a": 1, "b": 1, "after0": 1, "after1": 1}
+        assert "serving_kv_pool_rebuilds_total" in _metrics.expose_text()
+
+    @pytest.mark.parametrize("engine,program", [
+        ("paged", "_prefill_paged_fn"), ("int8", "_prefill_paged_fn"),
+        ("spec", "_prefill_spec_fn"), ("paged", "_prefill_suffix_fn"),
+        ("paged", "_copy_fn")])
+    def test_failed_prefill_after_dispatch(self, ctx, tmp_path, monkeypatch,
+                                           engine, program):
+        lm, srv, inq, outq, terminals = self._server(tmp_path, monkeypatch,
+                                                     engine)
+        shared = program in ("_prefill_suffix_fn", "_copy_fn")
+        if shared:
+            srv.register_prefix(self.PREFIX)
+        free0 = srv.health_snapshot()["kv_pages_free"]
+        inq.enqueue_prompt("alive", [2, 3, 5])
+        assert srv.serve_step() == 1              # a resident stream
+        real = getattr(srv, program)
+
+        def failing(*a, **kw):
+            real(*a, **kw)                        # the pools are consumed
+            raise RuntimeError("prefill failed")
+        monkeypatch.setattr(srv, program, failing)
+        # prefix + 3 tokens takes the CoW copy, then the suffix prefill
+        victim = self.PREFIX + [4, 1, 6] if shared else [4, 1, 6]
+        inq.enqueue_prompt("victim", victim)
+        inq.enqueue_prompt("behind", [2, 3, 5])   # claimed with the victim
+        srv.serve_step()
+        monkeypatch.setattr(srv, program, real)
+        for uri in ("alive", "victim"):
+            assert "prefill failed" in outq.query(uri, timeout_s=2)["error"]
+        _drive(srv)
+        assert outq.query("behind", timeout_s=5)["value"] == \
+            self._serial(lm, [2, 3, 5])
+        assert terminals == {"alive": 1, "victim": 1, "behind": 1}
+        snap = self._served_again(lm, srv, inq, outq, [victim, [2, 3, 5]],
+                                  free0)
+        assert snap["kv_pool_rebuilds"] == 1
+        assert srv.counters["errors"] == 2
+
+    def test_fault_before_dispatch_rebuilds_nothing(self, ctx, tmp_path,
+                                                    monkeypatch):
+        lm, srv, inq, outq, terminals = self._server(tmp_path, monkeypatch,
+                                                     "paged")
+        srv.register_prefix(self.PREFIX)
+        free0 = srv.health_snapshot()["kv_pages_free"]
+        inq.enqueue_prompt("hit", self.PREFIX + [1])
+        faults.arm("serving.decode_step", at=1)
+        assert srv.serve_step() == 0
+        assert "FaultInjected" in outq.query("hit", timeout_s=2)["error"]
+        assert terminals == {"hit": 1}
+        assert not srv._caches[0]["k"].is_deleted()
+        snap = self._served_again(lm, srv, inq, outq,
+                                  [self.PREFIX + [4], [2, 3, 5]], free0)
+        assert snap["kv_pool_rebuilds"] == 0

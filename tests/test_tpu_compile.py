@@ -322,6 +322,72 @@ def test_embedding_rule_matches_compiler(v5e, table, n_ids, bag, why):
             t, ids).compile()
 
 
+# -- the serving programs update the KV page pools in place --------------------
+
+_POOL = (3073, 16, 12 * 64)   # gpt2_small's cell: pages, positions, H*D
+
+
+def _gpt2_small_server(tmp_path, int8):
+    """``GenerativeServing`` over GPT-2 small with described parameters and
+    a two-page pool: its jitted programs are lowered below for pools of the
+    benchmark's size, which are never allocated."""
+    from analytics_zoo_tpu.capture.lm import TransformerLM
+    from analytics_zoo_tpu.serving import GenerativeServing, ServingConfig
+    lm = TransformerLM(vocab_size=50257, hidden=768, n_block=12, n_head=12,
+                       max_len=1024)
+    lm._graph.estimator.params = jax.eval_shape(
+        lm._init_params, jax.random.PRNGKey(0), None)
+    return GenerativeServing(ServingConfig(
+        data_src=f"dir://{tmp_path}/q", slots=48, kv_pages=2,
+        kv_page_len=16, kv_int8=int8), lm)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("program", ["decode_step", "prefill", "page_copy"])
+def test_serving_programs_keep_the_page_pools_in_place(
+        v5e, monkeypatch, tmp_path, program, int8):
+    """The programs as the server declares them (pools donated), compiled
+    for the chip at the benchmark's pool shape: every pool leaf is aliased
+    to an output, and no ``copy`` of a whole pool is left in the program.
+    ``[P, H, page_len, D]`` pools cost two such copies a pool in every one
+    of these programs, with or without donation (PERF.md, PR 26)."""
+    import re
+    from analytics_zoo_tpu.ops import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    srv = _gpt2_small_server(tmp_path, int8)
+
+    def described(tree):
+        return jax.tree_util.tree_map(lambda a: v5e(a.shape, a.dtype), tree)
+
+    params, state = described(srv._params), described(srv._state)
+    pools = described(jax.eval_shape(
+        lambda: srv.lm.init_paged_caches(_POOL[0], _POOL[1], int8=int8)))
+    assert pools[0]["k"].shape == _POOL
+    table, row, scalar = v5e((48, 64), I32), v5e((64,), I32), v5e((), I32)
+    if program == "decode_step":
+        lowered = srv._step_fn.lower(params, v5e((48,), I32),
+                                     v5e((48, 2), jnp.uint32), state, table,
+                                     pools)
+    elif program == "prefill":
+        lowered = srv._prefill_paged_fn.lower(
+            params, v5e((1, 256), I32), pools, state, table, row, scalar,
+            scalar)
+    else:
+        lowered = srv._copy_fn.lower(pools, scalar, scalar)
+    text = lowered.compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    leaves = {int(n) for n in re.findall(
+        r"%caches_\S+ = \S+ parameter\((\d+)\)", entry)}
+    assert len(leaves) == len(jax.tree_util.tree_leaves(pools))
+    aliased = {int(n) for n in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
+    assert leaves <= aliased, sorted(leaves - aliased)
+    dims = ",".join(map(str, _POOL))
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= \w+\[%s\]\S* copy\(" % dims, line)]
+    assert not copies, copies
+
+
 # -- which branch ran: the reason strings, on the CPU -------------------------
 
 def test_fallback_reasons_are_logged_once(monkeypatch, caplog):
