@@ -52,7 +52,9 @@ _GAUGE_UNITLESS_OK = {"serving.in_flight", "serving.slots_occupied",
                       "serving.kv_pages_free", "build.info",
                       "fleet.instances_alive", "fleet.desired_instances",
                       "cluster.leases_alive", "serving.brownout_level",
-                      "fleet.breaker_state"}
+                      "fleet.breaker_state", "serving.state_slots_in_use"}
+#: histograms of a count, not of a duration: exempt from the suffix rule
+_HISTOGRAM_UNITLESS_OK = {"serving.sparse_positions_read"}
 
 
 def _is_registration(node: ast.Call) -> bool:
@@ -138,7 +140,8 @@ def findings() -> List[Finding]:
                 f"'subsystem.noun_unit' convention (lower_snake, one dot)",
                 "rename to subsystem.noun_unit"))
         suffix = _UNIT_SUFFIX.get(kind)
-        if suffix and not name.endswith(suffix):
+        if (suffix and not name.endswith(suffix)
+                and name not in _HISTOGRAM_UNITLESS_OK):
             out.append(Finding(
                 path, line, MetricNamesPass.id,
                 f"{kind} {name!r} ({places[0][0]}) must end in "

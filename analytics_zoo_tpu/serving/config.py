@@ -49,7 +49,9 @@ class ServingConfig:
     #   per-slot rectangles (the PR 8 engine). Page 0 is the null page, so
     #   kv_pages - 1 pages are allocatable.
     kv_page_len: int = 16  # tokens per page; must divide the LM's max_len
-    #   and be a power of two <= 16 (so it divides every prefill bucket)
+    #   and be a power of two <= 16 (so it divides every prefill bucket);
+    #   a model with sparse-attention layers asks for its selection block
+    #   (capture/decoder.py: 64 for MiniCPM-SALA)
     kv_int8: bool = False  # int8 KV pool (delayed-scaling quantization)
     kv_shard: int = 1  # devices the pool's PAGE axis shards over (a model
     #   whose KV exceeds one device's HBM spreads pages across the mesh;

@@ -50,6 +50,13 @@ SHED_ERROR = "shed: queue overloaded"
 PAGE_SHED_ERROR = "shed: kv page pool exhausted"
 DEADLINE_ERROR = "deadline exceeded"
 SHUTDOWN_ERROR = "serving shut down before this request completed"
+# Chunked prefill: decode steps of the resident streams after each chunk of a
+# joining prompt. On the chip a chunk of 2,048 positions takes as long as four
+# to five steps (147 ms against 33 ms an iteration), so with 5 a joining
+# prompt gets at most half of the loop and a resident stream's tokens keep
+# coming at half their pace instead of a sixth; with 1 the token rate
+# swings fivefold with every join (docs/serving.md).
+DECODE_STEPS_PER_CHUNK = 5
 
 #: SLO telemetry in the shared registry (common/metrics.py). Every family
 #: is labeled by server instance so two servers in one process (tests, the
@@ -127,6 +134,31 @@ _M_SPEC_ACCEPT = _metrics.gauge(
     "serving.spec_accept_ratio",
     "Mean fraction of draft tokens accepted in the last verify round.",
     labels=("server",))
+#: chunked prefill and the recurrent state (models with linear-attention
+#: layers, capture/decoder.py); they read nought for every other model
+_M_GEN_COUNTERS = {
+    "prefill_chunks": _metrics.counter(
+        "serving.prefill_chunks_total",
+        "Chunks of joining prompts dispatched (chunked prefill).",
+        labels=("server",)),
+    "prompt_tokens": _metrics.counter(
+        "serving.prompt_tokens_total",
+        "Prompt positions prefilled chunk by chunk.", labels=("server",)),
+    "steps_between_chunks": _metrics.counter(
+        "serving.steps_between_chunks_total",
+        "Decode steps of the resident streams that ran while a joining "
+        "prompt was between two of its chunks or waited for its first.",
+        labels=("server",)),
+}
+_M_STATE_SLOTS = _metrics.gauge(
+    "serving.state_slots_in_use",
+    "Slots whose recurrent state is live: resident streams and prompts "
+    "being prefilled.", labels=("server",))
+_M_SPARSE_READ = _metrics.histogram(
+    "serving.sparse_positions_read",
+    "Positions that a sparse-attention layer's gather read for one stream "
+    "in one decode step, as the step program counted them: one "
+    "observation a step.", labels=("server",))
 _M_BROWNOUT = _metrics.gauge(
     "serving.brownout_level",
     "Current brownout degradation rung: 0=normal, 1=coarse streaming/wide "
@@ -1209,12 +1241,43 @@ class GenerativeServing:
                              "greedy-only (per-request sampled accept is a "
                              "follow-up); unset temperature/top_k/top_p")
         self._spec_k = int(config.spec_k) if self._spec else 0
+        # a model with recurrent layers (capture/decoder.py): a state a slot
+        # beside the pages, prefill in chunks; what would need a snapshot of
+        # the state, or a pool the sparse read cannot gather from, is
+        # refused here rather than run wrongly
+        self._recurrent = bool(getattr(lm, "recurrent", False))
+        if self._recurrent:
+            why = "a model with recurrent (linear-attention) layers"
+            if not self._paged:
+                raise ValueError(f"{why} is served by the paged engine: "
+                                 f"set kv_pages")
+            if draft_lm is not None or config.spec_k:
+                raise ValueError(
+                    f"speculative decoding is refused for {why}: rejected "
+                    f"drafts would have to be rolled back out of the state")
+            if config.kv_int8:
+                raise ValueError(
+                    f"kv_int8 is refused for {why}: its sparse layers' "
+                    f"selected-page read has no dequantising gather")
+            if int(getattr(config, "kv_shard", 1) or 1) > 1:
+                raise ValueError(
+                    f"kv_shard is refused for {why}: the state a slot is "
+                    f"not sharded with the pages")
+            if self._sampling:
+                raise ValueError(f"sampling is not wired for {why} yet: "
+                                 f"unset temperature/top_k/top_p")
         # -- device state: per-block slot caches + ONE shared occupancy ---
         self._params = lm.params
         if self._paged:
             pl = int(config.kv_page_len)
             num_pages = int(config.kv_pages)
-            if pl < 1 or (pl & (pl - 1)) or pl > 16:
+            if self._recurrent:
+                # one page is one block of the model's sparse selection
+                if pl != lm.page_len:
+                    raise ValueError(
+                        f"kv_page_len must be the model's selection "
+                        f"block, {lm.page_len}; got {pl}")
+            elif pl < 1 or (pl & (pl - 1)) or pl > 16:
                 raise ValueError(f"kv_page_len must be a power of two "
                                  f"<= 16 (divides every prefill bucket), "
                                  f"got {pl}")
@@ -1265,10 +1328,19 @@ class GenerativeServing:
             return nxt, state, caches
 
         def _step_paged(params, tokens, keys, state, table, caches):
-            logits, caches = lm.paged_slot_step(params, tokens,
-                                                state["length"], table,
-                                                caches)
+            if self._recurrent:
+                # a recurrent layer's state moves for active slots only: a
+                # prompt between two chunks keeps what its last chunk left
+                logits, caches, read = lm.paged_state_step(
+                    params, tokens, state["length"], table, caches,
+                    state["active"])
+            else:
+                logits, caches = lm.paged_slot_step(params, tokens,
+                                                    state["length"], table,
+                                                    caches)
             nxt = _select(logits, keys)
+            if self._recurrent:  # the host fetches both
+                nxt = (nxt, read)
             state = {"length": (state["length"]
                                 + state["active"].astype(jnp.int32)),
                      "active": state["active"]}
@@ -1338,6 +1410,18 @@ class GenerativeServing:
             return (caches, slot_join(state, slot, length),
                     page_table_set(table, slot, row))
 
+        def _prefill_chunk(params, padded, caches, state, table, row, slot,
+                           start, n_valid, length, last):
+            """One chunk of a joining prompt (chunked prefill): states and
+            pages carried on from the chunk before; the ``last`` one
+            joins the slot and installs its table row."""
+            caches = lm.prefill_chunk(params, padded, caches, row, slot,
+                                      start, n_valid)
+            joined = slot_join(state, slot, length)
+            state = {k: jnp.where(last, joined[k], state[k]) for k in state}
+            table = jnp.where(last, page_table_set(table, slot, row), table)
+            return caches, state, table
+
         def _prefill_prefix(params, padded, caches, row):
             kvs = lm.prefill_kv(params, padded)
             return [paged_insert(c, row, k[0], v[0])
@@ -1373,6 +1457,9 @@ class GenerativeServing:
             self._copy_fn = jax.jit(_copy_pages, donate_argnames=pools)
             self._table_set_fn = jax.jit(page_table_set)
             self._table_clear_fn = jax.jit(page_table_clear)
+            if self._recurrent:  # one compile per chunk bucket
+                self._prefill_chunk_fn = jax.jit(_prefill_chunk,
+                                                 donate_argnames=pools)
         else:
             self._prefill_fn = jax.jit(_prefill)  # one compile per bucket
         self._join_fn = jax.jit(slot_join)    # T==1 prompts: no prefill
@@ -1392,6 +1479,11 @@ class GenerativeServing:
         self._keys: List[Optional[np.ndarray]] = [None] * s
         self._next_tokens = np.zeros(s, np.int32)
         self._active_host = np.zeros(s, bool)
+        # chunked prefill: prompts claimed and not yet resident, oldest
+        # first, each holding its slot
+        self._prefilling: List[Dict[str, Any]] = []
+        self._steps_since_chunk = DECODE_STEPS_PER_CHUNK
+        self._reserved = np.zeros(s, bool)
         # continuation-on-failover bookkeeping: the original prompt, seed
         # and deadline ride along so a drain handoff can re-enqueue the
         # stream with its accumulated prefix (docs/fleet.md)
@@ -1401,7 +1493,12 @@ class GenerativeServing:
         # -- SLO bookkeeping (same registry families as ClusterServing) ---
         self.metrics_label = f"srv{next(_instance_ids)}"
         self._m = {key: fam.labels(server=self.metrics_label)
-                   for key, fam in _M_COUNTERS.items()}
+                   for key, fam in {**_M_COUNTERS,
+                                    **_M_GEN_COUNTERS}.items()}
+        self._m_state_slots = _M_STATE_SLOTS.labels(
+            server=self.metrics_label)
+        self._m_sparse_read = _M_SPARSE_READ.labels(
+            server=self.metrics_label)
         self._m_records = _M_RECORDS.labels(server=self.metrics_label)
         self._m_latency = _M_LATENCY.labels(server=self.metrics_label)
         self._m_depth = _M_QUEUE_DEPTH.labels(server=self.metrics_label)
@@ -1527,8 +1624,10 @@ class GenerativeServing:
 
         from ..ops.decode import init_slot_state, shard_paged_pool
         if self._paged:
+            more = {"slots": self.slots} if self._recurrent else {}
             self._caches = self.lm.init_paged_caches(
-                self.num_pages, self.page_len, int8=self.config.kv_int8)
+                self.num_pages, self.page_len, int8=self.config.kv_int8,
+                **more)
             if self._kv_shard > 1:
                 # page axis spread over kv_shard devices; decode gathers
                 # each stream's pages to the compute device, so tokens
@@ -1749,6 +1848,12 @@ class GenerativeServing:
             raise RuntimeError("shared prefixes are not wired into the "
                                "speculative scheduler yet (the draft "
                                "cache is contiguous)")
+        if self._recurrent:
+            raise RuntimeError(
+                "register_prefix is refused for a model with recurrent "
+                "(linear-attention) layers: a shared prefix would need a "
+                "snapshot of every layer's state at its end, which is not "
+                "kept")
         from ..capture.lm import prefill_bucket
         toks = [int(x) for x in tokens]
         n = len(toks)
@@ -1774,6 +1879,22 @@ class GenerativeServing:
         self._m_pages_free.set(len(self._free_pages))
         return len(self._prefixes) - 1
 
+    def _take_pages(self, uri: str, needed: int) -> Optional[List[int]]:
+        """``needed`` pages off the free stack for the request ``uri``, or
+        ``None`` after shedding it: pool exhaustion (or the armed
+        ``serving.page_alloc`` fault) answers the request with the page
+        shed error, and every resident stream keeps decoding."""
+        # chaos site: pool exhaustion at join → shed-or-evict, not a crash
+        if (faults.inject("serving.page_alloc")
+                or len(self._free_pages) < needed):
+            self._post_terminal(uri, {"error": PAGE_SHED_ERROR})
+            self._count("shed")
+            logger.warning(
+                "kv page pool exhausted: shed %s (need %d pages, %d free)",
+                uri, needed, len(self._free_pages))
+            return None
+        return [self._free_pages.pop() for _ in range(needed)]
+
     def _join_paged(self, slot: int, uri: str, prompt, t: int,
                     budget: int) -> bool:
         """Allocate pages for a validated request and prefill it into
@@ -1798,16 +1919,9 @@ class GenerativeServing:
         # bucket padding past the table width is never visible and never
         # decoded over — the null page absorbs it; no page needed
         fresh_needed = min(-(-high // pl), self._table_w) - full
-        # chaos site: pool exhaustion at join → shed-or-evict, not a crash
-        if (faults.inject("serving.page_alloc")
-                or len(self._free_pages) < fresh_needed):
-            self._post_terminal(uri, {"error": PAGE_SHED_ERROR})
-            self._count("shed")
-            logger.warning(
-                "kv page pool exhausted: shed %s (need %d pages, %d free)",
-                uri, fresh_needed, len(self._free_pages))
+        fresh = self._take_pages(uri, fresh_needed)
+        if fresh is None:
             return False
-        fresh = [self._free_pages.pop() for _ in range(fresh_needed)]
         shared = [int(p) for p in pfx["pages"][:full]] if pfx else []
         row = np.zeros(self._table_w, np.int32)
         row[:full] = shared
@@ -1912,6 +2026,14 @@ class GenerativeServing:
         # the profiler's phase for what follows keeps the name it has in
         # ClusterServing, host_input; here it is the prefill's dispatch
         t0 = time.perf_counter()
+        if self._recurrent:
+            # pages now, chunks over the coming iterations; the stream is
+            # resident once its last chunk has run (_advance_prefill)
+            began = self._begin_prefill(slot, uri, rec, prompt, prefix,
+                                        budget, exp, now)
+            _profiler.record_phase("serving", "host_input",
+                                   time.perf_counter() - t0, start=t0)
+            return began
         if self._paged:
             if not self._join_paged(slot, uri, full, t_full,
                                     budget - len(prefix)):
@@ -1933,6 +2055,16 @@ class GenerativeServing:
                                         np.int32(0))
         _profiler.record_phase("serving", "host_input",
                                time.perf_counter() - t0, start=t0)
+        self._activate(slot, uri, rec, prompt, prefix, budget, exp, now)
+        return True
+
+    def _activate(self, slot: int, uri: str, rec: Dict[str, Any], prompt,
+                  prefix, budget: int, exp: Optional[float],
+                  now: float) -> None:
+        """The host's bookkeeping of a stream that is now resident in
+        ``slot``: its prompt is in the caches and the next step decodes
+        its first token."""
+        full = prompt + prefix
         self._uri[slot] = uri
         self._tokens[slot] = list(prefix)
         self._budget[slot] = budget
@@ -1958,10 +2090,92 @@ class GenerativeServing:
             self._seed[slot] = int(seed)
             self._keys[slot] = self._split(int(seed), budget)
         self._active_host[slot] = True
+
+    # -- chunked prefill (models with recurrent layers) ----------------------
+
+    def _begin_prefill(self, slot: int, uri: str, rec: Dict[str, Any],
+                       prompt, prefix, budget: int, exp: Optional[float],
+                       now: float) -> bool:
+        """Give a validated request its pages and queue its prompt for
+        prefill in chunks; the slot is held from now on. Pool exhaustion
+        sheds the request, as in :meth:`_join_paged`."""
+        full = prompt + prefix
+        fed = len(full) - 1
+        plan = self.lm.chunk_plan(fed)
+        high = max(plan[-1][0] + plan[-1][1],
+                   len(full) + budget - len(prefix))
+        needed = min(-(-high // self.page_len), self._table_w)
+        pages = self._take_pages(uri, needed)
+        if pages is None:
+            return False
+        row = np.zeros(self._table_w, np.int32)
+        row[:needed] = pages
+        for p in pages:
+            self._page_refs[p] = 1
+        self._slot_pages[slot] = pages
+        self._m_pages_free.set(len(self._free_pages))
+        self._reserved[slot] = True
+        self._prefilling.append({
+            "slot": slot, "uri": uri, "rec": rec, "prompt": prompt,
+            "prefix": prefix, "budget": budget, "exp": exp, "now": now,
+            "full": full, "fed": fed, "plan": plan, "row": row, "next": 0})
+        return True
+
+    def _drop_prefill(self, job: Dict[str, Any], value: Dict[str, Any],
+                      counter: Optional[str]) -> None:
+        """End a joining prompt before it is resident: its one terminal,
+        its pages back, its slot free. What its chunks wrote stays where
+        it is: the next join of the slot overwrites the states."""
+        self._post_terminal(job["uri"], value)
+        if counter is not None:
+            self._count(counter)
+        self._release_pages(job["slot"])
+        self._reserved[job["slot"]] = False
+
+    def _advance_prefill(self) -> bool:
+        """Dispatch the next chunk of the oldest joining prompt; its last
+        chunk makes the stream resident. Returns whether a chunk ran."""
+        job = self._prefilling[0]
+        if job["exp"] is not None and wall_clock() >= job["exp"]:
+            self._prefilling.pop(0)
+            self._drop_prefill(job, {"error": DEADLINE_ERROR}, "expired")
+            return False
+        slot, index, count = job["slot"], job["next"], len(job["plan"])
+        start, width = job["plan"][index]
+        n = max(0, min(width, job["fed"] - start))
+        padded = np.zeros((1, width), np.int32)
+        padded[0, :n] = job["full"][start:start + n]
+        last = index == count - 1
+        t0 = time.perf_counter()
+        try:
+            self._caches, self._state, self._table = self._prefill_chunk_fn(
+                self._params, padded, self._caches, self._state,
+                self._table, job["row"], np.int32(slot), np.int32(start),
+                np.int32(n), np.int32(job["fed"]), np.bool_(last))
+        except Exception as e:
+            # the chunk had been given the caches: as after a failed
+            # prefill, everything resident or joining gets its terminal
+            logger.exception("prefill chunk %d/%d of %s failed", index + 1,
+                             count, job["uri"])
+            self._fail_active(repr(e), rebuild=True)
+            return False
+        if _utils.span_hooks:
+            _utils.offer_span("serve.prefill_chunk", t0,
+                              time.perf_counter() - t0)
+        self._count("prefill_chunks")
+        self._count("prompt_tokens", n)
+        job["next"] += 1
+        if last:
+            self._prefilling.pop(0)
+            self._reserved[slot] = False
+            self._activate(slot, job["uri"], job["rec"], job["prompt"],
+                           job["prefix"], job["budget"], job["exp"],
+                           job["now"])
         return True
 
     def _admit(self) -> None:
-        free = [i for i in range(self.slots) if not self._active_host[i]]
+        free = [i for i in range(self.slots)
+                if not self._active_host[i] and not self._reserved[i]]
         if not free:
             return
         with time_it("serve.admit"):
@@ -2042,6 +2256,9 @@ class GenerativeServing:
             if self._active_host[i]:
                 mask[i] = True
                 self._retire(i, {"error": message}, counter="errors")
+        jobs, self._prefilling = self._prefilling, []
+        for job in jobs:  # joining prompts lose what their chunks wrote
+            self._drop_prefill(job, {"error": message}, "errors")
         if rebuild:
             self._rebuild_pools()
         elif mask.any():
@@ -2187,10 +2404,21 @@ class GenerativeServing:
             self._expire_slots()
         if not self._draining.is_set():
             self._admit()
+        # chunked prefill: one chunk of the oldest joining prompt, then
+        # DECODE_STEPS_PER_CHUNK iterations of the resident streams' decode
+        # step below before the next chunk (none where nothing is resident)
+        chunked = (bool(self._prefilling)
+                   and (self._steps_since_chunk >= DECODE_STEPS_PER_CHUNK
+                        or not self._active_host.any())
+                   and self._advance_prefill())
+        if chunked:
+            self._steps_since_chunk = 0
         n_active = int(np.sum(self._active_host))
         self._m_slots.set(n_active)
-        if n_active == 0:
-            return 0
+        if self._recurrent:
+            self._m_state_slots.set(n_active + len(self._prefilling))
+        if n_active == 0:  # a prompt still joining keeps the loop awake
+            return int(chunked or bool(self._prefilling))
         tokens = np.ascontiguousarray(self._next_tokens)
         keys = np.zeros((self.slots, 2), np.uint32)
         if self._sampling:
@@ -2209,8 +2437,10 @@ class GenerativeServing:
                 em_host = self._fetch_tokens(emitted)
                 n_host = self._fetch_tokens(n_acc)
             else:
-                nxt_host = self._fetch_tokens(
-                    self._dispatch_step(tokens, keys))
+                out = self._dispatch_step(tokens, keys)
+                if self._recurrent:
+                    out, read = out
+                nxt_host = self._fetch_tokens(out)
         except Exception as e:
             logger.exception("decode step failed for %d streams", n_active)
             self._fail_active(repr(e), rebuild=given)
@@ -2228,6 +2458,11 @@ class GenerativeServing:
         per = (time.perf_counter() - t_step) / n_active
         self._ewma_token_s = (per if self._ewma_token_s == 0.0
                               else 0.8 * self._ewma_token_s + 0.2 * per)
+        if self._recurrent:
+            self._steps_since_chunk += 1
+            if self._prefilling:
+                self._count("steps_between_chunks")
+            self._m_sparse_read.observe(float(read))
         with time_it("serve.post"):
             self._post_tokens(nxt_host)
         return n_active
@@ -2356,6 +2591,20 @@ class GenerativeServing:
                 continue
             self._abandon(i)
             moved += 1
+        jobs, self._prefilling = self._prefilling, []
+        for job in jobs:  # a prompt between two chunks goes back whole
+            try:
+                to_queue.enqueue(job["uri"], job["rec"])
+            except Exception:
+                logger.exception("handoff enqueue for %s failed", job["uri"])
+                self._drop_prefill(job, {"error": SHUTDOWN_ERROR}, "errors")
+                continue
+            with self._counter_lock:
+                self._in_flight = max(0, self._in_flight - 1)
+                self._meta.pop(job["uri"], None)
+            self._release_pages(job["slot"])
+            self._reserved[job["slot"]] = False
+            moved += 1
         if mask.any():
             self._evict_slots(mask)
         if self._terminal_state is None:
@@ -2444,6 +2693,19 @@ class GenerativeServing:
                 round(float(self._m_spec_accept.value()), 4)
                 if self._spec else None),
             "brownout_level": self._brownout.level,
+            "prefills_pending": len(self._prefilling),
+            "prefill_chunks_total": int(self._m["prefill_chunks"].value()),
+            "prompt_tokens_total": int(self._m["prompt_tokens"].value()),
+            "steps_between_chunks_total": int(
+                self._m["steps_between_chunks"].value()),
+            "state_slots_in_use": (
+                int(np.sum(self._active_host)) + len(self._prefilling)
+                if self._recurrent else None),
+            "sparse_positions_read": {
+                "mean": (round(self._m_sparse_read.sum()
+                               / self._m_sparse_read.count(), 1)
+                         if self._m_sparse_read.count() else None),
+                "window": self._m_sparse_read.count()},
             "last_claim_age_s": claim_age,
             "ttft_ms": {"p50": _pct(self._m_ttft, 0.50),
                         "p99": _pct(self._m_ttft, 0.99),

@@ -388,6 +388,70 @@ def test_serving_programs_keep_the_page_pools_in_place(
     assert not copies, copies
 
 
+_SALA = dict(slots=12, pages=4705, page_len=64, width=392)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "chunk_2048",
+                                     "chunk_512"])
+def test_sala_programs_keep_pools_and_states_in_place(v5e, tmp_path,
+                                                      program):
+    """MiniCPM-SALA's decode step and prefill chunk as the server declares
+    them, compiled for the chip at the benchmark's sizes (16 layers at the
+    published widths, 4,705 pages of 64, 12 slots): every cache leaf (K/V
+    pages, compressed keys, lightning states) is aliased to an output, and
+    no copy of a whole pool or of the states is left in the program; the
+    program and its temporaries fit the chip beside the weights."""
+    import json
+    import pathlib
+    import re
+    from analytics_zoo_tpu.capture.decoder import DecoderSpec, LayeredDecoder
+    from analytics_zoo_tpu.serving import GenerativeServing, ServingConfig
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cfg = json.loads((root / "perfbench" / "configs"
+                      / "minicpm_sala.json").read_text())
+    lm = LayeredDecoder(DecoderSpec.from_config(cfg, cfg["n_positions"]))
+    lm.set_params(jax.eval_shape(lm.init_params))
+    slots = _SALA["slots"]
+    srv = GenerativeServing(ServingConfig(
+        data_src=f"dir://{tmp_path}/q", slots=slots, kv_pages=2,
+        kv_page_len=_SALA["page_len"]), lm)
+
+    def described(tree):
+        return jax.tree_util.tree_map(lambda a: v5e(a.shape, a.dtype), tree)
+
+    params, state = described(srv._params), described(srv._state)
+    pools = described(jax.eval_shape(lambda: lm.init_paged_caches(
+        _SALA["pages"], _SALA["page_len"], slots=slots)))
+    table = v5e((slots, _SALA["width"]), I32)
+    row, scalar = v5e((_SALA["width"],), I32), v5e((), I32)
+    if program == "decode_step":
+        lowered = srv._step_fn.lower(params, v5e((slots,), I32),
+                                     v5e((slots, 2), jnp.uint32), state,
+                                     table, pools)
+    else:
+        width = int(program.split("_")[1])
+        lowered = srv._prefill_chunk_fn.lower(
+            params, v5e((1, width), I32), pools, state, table, row, scalar,
+            scalar, scalar, scalar, v5e((), jnp.bool_))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    leaves = {int(n) for n in re.findall(
+        r"%caches_\S+ = \S+ parameter\((\d+)\)", entry)}
+    assert len(leaves) == len(jax.tree_util.tree_leaves(pools)) == 24
+    aliased = {int(n) for n in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
+    assert leaves <= aliased, sorted(leaves - aliased)
+    for dims in ("4705,64,256", "4705,4,256", "12,32,128,128"):
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(r"= \w+\[%s\]\S* copy\(" % dims, line)]
+        assert not copies, copies
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    assert held < 14.5e9, held   # of the chip's 16 GB
+
+
 # -- which branch ran: the reason strings, on the CPU -------------------------
 
 def test_fallback_reasons_are_logged_once(monkeypatch, caplog):
