@@ -23,7 +23,8 @@ from ..ops.attention import (flash_attention, fused_short_applicable,
 from ..ops.decode import (beam_generate, cached_attention,
                           greedy_generate, init_kv_cache, init_paged_pool,
                           init_slot_cache, paged_attention, paged_insert,
-                          paged_verify_attention, sample_generate,
+                          paged_pages_read, paged_verify_attention,
+                          sample_generate,
                           slot_attention, slot_insert, speculative_generate)
 
 #: prefill length buckets: prompts are right-padded to the smallest bucket
@@ -359,26 +360,38 @@ class TransformerLM:
                 for _ in range(self.n_block)]
 
     def paged_slot_step(self, params, tokens, lengths, table, caches):
-        """``slot_step`` against the paged pool: same contract, but each
-        slot's K/V lives in the pages its ``table`` row names instead of a
-        private ``max_len`` rectangle. Bit-identical to ``slot_step`` (the
-        gathered buffer differs from the contiguous one only at
-        masked-to-exact-zero positions)."""
+        """``slot_step`` against the paged pool: each slot's K/V lives in
+        the pages its ``table`` row names instead of a private ``max_len``
+        rectangle. Returns ``(next-token logits [S, V], updated caches,
+        pages read)``: the last is what one block's attention read for all
+        slots, counted here from ``lengths`` by the form that runs
+        (``ops/decode.py paged_pages_read``).
+
+        Off the TPU, and on it for int8 pools and programs over several
+        devices, the XLA form runs and the step is bit-identical to
+        ``slot_step`` (the gathered buffer differs from the contiguous one
+        only at masked-to-exact-zero positions). On one TPU chip over an
+        unquantised pool the kernel reads the live pages in place; the
+        benchmark's ``correct`` holds it there (``served_logit_gap_max``)."""
         tokens = jnp.asarray(tokens, jnp.int32)
         with jax.named_scope("embed"):
             x = (params["embed"][tokens][:, None]
                  + params["pos"][lengths][:, None])
         new_caches = []
-        for p, cache in zip(params["blocks"], caches):
-            holder = {}
+        # the mesh under the parameters says whether the program spans
+        # several devices, where no Mosaic kernel can go (ops/dispatch.py)
+        with dispatch.partitioned_over(self._graph.estimator.mesh):
+            read = paged_pages_read(caches[0], table, lengths, self.max_len)
+            for p, cache in zip(params["blocks"], caches):
+                holder = {}
 
-            def kv_fn(q, k, v, cache=cache, holder=holder):
-                ctx, holder["cache"] = paged_attention(
-                    q, k, v, cache, table, lengths, self.max_len)
-                return ctx
-            x = self._block(p, x, kv_fn)
-            new_caches.append(holder["cache"])
-        return self._head(params, x, last_only=True), new_caches
+                def kv_fn(q, k, v, cache=cache, holder=holder):
+                    ctx, holder["cache"] = paged_attention(
+                        q, k, v, cache, table, lengths, self.max_len)
+                    return ctx
+                x = self._block(p, x, kv_fn)
+                new_caches.append(holder["cache"])
+        return self._head(params, x, last_only=True), new_caches, read
 
     def verify_step(self, params, blocks, lengths, table, caches):
         """Speculative verify: feed ``blocks`` [S, T] (last committed token

@@ -90,7 +90,12 @@ SLOT_OPS = ("init_slot_cache", "slot_join", "slot_evict", "slot_insert",
 PAGED_OPS = ("init_paged_pool", "page_table_set", "page_table_clear",
              "page_copy", "_page_positions", "_paged_write", "paged_gather",
              "paged_insert", "paged_attention", "paged_verify_attention",
+             "paged_decode_context", "paged_pages_read", "paged_prefix_kv",
              "spec_accept_greedy", "_spec_accept_sampled")
+#: the paged decode kernel's pallas body (ops/decode.py): it traces only
+#: inside ``pl.pallas_call``, which is no discovery root, so the table
+#: polices it; its page copies are ``fori_loop``s, not Python loops
+PAGED_KERNEL_BODIES = ("_paged_decode_kernel",)
 
 HOT_FUNCS = ("evaluate", "_evaluate_direct", "_evaluate_direct_exact",
              "predict")
@@ -124,6 +129,7 @@ _CHECKS: List[Tuple[str, Optional[str], Sequence[str], Sequence[str],
     (EMBED_KERNELS_PY, None, EMBED_KERNEL_WRAPPERS, (), False, "body"),
     (DECODE_PY, None, SLOT_OPS, (), True, "body"),
     (DECODE_PY, None, PAGED_OPS, (), True, "body"),
+    (DECODE_PY, None, PAGED_KERNEL_BODIES, (), True, "body"),
     (LM_PY, "TransformerLM",
      ("slot_step", "prefill_kv", "paged_slot_step", "verify_step",
       "prefill_kv_suffix"), (), False, "body"),

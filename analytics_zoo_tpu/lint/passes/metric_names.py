@@ -54,7 +54,8 @@ _GAUGE_UNITLESS_OK = {"serving.in_flight", "serving.slots_occupied",
                       "cluster.leases_alive", "serving.brownout_level",
                       "fleet.breaker_state", "serving.state_slots_in_use"}
 #: histograms of a count, not of a duration: exempt from the suffix rule
-_HISTOGRAM_UNITLESS_OK = {"serving.sparse_positions_read"}
+_HISTOGRAM_UNITLESS_OK = {"serving.sparse_positions_read",
+                           "serving.paged_pages_read"}
 
 
 def _is_registration(node: ast.Call) -> bool:

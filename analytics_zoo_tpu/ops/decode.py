@@ -14,6 +14,7 @@ only pattern that amortizes dispatch latency on remote-attached chips).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -21,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import dispatch
 from .attention import _NEG_INF, masked_context
 from .int8_dataflow import next_amax, quant_int8, scale_of_amax
 
@@ -231,11 +233,25 @@ def slot_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
 # Page 0 is the NULL page: never allocated to a stream, it absorbs the
 # writes of inactive slots and of positions past a slot's allocation (the
 # same way inactive slots harmlessly write into their own rectangle in the
-# contiguous engine). Bit-identity with the slot engine holds because
-# attention gathers a slot's pages back into logical [max_len] order and
-# runs the SAME masked_context arithmetic: garbage beyond a slot's length —
-# null-page junk here, stale rectangle tail there — is masked to exactly
-# _NEG_INF and contributes exact-zero terms either way.
+# contiguous engine).
+#
+# The decode step reads the pool in one of two forms. The XLA form
+# (everywhere off the TPU, and on it wherever ``_paged_decode_rule`` names a
+# reason) gathers a slot's pages back into logical [max_len] order and runs
+# the SAME masked_context arithmetic as the slot engine: garbage beyond a
+# slot's length — null-page junk here, stale rectangle tail there — is
+# masked to exactly _NEG_INF and contributes exact-zero terms either way.
+# Bit-identity with the slot engine is a property of THAT form, and the
+# tests that hold it run it. On the TPU, over an unquantised pool on one
+# device, the T = 1 step runs ``paged_decode_context`` instead: a pallas
+# kernel that walks each slot's table row up to its length and reads the
+# live [page_len, H*D] pages where they lie, with an online softmax per
+# head (a head is a D-lane slice of the stored row, so nothing is gathered
+# or laid out again). It computes the same mathematics with products at one
+# bfloat16 pass and float32 accumulation, so there the benchmark's
+# ``correct`` holds it by ``served_logit_gap_max``, and
+# tests/test_paged_decode_kernel.py holds it to the XLA form in interpret
+# mode.
 #
 # All shapes are static: tables, lengths and page ids are DATA, so joins,
 # evictions and CoW copies never recompile the step program. The int8
@@ -402,8 +418,10 @@ def paged_gather(cache: PagedCache, table: jax.Array, heads: int
     K/V ``[S, H, C*page_len, D]`` (dequantized to f32 for int8 pools);
     ``heads`` unfolds the pool's flat ``H*D`` rows. This materializes the
     logical view as a TRANSIENT activation — the persistent HBM footprint
-    is the pool; a production TPU kernel would fuse the gather into the
-    attention read (pallas follow-up)."""
+    is the pool. It is the read of the XLA form (bit-identical to the slot
+    engine's rectangle under ``masked_context``): the decode step on the
+    TPU reads the pages in place instead (:func:`paged_decode_context`)
+    wherever :func:`_paged_decode_rule` allows."""
     k = jnp.take(cache["k"], table, axis=0)   # [S, C, page_len, H*D]
     v = jnp.take(cache["v"], table, axis=0)
     if "scale_k" in cache:
@@ -437,18 +455,227 @@ def paged_insert(cache: PagedCache, table_row: jax.Array, k_new: jax.Array,
                         v_new.transpose(1, 0, 2)[None], inline_amax=True)
 
 
+#: pages of one stream the decode kernel holds in VMEM at a time; it holds
+#: two such chunks, the next one's copies in flight while this one is
+#: attended (8 pages of [16, 768] float32, K and V, twice: 1.5 MiB)
+PAGED_DECODE_CHUNK = 8
+
+#: the page table and the lengths ride scalar prefetch, i.e. sit whole in
+#: the chip's scalar memory; a wider table takes the XLA form
+PAGED_DECODE_TABLE_BYTES = 256 * 1024
+
+
+def _paged_decode_rule(cache: PagedCache, table: jax.Array) -> Optional[str]:
+    """Why the T = 1 decode step cannot read this pool in place with
+    :func:`paged_decode_context` (None: it can). The XLA form then runs,
+    and on the TPU that is logged once with the rule."""
+    if "scale_k" in cache:
+        return ("int8 pool: the XLA form's gather dequantises the pages it "
+                "reads")
+    if dispatch.partitioned():
+        return ("the step program spans several devices (a pool sharded "
+                "over pages, a mesh under the parameters), and a Mosaic "
+                "kernel cannot be partitioned automatically")
+    _, page_len, row = cache["k"].shape
+    sublanes = 32 // cache["k"].dtype.itemsize
+    if page_len % sublanes or row % 128:
+        return (f"a page of [{page_len}, {row}] {cache['k'].dtype} is not "
+                f"whole ({sublanes}, 128) tiles")
+    if table.size * 4 > PAGED_DECODE_TABLE_BYTES:
+        return (f"a page table of {table.size} entries exceeds the "
+                f"{PAGED_DECODE_TABLE_BYTES}-byte scalar prefetch budget")
+    return None
+
+
+def _note_xla_form(why: str) -> None:
+    """On the TPU, say once that pages are read by gather-then-attend
+    where no kernel reads them in place, and why. Off the TPU the XLA
+    form is the implementation and not a fallback: nothing is noted."""
+    if dispatch.on_tpu():
+        dispatch.note_fallback("paged_decode", why)
+
+
+def _reads_in_place(cache: PagedCache, table: jax.Array) -> bool:
+    """The kernel on the TPU when its rule holds; otherwise the XLA
+    form."""
+    rule = _paged_decode_rule(cache, table)
+    if rule is not None:
+        _note_xla_form(rule)
+    return rule is None and dispatch.on_tpu()
+
+
+def _paged_decode_kernel(len_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sem, *, heads: int, width: int,
+                         product_dtype):
+    """All slots of one block's decode step. For slot ``s`` the pages
+    ``table[s, 0 .. lengths[s] // page_len]`` come from the pool in HBM
+    into VMEM a chunk at a time (one DMA a page for K and one for V; the
+    next chunk's, or the next slot's first, are started before this one
+    is waited for), and a float32 online softmax runs per head.
+
+    The stored row is ``H*D`` lanes with head ``h`` in lanes ``[h*D,
+    (h+1)*D)``. Scores of all heads at once are one product of the chunk
+    ``[chunk*page_len, H*D]`` with a query block ``[rows, H*D]`` whose row
+    ``h`` holds the query's head ``h`` in its own lanes and zeros
+    elsewhere; ``p @ V`` gives every head's weights over all lanes, of
+    which row ``h`` keeps its own lanes at the end. Pages past the length
+    are neither copied nor counted: the buffer keeps what it held (V is
+    zeroed once, so that it is finite), their scores are forced to
+    ``_NEG_INF`` and their weights are exactly 0."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slots, row = q_ref.shape
+    _, chunk, page_len, _ = k_buf.shape
+    span = chunk * page_len
+    rows = -(-heads // 16) * 16
+    lane_head = lax.broadcasted_iota(jnp.int32, (rows, row), 1) // (
+        row // heads)
+    own = (lane_head == lax.broadcasted_iota(jnp.int32, (rows, row), 0)
+           ).astype(jnp.float32)
+    v_buf[...] = jnp.zeros_like(v_buf)
+
+    def pages_of(s):
+        return jnp.minimum(len_ref[s] // page_len + 1, width)
+
+    def each_page(s, c, buf, act):
+        """``act`` on the copies of chunk ``c`` of slot ``s`` into ``buf``."""
+        def one(j, carry):
+            page = table_ref[s * width + c * chunk + j]
+            act(pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf, j],
+                                      sem.at[0, buf]))
+            act(pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf, j],
+                                      sem.at[1, buf]))
+            return carry
+        lax.fori_loop(0, jnp.minimum(pages_of(s) - c * chunk, chunk), one, 0)
+
+    def start(s, c, buf):
+        each_page(s, c, buf, lambda dma: dma.start())
+
+    start(0, 0, 0)
+
+    def slot(s, buf):
+        length = len_ref[s]
+        chunks = pl.cdiv(pages_of(s), chunk)
+        qb = (own * q_ref[pl.ds(s, 1), :]).astype(product_dtype)
+
+        def attend(c, carry):
+            buf, m, l, acc = carry
+            last = c + 1 == chunks
+
+            @pl.when(jnp.logical_not(last))
+            def _next_chunk():
+                start(s, c + 1, 1 - buf)
+
+            @pl.when(last & (s + 1 < slots))
+            def _next_slot():
+                start(s + 1, 0, 1 - buf)
+
+            each_page(s, c, buf, lambda dma: dma.wait())
+            k = k_buf[buf].reshape(span, row).astype(product_dtype)
+            sc = lax.dot_general(qb, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            pos = c * span + lax.broadcasted_iota(jnp.int32, (rows, span), 1)
+            sc = jnp.where(pos <= length, sc, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            corr = jnp.exp(m - m_new)
+            v = v_buf[buf].reshape(span, row).astype(product_dtype)
+            acc = acc * corr + jnp.dot(p.astype(product_dtype), v,
+                                       preferred_element_type=jnp.float32)
+            return (1 - buf, m_new,
+                    l * corr + jnp.sum(p, axis=1, keepdims=True), acc)
+
+        buf, _, l, acc = lax.fori_loop(
+            0, chunks, attend,
+            (buf, jnp.full((rows, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((rows, 1), jnp.float32),
+             jnp.zeros((rows, row), jnp.float32)))
+        o_ref[pl.ds(s, 1), :] = jnp.sum(own * (acc / l), axis=0,
+                                        keepdims=True).astype(o_ref.dtype)
+        return buf
+
+    lax.fori_loop(0, slots, slot, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "product_dtype"))
+@jax.named_scope("kv_attend")
+def paged_decode_context(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                         table: jax.Array, lengths: jax.Array, scale: float,
+                         product_dtype=jnp.bfloat16) -> jax.Array:
+    """Context of one decode step ``q`` [S, H, 1, D] over the pools as
+    stored, ``[P, page_len, H*D]``, through ``table`` [S, W]: slot ``s``
+    sees positions ``0 .. lengths[s]`` and the kernel brings in the pages
+    that hold them and no others, so its cost follows the live pages and
+    not the table's width (an evicted slot, length 0 and a cleared row,
+    costs the null page). One program for all lengths: table and lengths
+    are data (scalar prefetch). ``product_dtype`` is what the two matrix
+    products multiply in, accumulating in float32: one bfloat16 pass as
+    the TPU's default precision has it; float32 is for holding the
+    arithmetic to :func:`~..attention.masked_context` in interpret mode.
+
+    Jitted on its own, so that a step program traces and lowers the kernel
+    once for all its blocks and not once a block (half a second each,
+    in every process, compile cache or not); the scope is inside the
+    ``jit``, innermost, where XLA takes the Mosaic call's name from."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slots, heads, _, d = q.shape
+    _, page_len, row = k_pool.shape
+    width = table.shape[1]
+    whole = pl.BlockSpec((slots, row), lambda i, *_: (0, 0),
+                         memory_space=pltpu.VMEM)
+    buf = pltpu.VMEM((2, PAGED_DECODE_CHUNK, page_len, row), k_pool.dtype)
+    ctx = pl.pallas_call(
+        functools.partial(_paged_decode_kernel, heads=heads, width=width,
+                          product_dtype=product_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole,
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=jax.ShapeDtypeStruct((slots, row), q.dtype),
+    )(lengths.astype(jnp.int32), table.astype(jnp.int32).reshape(-1),
+      (q * scale).reshape(slots, row), k_pool, v_pool)
+    return ctx.reshape(slots, heads, 1, d)
+
+
+def paged_pages_read(cache: PagedCache, table: jax.Array,
+                     lengths: jax.Array, max_len: int) -> jax.Array:
+    """Pages :func:`paged_attention` reads for all slots in one block of
+    one step, from the form that runs: the live pages under the kernel,
+    the rectangle ``S * max_len // page_len`` under the XLA form. The
+    step program returns it beside the tokens
+    (``serving.paged_pages_read``)."""
+    page_len = cache["k"].shape[1]
+    cols = max_len // page_len
+    if _reads_in_place(cache, table[:, :cols]):
+        return jnp.sum(jnp.minimum(lengths // page_len + 1, cols))
+    return jnp.asarray(table.shape[0] * cols, jnp.int32)
+
+
 def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                     cache: PagedCache, table: jax.Array,
                     lengths: jax.Array, max_len: int,
                     scale: Optional[float] = None
                     ) -> Tuple[jax.Array, PagedCache]:
     """One decode step over ALL slots through the page pool — the paged
-    twin of :func:`slot_attention`, bit-identical to it: write each slot's
-    new K/V at its own ``lengths[s]`` position (scattered to the owning
-    page), gather the first ``max_len // page_len`` table columns back
-    into a logical ``[S, H, max_len, D]`` view, then run the SAME
+    twin of :func:`slot_attention`: write each slot's new K/V at its own
+    ``lengths[s]`` position (scattered to the owning page), then attend
+    each slot's query against positions ``0 .. lengths[s]``.
+
+    The XLA form gathers the first ``max_len // page_len`` table columns
+    back into a logical ``[S, H, max_len, D]`` view and runs the SAME
     :func:`~..attention.masked_context` arithmetic over the SAME key
-    length and visibility mask.
+    length and visibility mask as the slot engine: bit-identical to it,
+    and what runs off the TPU. On the TPU the updated pool is read in
+    place by :func:`paged_decode_context` where :func:`_paged_decode_rule`
+    allows; that kernel agrees with the XLA form to the precision of one
+    bfloat16 pass, and the benchmark's ``correct`` holds it on the chip
+    (``served_logit_gap_max``). The pool returned is the same either way.
 
     ``q``/``k_new``/``v_new``: ``[S, H, 1, D]``; ``lengths``: [S] int32.
     The caller advances lengths once after every block attended, exactly
@@ -459,12 +686,26 @@ def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     pages, offs = _page_positions(table, lengths[:, None], page_len)
     cache = _paged_write(cache, pages, offs, k_new.transpose(0, 2, 1, 3),
                          v_new.transpose(0, 2, 1, 3), inline_amax=False)
-    k_buf, v_buf = paged_gather(cache, table[:, :max_len // page_len],
-                                q.shape[1])
+    cols = table[:, :max_len // page_len]
+    if _reads_in_place(cache, cols):
+        return paged_decode_context(q, cache["k"], cache["v"], cols,
+                                    lengths, scale), cache
+    k_buf, v_buf = paged_gather(cache, cols, q.shape[1])
     key_pos = lax.broadcasted_iota(jnp.int32, (t, max_len), 1)
     visible = key_pos[None] <= lengths[:, None, None]   # [S, 1, max_len]
     ctx = masked_context(q, k_buf, v_buf, visible[:, None], scale)
     return ctx, cache
+
+
+def paged_prefix_kv(cache: PagedCache, row: jax.Array, heads: int,
+                    length: int) -> Tuple[jax.Array, jax.Array]:
+    """K/V ``[1, H, length, D]`` of a shared prefix from the pages
+    ``row`` [W] names, for the dense prefill of a suffix that joins
+    behind it (``length`` is static). Always the XLA gather."""
+    _note_xla_form("a shared-prefix join gathers the prefix's pages for "
+                   "the dense prefill of its suffix")
+    k, v = paged_gather(cache, row[None], heads)
+    return k[:, :, :length], v[:, :, :length]
 
 
 def paged_verify_attention(q: jax.Array, k_new: jax.Array,
@@ -489,6 +730,8 @@ def paged_verify_attention(q: jax.Array, k_new: jax.Array,
     _, _, t, d = q.shape
     page_len = cache["k"].shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    _note_xla_form("the speculative verify step feeds k + 1 tokens a slot, "
+                   "causal among themselves: the kernel is the T = 1 step's")
     positions = (lengths[:, None]
                  + lax.broadcasted_iota(jnp.int32, (q.shape[0], t), 1))
     pages, offs = _page_positions(table, positions, page_len)

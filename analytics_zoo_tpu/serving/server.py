@@ -159,6 +159,13 @@ _M_SPARSE_READ = _metrics.histogram(
     "Positions that a sparse-attention layer's gather read for one stream "
     "in one decode step, as the step program counted them: one "
     "observation a step.", labels=("server",))
+_M_PAGES_READ = _metrics.histogram(
+    "serving.paged_pages_read",
+    "KV pages that the decode step's attention read for all slots in one "
+    "block, as the step program counted them from the lengths it was "
+    "given: the live pages where the kernel reads them in place, the "
+    "table's rectangle under the XLA form; one observation a step.",
+    labels=("server",))
 _M_BROWNOUT = _metrics.gauge(
     "serving.brownout_level",
     "Current brownout degradation rung: 0=normal, 1=coarse streaming/wide "
@@ -1210,8 +1217,9 @@ class GenerativeServing:
 
         from ..ops.decode import (make_logit_filter,
                                   page_copy, page_table_clear,
-                                  page_table_set, paged_gather, paged_insert,
-                                  slot_evict, slot_insert, slot_join,
+                                  page_table_set, paged_insert,
+                                  paged_prefix_kv, slot_evict, slot_insert,
+                                  slot_join,
                                   spec_accept_greedy)
 
         self.config = config
@@ -1335,12 +1343,15 @@ class GenerativeServing:
                     params, tokens, state["length"], table, caches,
                     state["active"])
             else:
-                logits, caches = lm.paged_slot_step(params, tokens,
-                                                    state["length"], table,
-                                                    caches)
+                logits, caches, read = lm.paged_slot_step(
+                    params, tokens, state["length"], table, caches)
             nxt = _select(logits, keys)
             if self._recurrent:  # the host fetches both
                 nxt = (nxt, read)
+            else:
+                # the step's own count of the pages it read rides behind
+                # the tokens: one array, the step's one fetch
+                nxt = jnp.concatenate([nxt.astype(jnp.int32), read[None]])
             state = {"length": (state["length"]
                                 + state["active"].astype(jnp.int32)),
                      "active": state["active"]}
@@ -1402,8 +1413,7 @@ class GenerativeServing:
                             slot, length, plen):
             # gather the shared prefix K/V (refcounted pages, prefilled
             # once) and run only the divergent suffix forward
-            pref = [paged_gather(c, prow[None], lm.n_head) for c in caches]
-            pref = [(k[:, :, :plen], v[:, :, :plen]) for k, v in pref]
+            pref = [paged_prefix_kv(c, prow, lm.n_head, plen) for c in caches]
             kvs = lm.prefill_kv_suffix(params, padded, pref, plen)
             caches = [paged_insert(c, row, k[0], v[0], start=plen)
                       for c, (k, v) in zip(caches, kvs)]
@@ -1499,6 +1509,7 @@ class GenerativeServing:
             server=self.metrics_label)
         self._m_sparse_read = _M_SPARSE_READ.labels(
             server=self.metrics_label)
+        self._m_pages_read = _M_PAGES_READ.labels(server=self.metrics_label)
         self._m_records = _M_RECORDS.labels(server=self.metrics_label)
         self._m_latency = _M_LATENCY.labels(server=self.metrics_label)
         self._m_depth = _M_QUEUE_DEPTH.labels(server=self.metrics_label)
@@ -2441,6 +2452,8 @@ class GenerativeServing:
                 if self._recurrent:
                     out, read = out
                 nxt_host = self._fetch_tokens(out)
+                if self._paged and not self._recurrent:
+                    nxt_host, read = nxt_host[:-1], nxt_host[-1]
         except Exception as e:
             logger.exception("decode step failed for %d streams", n_active)
             self._fail_active(repr(e), rebuild=given)
@@ -2463,6 +2476,8 @@ class GenerativeServing:
             if self._prefilling:
                 self._count("steps_between_chunks")
             self._m_sparse_read.observe(float(read))
+        elif self._paged:
+            self._m_pages_read.observe(float(read))
         with time_it("serve.post"):
             self._post_tokens(nxt_host)
         return n_active
@@ -2648,6 +2663,13 @@ class GenerativeServing:
             v = fam.percentile(p)
             return None if v is None else round(v * 1e3, 3)
 
+        def _mean_of(hist) -> Dict[str, Any]:
+            # a histogram of counts: its values lie above the shared
+            # buckets, so the exact sum over the count, not a percentile
+            n = hist.count()
+            return {"mean": round(hist.sum() / n, 1) if n else None,
+                    "window": n}
+
         err = getattr(self, "_background_error", None)
         if self._terminal_state is not None:
             state = self._terminal_state
@@ -2701,11 +2723,8 @@ class GenerativeServing:
             "state_slots_in_use": (
                 int(np.sum(self._active_host)) + len(self._prefilling)
                 if self._recurrent else None),
-            "sparse_positions_read": {
-                "mean": (round(self._m_sparse_read.sum()
-                               / self._m_sparse_read.count(), 1)
-                         if self._m_sparse_read.count() else None),
-                "window": self._m_sparse_read.count()},
+            "sparse_positions_read": _mean_of(self._m_sparse_read),
+            "paged_pages_read": _mean_of(self._m_pages_read),
             "last_claim_age_s": claim_age,
             "ttft_ms": {"p50": _pct(self._m_ttft, 0.50),
                         "p99": _pct(self._m_ttft, 0.99),

@@ -3953,8 +3953,8 @@ def _ratio_paged(lm, rs, new_tokens: int, plen: int, pstreams: int = 512,
 
     @jax.jit
     def pstep(tokens, state, caches):
-        logits, caches = lm.paged_slot_step(params, tokens,
-                                            state["length"], table, caches)
+        logits, caches, _ = lm.paged_slot_step(
+            params, tokens, state["length"], table, caches)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         state = {"length": state["length"]
                  + state["active"].astype(jnp.int32),
@@ -4293,8 +4293,8 @@ def _ratio_tp():
 
     @jax.jit
     def pstep(tokens, state, caches):
-        logits, caches = lm.paged_slot_step(params, tokens,
-                                            state["length"], table, caches)
+        logits, caches, _ = lm.paged_slot_step(
+            params, tokens, state["length"], table, caches)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         state = {"length": state["length"]
                  + state["active"].astype(jnp.int32),

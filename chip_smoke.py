@@ -69,7 +69,11 @@ KERNELS = dict(
     short_causal=[((8, 12, 512, 64), "bfloat16"),
                   ((1, 12, 16, 64), "float32"),    # TransformerLM prefill
                   ((1, 12, 32, 64), "float32")],
-    table=(2 ** 20, 128), ids=8192, bag=4, scatter_rows=4096)
+    table=(2 ** 20, 128), ids=8192, bag=4, scatter_rows=4096,
+    # GPT-2 small's serving pool: slots, heads, head dim, page length,
+    # table width, pages; a third of the slots empty, the rest mid-stream
+    paged=dict(slots=48, heads=12, dim=64, page_len=16, width=64,
+               pages=3073))
 
 DP = dict(bert=BERT_BASE, seq=128, batch=32, steps=3, lr=5e-5)
 #: on |loss| between the data mesh and one device, bf16 compute. The first
@@ -360,6 +364,54 @@ def phase_kernels(cfg, expect_pallas=True):
             lambda g_, r: jnp.zeros((cfg["scatter_rows"], dim),
                                     jnp.float32).at[r].add(g_, mode="drop"),
             (grads, rows), (1e-5,))
+
+    # the paged decode step through its entry point: on the TPU the kernel
+    # reads each slot's live pages in place, the reference is the XLA form
+    # (write, gather every column, masked_context), and the pools that
+    # come back are the same bit for bit
+    from analytics_zoo_tpu.ops import decode
+    from analytics_zoo_tpu.ops.attention import masked_context
+    pg = cfg["paged"]
+    slots, heads, dim = pg["slots"], pg["heads"], pg["dim"]
+    page_len, width = pg["page_len"], pg["width"]
+    max_len = page_len * width
+    rs = np.random.RandomState(SEED)
+    lengths = np.where(np.arange(slots) % 3 == 0, 0,
+                       rs.randint(0, max_len, slots))
+    lengths[-1] = max_len - 1
+    table = np.zeros((slots, width), np.int32)
+    free = rs.permutation(np.arange(1, pg["pages"]))
+    for i in np.flatnonzero(lengths):
+        need = lengths[i] // page_len + 1
+        table[i, :need], free = free[:need], free[need:]
+    pools = [jax.random.normal(jax.random.fold_in(key, 300 + i),
+                               (pg["pages"], page_len, heads * dim),
+                               jnp.float32) * 0.5 for i in range(2)]
+    step_in = qkv((slots, heads, 1, dim), jnp.float32)
+
+    def paged_step(q, k_new, v_new, k_pool, v_pool, table, lengths):
+        ctx, cache = decode.paged_attention(
+            q, k_new, v_new, {"k": k_pool, "v": v_pool}, table, lengths,
+            max_len)
+        return ctx, cache["k"], cache["v"]
+
+    def paged_xla_form(q, k_new, v_new, k_pool, v_pool, table, lengths):
+        at = decode._page_positions(table, lengths[:, None], page_len)
+        cache = decode._paged_write(
+            {"k": k_pool, "v": v_pool}, *at, k_new.transpose(0, 2, 1, 3),
+            v_new.transpose(0, 2, 1, 3), inline_amax=False)
+        k_buf, v_buf = decode.paged_gather(cache, table, heads)
+        visible = jnp.arange(max_len)[None, None, :] <= lengths[:, None, None]
+        with jax.default_matmul_precision("highest"):
+            ctx = masked_context(q, k_buf, v_buf, visible[:, None],
+                                 dim ** -0.5)
+        return ctx, cache["k"], cache["v"]
+
+    compare("paged_attention decode step (pages read in place)",
+            (slots, heads, max_len, dim), "float32", paged_step,
+            paged_xla_form,
+            (*step_in, *pools, jnp.asarray(table),
+             jnp.asarray(lengths, jnp.int32)), (2e-2, 0.0, 0.0))
 
     emit("kernels", checks=checks, peak_bytes_in_use=_peak_bytes())
     bad = [c["kernel"] for c in checks if not c["ok"]]

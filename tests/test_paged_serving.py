@@ -513,3 +513,60 @@ class TestDonatedPoolFailure:
         snap = self._served_again(lm, srv, inq, outq,
                                   [self.PREFIX + [4], [2, 3, 5]], free0)
         assert snap["kv_pool_rebuilds"] == 0
+
+
+class TestPagesReadCounter:
+    """``serving.paged_pages_read``: what one block's attention read for
+    all slots, counted by the step program and fetched behind the tokens.
+    One request of 3 tokens in 2 slots of 4 pages of 8: every one of its 4
+    decode steps sees length under 8 in one slot and an empty slot."""
+
+    def _serve_one(self, tmp_path, lm, **kw):
+        src = _src(tmp_path)
+        srv = GenerativeServing(_paged_cfg(src, max_new_tokens=4, **kw), lm)
+        InputQueue(src).enqueue_prompt("r", [2, 3, 5])
+        _drive(srv)
+        assert len(OutputQueue(src).query("r", timeout_s=5)["value"]) == 4
+        return srv.health_snapshot()["paged_pages_read"]
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+    def test_the_xla_form_reads_the_rectangle(self, ctx, tmp_path, int8):
+        read = self._serve_one(tmp_path, _lm(), kv_int8=int8)
+        assert read == {"mean": 2 * 4.0, "window": 4}
+        assert "serving_paged_pages_read" in _metrics.expose_text()
+
+    def test_the_kernel_reads_the_live_pages(self, ctx, tmp_path,
+                                             monkeypatch):
+        """The server's own step program with the kernel in it (interpret
+        mode; 2 heads of 64 so that a stored row is whole lanes): one page
+        of the stream and the null page of the empty slot, every step."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        from analytics_zoo_tpu.capture.lm import TransformerLM
+        from analytics_zoo_tpu.ops import dispatch
+        import jax
+        lm = TransformerLM(vocab_size=16, hidden=128, n_block=2, n_head=2,
+                           max_len=32, seed=0)
+        # one device under the parameters, as on one chip (the suite's
+        # context spans 8 virtual devices, and no kernel goes there)
+        lm._graph.estimator.mesh = jax.sharding.Mesh(
+            np.asarray(jax.devices()[:1]), ("data",))
+        lm.fit(np.random.RandomState(0).randint(0, 16, (8, 12)),
+               batch_size=8, epochs=1)
+        monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+        monkeypatch.setattr(dispatch, "_seen", set())
+        with pltpu.force_tpu_interpret_mode():
+            read = self._serve_one(tmp_path, lm)
+        assert read == {"mean": 2.0, "window": 4}
+        assert not [k for k, _ in dispatch.fallbacks_seen()
+                    if k == "paged_decode"]
+
+    def test_speculative_rounds_do_not_observe_it(self, ctx, tmp_path):
+        src = _src(tmp_path)
+        srv = GenerativeServing(_paged_cfg(src, spec_k=3), _lm(),
+                                draft_lm=_lm(max_len=64, seed=1))
+        InputQueue(src).enqueue_prompt("r", [2, 3, 5])
+        _drive(srv)
+        assert OutputQueue(src).query("r", timeout_s=5)["done"]
+        assert srv.health_snapshot()["paged_pages_read"] == {
+            "mean": None, "window": 0}

@@ -337,6 +337,10 @@ def _gpt2_small_server(tmp_path, int8):
                        max_len=1024)
     lm._graph.estimator.params = jax.eval_shape(
         lm._init_params, jax.random.PRNGKey(0), None)
+    # one chip, as in the benchmark's cell (this process has 8 virtual CPU
+    # devices, and kernels trace by the mesh under the parameters)
+    lm._graph.estimator.mesh = jax.sharding.Mesh(
+        np.asarray(jax.devices()[:1]), ("data",))
     return GenerativeServing(ServingConfig(
         data_src=f"dir://{tmp_path}/q", slots=48, kv_pages=2,
         kv_page_len=16, kv_int8=int8), lm)
@@ -350,10 +354,17 @@ def test_serving_programs_keep_the_page_pools_in_place(
     for the chip at the benchmark's pool shape: every pool leaf is aliased
     to an output, and no ``copy`` of a whole pool is left in the program.
     ``[P, H, page_len, D]`` pools cost two such copies a pool in every one
-    of these programs, with or without donation (PERF.md, PR 26)."""
+    of these programs, with or without donation (PERF.md, PR 26).
+
+    The decode step over the float32 pool also holds the kernel that reads
+    the live pages in place, one Mosaic call a block, and none of the
+    dense read's buffers (all 64 pages of all 48 slots gathered, selected
+    and laid out again; PERF.md, PR 28); over the int8 pool it is the XLA
+    form, and says so once."""
     import re
     from analytics_zoo_tpu.ops import dispatch
     monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    monkeypatch.setattr(dispatch, "_seen", set())
     srv = _gpt2_small_server(tmp_path, int8)
 
     def described(tree):
@@ -386,6 +397,19 @@ def test_serving_programs_keep_the_page_pools_in_place(
     copies = [line.strip()[:160] for line in text.splitlines()
               if re.search(r"= \w+\[%s\]\S* copy\(" % dims, line)]
     assert not copies, copies
+    if program != "decode_step":
+        return
+    seen = [rule for kernel, rule in dispatch.fallbacks_seen()
+            if kernel == "paged_decode"]
+    dense = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"\[48,1024,12,64\]|\[48,64,16,768\]", line)]
+    if int8:
+        assert len(seen) == 1 and "int8 pool" in seen[0], seen
+        assert dense
+    else:
+        assert seen == []
+        assert text.count("tpu_custom_call") >= 12
+        assert not dense, dense[:4]
 
 
 _SALA = dict(slots=12, pages=4705, page_len=64, width=392)
@@ -508,7 +532,9 @@ def test_chip_smoke_one_chip_phases_on_cpu(ctx, tmp_path):
         flash_bias=((1, 2, 32, 16), "float32"),
         short_bias=((2, 2, 16, 16), "float32"),
         short_causal=[((1, 2, 16, 16), "float32")],
-        table=(64, 128), ids=16, bag=2, scatter_rows=8),
+        table=(64, 128), ids=16, bag=2, scatter_rows=8,
+        paged=dict(slots=4, heads=2, dim=16, page_len=8, width=4,
+                   pages=17)),
         expect_pallas=False)
     assert {c["branch"] for c in checks} == {"reference"}
     losses = smoke.phase_train(

@@ -468,6 +468,10 @@ HOST_STAGING_ROWS = {
 EMBED_KERNEL_ROWS = (set(hot_path.EMBED_KERNEL_BODIES)
                      | set(hot_path.EMBED_KERNEL_WRAPPERS))
 
+#: the paged decode kernel's pallas body (ops/decode.py), for the same
+#: reason; its wrapper ``paged_decode_context`` is discovered.
+PAGED_KERNEL_ROWS = set(hot_path.PAGED_KERNEL_BODIES)
+
 
 def test_jit_discovery_covers_legacy_table(discovery):
     disc = discovery
@@ -480,7 +484,10 @@ def test_jit_discovery_covers_legacy_table(discovery):
     auto = disc.traced_names() | disc.dispatch_names()
     assert HOST_STAGING_ROWS <= legacy, "exemption list drifted from table"
     assert EMBED_KERNEL_ROWS <= legacy, "exemption list drifted from table"
-    not_auto = (legacy - HOST_STAGING_ROWS - EMBED_KERNEL_ROWS) - auto
+    assert PAGED_KERNEL_ROWS <= legacy, "exemption list drifted from table"
+    not_auto = (legacy - HOST_STAGING_ROWS - EMBED_KERNEL_ROWS
+                - PAGED_KERNEL_ROWS) - auto
+    assert "paged_decode_context" in auto
     assert not not_auto, (
         f"device-side legacy rows no longer auto-discovered: "
         f"{sorted(not_auto)}")
