@@ -316,7 +316,11 @@ class TransformerLM:
 
     def init_slot_caches(self, slots: int):
         """One slot-batched K/V cache per block (float32 — decode parity
-        with the serial ``generate()`` caches)."""
+        with the serial ``generate()`` caches). With :meth:`slot_step`, what
+        a DRAFT model decodes off in speculative rounds (the scheduler's and
+        :meth:`generate_speculative`'s) and the reference the paged step is
+        held bit-identical to (tests/test_paged_kv.py); the scheduler's
+        target model steps through :meth:`paged_slot_step`."""
         return [init_slot_cache(slots, self.n_head, self.max_len,
                                 self._head_dim, jnp.float32)
                 for _ in range(self.n_block)]

@@ -134,9 +134,9 @@ _CHECKS: List[Tuple[str, Optional[str], Sequence[str], Sequence[str],
      ("slot_step", "prefill_kv", "paged_slot_step", "verify_step",
       "prefill_kv_suffix"), (), False, "body"),
     (SERVER_PY, "GenerativeServing",
-     ("_dispatch_step", "_insert_request_device", "_insert_request_paged",
-      "_insert_request_spec", "_insert_suffix_paged", "_copy_page_device",
-      "_evict_slots"), (), True, "body"),
+     ("_dispatch_step", "_insert_request_paged", "_insert_request_spec",
+      "_insert_suffix_paged", "_copy_page_device", "_evict_slots"),
+     (), True, "body"),
     # the fleet router's placement scoring runs once per routed request:
     # it must stay a single vectorized pass over the instance-gauge
     # arrays — no host syncs, no per-request Python loop over instances

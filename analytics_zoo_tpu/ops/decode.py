@@ -114,14 +114,21 @@ def checked_cached_attention(q: jax.Array, k_new: jax.Array,
     return cached_attention(q, k_new, v_new, cache, scale)
 
 
-# -- slot-based cache for continuous batching -------------------------------
+# -- slot rectangles and the slot state --------------------------------------
 #
-# The generative scheduler (serving/server.py GenerativeServing) keeps S
-# independent streams resident in ONE device-shaped cache so a single fused
-# step advances every occupied slot. All shapes are static: joining,
-# stepping and evicting only move traced indices/masks around, so the step
-# program compiles exactly once (plus one prefill program per length
-# bucket) no matter how streams come and go.
+# S independent streams resident in ONE device-shaped cache, a ``max_len``
+# rectangle a slot, so a single fused step advances every occupied slot. All
+# shapes are static: joining, stepping and evicting only move traced
+# indices/masks around, so a step program compiles exactly once no matter how
+# streams come and go. The generative scheduler (serving/server.py
+# GenerativeServing) keeps its target model's K/V in the page pool further
+# down; what keeps these: the DRAFT model of a speculative round decodes off
+# slot rectangles (``_step_spec`` / ``_prefill_spec`` there,
+# ``generate_speculative`` in capture/lm.py); the slot STATE (lengths +
+# active mask: ``init_slot_state`` / ``slot_join`` / ``slot_evict``) is the
+# page pool's occupancy too; and ``slot_insert`` / ``slot_attention`` are the
+# reference that tests/test_paged_kv.py and tests/test_attention.py hold the
+# XLA form of the paged read bit-identical to.
 
 SlotCache = Dict[str, Any]
 
@@ -207,10 +214,10 @@ def slot_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
 
 # -- paged KV cache (block-granular allocation + per-slot page tables) ------
 #
-# The slot engine above reserves a contiguous [S, H, max_len, D] rectangle
-# per block: HBM pays for max_len whether a stream uses it or not. The paged
-# engine (vLLM's PagedAttention transplanted onto the traced-index slot
-# machinery) replaces the rectangles with ONE global pool of fixed-size
+# The slot caches above reserve a contiguous [S, H, max_len, D] rectangle
+# per block: HBM pays for max_len whether a stream uses it or not. The page
+# pool (vLLM's PagedAttention transplanted onto the traced-index slot
+# machinery) puts in the rectangles' place ONE global pool of fixed-size
 # pages plus a per-slot page TABLE [S, W] of pool indices in logical order —
 # a stream only holds the pages its prompt + budget actually need, and
 # identical prompt prefixes can share refcounted pages (copy-on-write,
@@ -232,16 +239,15 @@ def slot_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
 #
 # Page 0 is the NULL page: never allocated to a stream, it absorbs the
 # writes of inactive slots and of positions past a slot's allocation (the
-# same way inactive slots harmlessly write into their own rectangle in the
-# contiguous engine).
+# same way inactive slots harmlessly write into their own rectangle).
 #
 # The decode step reads the pool in one of two forms. The XLA form
 # (everywhere off the TPU, and on it wherever ``_paged_decode_rule`` names a
 # reason) gathers a slot's pages back into logical [max_len] order and runs
-# the SAME masked_context arithmetic as the slot engine: garbage beyond a
+# the SAME masked_context arithmetic as slot_attention: garbage beyond a
 # slot's length — null-page junk here, stale rectangle tail there — is
 # masked to exactly _NEG_INF and contributes exact-zero terms either way.
-# Bit-identity with the slot engine is a property of THAT form, and the
+# Bit-identity with the slot rectangles is a property of THAT form, and the
 # tests that hold it run it. On the TPU, over an unquantised pool on one
 # device, the T = 1 step runs ``paged_decode_context`` instead: a pallas
 # kernel that walks each slot's table row up to its length and reads the
@@ -670,7 +676,7 @@ def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     The XLA form gathers the first ``max_len // page_len`` table columns
     back into a logical ``[S, H, max_len, D]`` view and runs the SAME
     :func:`~..attention.masked_context` arithmetic over the SAME key
-    length and visibility mask as the slot engine: bit-identical to it,
+    length and visibility mask as :func:`slot_attention`: bit-identical to it,
     and what runs off the TPU. On the TPU the updated pool is read in
     place by :func:`paged_decode_context` where :func:`_paged_decode_rule`
     allows; that kernel agrees with the XLA form to the precision of one
@@ -679,7 +685,7 @@ def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
 
     ``q``/``k_new``/``v_new``: ``[S, H, 1, D]``; ``lengths``: [S] int32.
     The caller advances lengths once after every block attended, exactly
-    as with the contiguous engine."""
+    as with the slot rectangles."""
     _, _, t, d = q.shape
     page_len = cache["k"].shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)

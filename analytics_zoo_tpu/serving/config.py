@@ -44,10 +44,11 @@ class ServingConfig:
     top_k: Optional[int] = None          # scheduler samples through the
     top_p: Optional[float] = None        # shared make_logit_filter; all
     #   None => greedy argmax decoding
-    # -- paged KV engine -------------------------------------------------------
-    kv_pages: Optional[int] = None  # pool size in pages; None = contiguous
-    #   per-slot rectangles (the PR 8 engine). Page 0 is the null page, so
-    #   kv_pages - 1 pages are allocatable.
+    # -- the KV page pool -------------------------------------------------------
+    kv_pages: Optional[int] = None  # pool size in pages, a deployment's
+    #   memory budget; None = every slot can reach max_len:
+    #   slots * ceil((max_len + spec_k) / kv_page_len) + 1. Page 0 is the
+    #   null page, so kv_pages - 1 pages are allocatable.
     kv_page_len: int = 16  # tokens per page; must divide the LM's max_len
     #   and be a power of two <= 16 (so it divides every prefill bucket);
     #   a model with sparse-attention layers asks for its selection block
@@ -59,7 +60,7 @@ class ServingConfig:
     #   sharded output is token-identical to kv_shard=1). Must divide
     #   kv_pages and be <= the local device count.
     spec_k: int = 0  # speculative decoding: draft tokens per verify round;
-    #   0 = disabled. Requires kv_pages and a draft_lm, greedy-only.
+    #   0 = disabled. Requires a draft_lm, greedy-only.
 
     @staticmethod
     def from_yaml(path: str) -> "ServingConfig":

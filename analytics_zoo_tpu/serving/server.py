@@ -1188,25 +1188,29 @@ class GenerativeServing:
     the same result record — they are progress, not terminals — and
     ``OutputQueue.stream()`` turns them into a client-side generator.
 
-    Decode parity: slot-batched streams are BIT-IDENTICAL to serial
-    ``TransformerLM.generate()`` runs — both paths share the bucketed
-    prefill (``prefill_kv``), the ``make_logit_filter`` sampling chain and
-    the ``cached_attention``-mirroring ``slot_attention`` arithmetic
-    (tests/test_generative_serving.py holds the line).
-
-    Paged KV engine (``config.kv_pages``): per-slot ``max_len``
-    rectangles are replaced by a global page pool + per-slot page tables
+    One KV engine: a global page pool + per-slot page tables
     (``ops/decode.py`` paged ops) — HBM is paid per ALLOCATED page, not
-    per slot, so concurrency scales with actual stream lengths. Joins
-    allocate pages (shedding with ``PAGE_SHED_ERROR`` on exhaustion — the
+    per slot, so concurrency scales with actual stream lengths.
+    ``config.kv_pages`` is the pool's size, a deployment's memory budget;
+    left ``None`` it is worked out so that every slot can reach
+    ``max_len`` and no join sheds for want of pages. Joins allocate pages
+    (shedding with ``PAGE_SHED_ERROR`` on exhaustion — the
     ``serving.page_alloc`` fault site), retirement refcounts them back.
     ``register_prefix()`` shares a common prompt's pages across streams
     with copy-on-write tails; ``config.kv_int8`` stores the pool in int8
-    with delayed scaling; ``config.spec_k`` + a ``draft_lm`` switches the
-    step to speculative draft/verify rounds (greedy-only,
-    token-identical to serial greedy). Paged greedy/sampled decode stays
-    bit-identical to the contiguous engine
-    (tests/test_paged_serving.py)."""
+    with delayed scaling; ``config.spec_k`` + a ``draft_lm`` makes the
+    step a speculative draft/verify round (greedy-only, token-identical
+    to serial greedy).
+
+    Decode parity: served streams are BIT-IDENTICAL to serial
+    ``TransformerLM.generate()`` runs on the CPU — both share the bucketed
+    prefill (``prefill_kv``), the ``make_logit_filter`` sampling chain,
+    and the XLA form of the paged read mirrors ``cached_attention``'s
+    arithmetic. tests/test_generative_serving.py holds that line on the
+    derived pool and on a small one, tests/test_paged_serving.py holds
+    the pool's own mechanisms (prefixes, int8, speculation, sharding,
+    exhaustion), tests/test_paged_kv.py holds the paged ops to the slot
+    rectangles of ``ops/decode.py``."""
 
     SHED_INTERVAL_S = 0.05
 
@@ -1238,12 +1242,7 @@ class GenerativeServing:
             filter_logits = make_logit_filter(
                 config.temperature if config.temperature is not None
                 else 1.0, config.top_k, config.top_p)
-        # -- paged KV engine + speculative decoding flags -----------------
-        self._paged = config.kv_pages is not None
         self._spec = draft_lm is not None and config.spec_k > 0
-        if self._spec and not self._paged:
-            raise ValueError("speculative decoding rides the paged KV "
-                             "engine: set kv_pages alongside spec_k")
         if self._spec and self._sampling:
             raise ValueError("speculative decoding in the scheduler is "
                              "greedy-only (per-request sampled accept is a "
@@ -1256,9 +1255,6 @@ class GenerativeServing:
         self._recurrent = bool(getattr(lm, "recurrent", False))
         if self._recurrent:
             why = "a model with recurrent (linear-attention) layers"
-            if not self._paged:
-                raise ValueError(f"{why} is served by the paged engine: "
-                                 f"set kv_pages")
             if draft_lm is not None or config.spec_k:
                 raise ValueError(
                     f"speculative decoding is refused for {why}: rejected "
@@ -1274,38 +1270,37 @@ class GenerativeServing:
             if self._sampling:
                 raise ValueError(f"sampling is not wired for {why} yet: "
                                  f"unset temperature/top_k/top_p")
-        # -- device state: per-block slot caches + ONE shared occupancy ---
+        # -- device state: the page pools + ONE shared occupancy ----------
         self._params = lm.params
-        if self._paged:
-            pl = int(config.kv_page_len)
-            num_pages = int(config.kv_pages)
-            if self._recurrent:
-                # one page is one block of the model's sparse selection
-                if pl != lm.page_len:
-                    raise ValueError(
-                        f"kv_page_len must be the model's selection "
-                        f"block, {lm.page_len}; got {pl}")
-            elif pl < 1 or (pl & (pl - 1)) or pl > 16:
-                raise ValueError(f"kv_page_len must be a power of two "
-                                 f"<= 16 (divides every prefill bucket), "
-                                 f"got {pl}")
-            if lm.max_len % pl:
-                raise ValueError(f"kv_page_len {pl} must divide the LM's "
-                                 f"max_len {lm.max_len}")
-            if num_pages < 2:
-                raise ValueError(f"kv_pages must be >= 2 (page 0 is the "
-                                 f"null page), got {num_pages}")
-            self.page_len = pl
-            self.num_pages = num_pages
-            # table rows carry slack columns for the transient spec_k
-            # overshoot past max_len (those writes land on real pages the
-            # stream owns only within its allocation; beyond it, the null
-            # page absorbs them)
-            self._table_w = (lm.max_len + self._spec_k + pl - 1) // pl
-            self._kv_shard = int(getattr(config, "kv_shard", 1) or 1)
-        else:
-            self._kv_shard = 1
-        self._prefixes: List[Dict[str, Any]] = []  # paged engine only
+        pl = int(config.kv_page_len)
+        if self._recurrent:
+            # one page is one block of the model's sparse selection
+            if pl != lm.page_len:
+                raise ValueError(
+                    f"kv_page_len must be the model's selection "
+                    f"block, {lm.page_len}; got {pl}")
+        elif pl < 1 or (pl & (pl - 1)) or pl > 16:
+            raise ValueError(f"kv_page_len must be a power of two "
+                             f"<= 16 (divides every prefill bucket), "
+                             f"got {pl}")
+        if lm.max_len % pl:
+            raise ValueError(f"kv_page_len {pl} must divide the LM's "
+                             f"max_len {lm.max_len}")
+        self.page_len = pl
+        # table rows carry slack columns for the transient spec_k
+        # overshoot past max_len (those writes land on real pages the
+        # stream owns only within its allocation; beyond it, the null
+        # page absorbs them)
+        self._table_w = (lm.max_len + self._spec_k + pl - 1) // pl
+        # no budget named: every slot can fill its table row, so no join
+        # sheds for want of pages (+ 1: page 0 is the null page)
+        self.num_pages = (int(config.kv_pages) if config.kv_pages is not None
+                          else self.slots * self._table_w + 1)
+        if self.num_pages < 2:
+            raise ValueError(f"kv_pages must be >= 2 (page 0 is the "
+                             f"null page), got {self.num_pages}")
+        self._kv_shard = int(getattr(config, "kv_shard", 1) or 1)
+        self._prefixes: List[Dict[str, Any]] = []
         if self._spec:
             self.draft_lm = draft_lm
             self._dparams = draft_lm.params
@@ -1324,17 +1319,6 @@ class GenerativeServing:
             return jax.vmap(lambda kk, row: jax.random.categorical(
                 kk, row, axis=-1))(keys, filt)
 
-        def _step(params, tokens, keys, state, caches):
-            logits, caches = lm.slot_step(params, tokens, state["length"],
-                                          caches)
-            nxt = _select(logits, keys)
-            # lengths advance ONCE, after every block attended with the
-            # pre-increment value (write-then-attend, as serial decode)
-            state = {"length": (state["length"]
-                                + state["active"].astype(jnp.int32)),
-                     "active": state["active"]}
-            return nxt, state, caches
-
         def _step_paged(params, tokens, keys, state, table, caches):
             if self._recurrent:
                 # a recurrent layer's state moves for active slots only: a
@@ -1352,6 +1336,8 @@ class GenerativeServing:
                 # the step's own count of the pages it read rides behind
                 # the tokens: one array, the step's one fetch
                 nxt = jnp.concatenate([nxt.astype(jnp.int32), read[None]])
+            # lengths advance ONCE, after every block attended with the
+            # pre-increment value (write-then-attend, as serial decode)
             state = {"length": (state["length"]
                                 + state["active"].astype(jnp.int32)),
                      "active": state["active"]}
@@ -1383,12 +1369,6 @@ class GenerativeServing:
             n = n * active.astype(n.dtype)
             state = {"length": lengths + n, "active": active}
             return emitted, n, state, caches, dcaches
-
-        def _prefill(params, padded, caches, state, slot, length):
-            kvs = lm.prefill_kv(params, padded)
-            caches = [slot_insert(c, slot, k[0], v[0])
-                      for c, (k, v) in zip(caches, kvs)]
-            return caches, slot_join(state, slot, length)
 
         def _prefill_paged(params, padded, caches, state, table, row, slot,
                            length):
@@ -1445,33 +1425,27 @@ class GenerativeServing:
         # chip's compiler then writes the token rows in place, and no
         # program copies a pool. The handles passed in are dead once the
         # call returns, so each caller below rebinds the results at once.
-        # The slot engine's programs are not donated.
         pools = ("caches",)
         if self._spec:
             both = ("caches", "dcaches")
             self._step_fn = jax.jit(_step_spec, donate_argnames=both)
             self._prefill_spec_fn = jax.jit(_prefill_spec,
                                             donate_argnames=both)
-        elif self._paged:
+        else:
             self._step_fn = jax.jit(_step_paged, donate_argnames=pools)
-        else:
-            self._step_fn = jax.jit(_step)
-        if self._paged:
-            self._prefill_paged_fn = jax.jit(_prefill_paged,
+        self._prefill_paged_fn = jax.jit(_prefill_paged,
+                                         donate_argnames=pools)
+        self._prefill_suffix_fn = jax.jit(_prefill_suffix,
+                                          static_argnames=("plen",),
+                                          donate_argnames=pools)
+        self._prefill_prefix_fn = jax.jit(_prefill_prefix,
+                                          donate_argnames=pools)
+        self._copy_fn = jax.jit(_copy_pages, donate_argnames=pools)
+        self._table_set_fn = jax.jit(page_table_set)
+        self._table_clear_fn = jax.jit(page_table_clear)
+        if self._recurrent:  # one compile per chunk bucket
+            self._prefill_chunk_fn = jax.jit(_prefill_chunk,
                                              donate_argnames=pools)
-            self._prefill_suffix_fn = jax.jit(_prefill_suffix,
-                                              static_argnames=("plen",),
-                                              donate_argnames=pools)
-            self._prefill_prefix_fn = jax.jit(_prefill_prefix,
-                                              donate_argnames=pools)
-            self._copy_fn = jax.jit(_copy_pages, donate_argnames=pools)
-            self._table_set_fn = jax.jit(page_table_set)
-            self._table_clear_fn = jax.jit(page_table_clear)
-            if self._recurrent:  # one compile per chunk bucket
-                self._prefill_chunk_fn = jax.jit(_prefill_chunk,
-                                                 donate_argnames=pools)
-        else:
-            self._prefill_fn = jax.jit(_prefill)  # one compile per bucket
         self._join_fn = jax.jit(slot_join)    # T==1 prompts: no prefill
         self._evict_fn = jax.jit(slot_evict)
         self._split = lambda seed, n: np.asarray(
@@ -1527,8 +1501,7 @@ class GenerativeServing:
             server=self.metrics_label)
         self._m_brownout = _M_BROWNOUT.labels(server=self.metrics_label)
         self._brownout = _Brownout(self.metrics_label)
-        if self._paged:
-            self._m_pages_free.set(len(self._free_pages))
+        self._m_pages_free.set(len(self._free_pages))
         self._counter_lock = threading.Lock()
         self._in_flight = 0
         self._meta: Dict[str, Tuple[float, Optional[int]]] = {}
@@ -1597,8 +1570,7 @@ class GenerativeServing:
             self._count(counter)
         elif "value" in value:
             self._m_records.inc()
-        if self._paged:
-            self._release_pages(slot)
+        self._release_pages(slot)
         self._clear_slot(slot)
 
     def _clear_slot(self, slot: int) -> None:
@@ -1623,8 +1595,7 @@ class GenerativeServing:
             in_flight = self._in_flight
             self._meta.pop(self._uri[slot], None)
         self._m_in_flight.set(in_flight)
-        if self._paged:
-            self._release_pages(slot)
+        self._release_pages(slot)
         self._clear_slot(slot)
 
     def _fresh_device_state(self) -> None:
@@ -1634,29 +1605,25 @@ class GenerativeServing:
         import jax.numpy as jnp
 
         from ..ops.decode import init_slot_state, shard_paged_pool
-        if self._paged:
-            more = {"slots": self.slots} if self._recurrent else {}
-            self._caches = self.lm.init_paged_caches(
-                self.num_pages, self.page_len, int8=self.config.kv_int8,
-                **more)
-            if self._kv_shard > 1:
-                # page axis spread over kv_shard devices; decode gathers
-                # each stream's pages to the compute device, so tokens
-                # stay bit-identical to the single-device pool
-                self._caches = shard_paged_pool(self._caches,
-                                                self._kv_shard)
-            self._table = jnp.zeros((self.slots, self._table_w), jnp.int32)
-            # host-side allocator: free-page stack, refcounts, and the
-            # pages each slot holds (shared prefix pages appear in many)
-            self._free_pages = self._initial_free_pages(self.num_pages,
-                                                        self._kv_shard)
-            self._page_refs = np.zeros(self.num_pages, np.int64)
-            self._slot_pages: List[List[int]] = [[] for _ in
-                                                 range(self.slots)]
-        else:
-            self._caches = self.lm.init_slot_caches(self.slots)
+        more = {"slots": self.slots} if self._recurrent else {}
+        self._caches = self.lm.init_paged_caches(
+            self.num_pages, self.page_len, int8=self.config.kv_int8, **more)
+        if self._kv_shard > 1:
+            # page axis spread over kv_shard devices; decode gathers
+            # each stream's pages to the compute device, so tokens
+            # stay bit-identical to the single-device pool
+            self._caches = shard_paged_pool(self._caches, self._kv_shard)
+        self._table = jnp.zeros((self.slots, self._table_w), jnp.int32)
+        # host-side allocator: free-page stack, refcounts, and the
+        # pages each slot holds (shared prefix pages appear in many)
+        self._free_pages = self._initial_free_pages(self.num_pages,
+                                                    self._kv_shard)
+        self._page_refs = np.zeros(self.num_pages, np.int64)
+        self._slot_pages: List[List[int]] = [[] for _ in range(self.slots)]
+        # the occupancy (length, active) is the slot state of ops/decode.py
         self._state = init_slot_state(self.slots)
         if self._spec:
+            # the draft model still decodes off slot rectangles
             self._dcaches = self.draft_lm.init_slot_caches(self.slots)
 
     def _rebuild_pools(self) -> None:
@@ -1673,8 +1640,7 @@ class GenerativeServing:
         for pfx in prefixes:
             self.register_prefix(pfx["tokens"])
         self._m_pool_rebuilds.inc()
-        if self._paged:
-            self._m_pages_free.set(len(self._free_pages))
+        self._m_pages_free.set(len(self._free_pages))
         logger.warning("kv caches rebuilt after a failed dispatch "
                        "(%d prefixes prefilled again)", len(prefixes))
 
@@ -1732,20 +1698,13 @@ class GenerativeServing:
                 self._params, self._dparams, tokens, self._state,
                 self._table, self._caches, self._dcaches)
             out = (emitted, n_acc)
-        elif self._paged:
+        else:
             out, self._state, self._caches = self._step_fn(
                 self._params, tokens, keys, self._state, self._table,
                 self._caches)
-        else:
-            out, self._state, self._caches = self._step_fn(
-                self._params, tokens, keys, self._state, self._caches)
         _profiler.record_phase("serving", "dispatch",
                                time.perf_counter() - t0, start=t0)
         return out
-
-    def _insert_request_device(self, padded, slot, length):
-        self._caches, self._state = self._prefill_fn(
-            self._params, padded, self._caches, self._state, slot, length)
 
     def _insert_request_paged(self, padded, row, slot, length):
         self._caches, self._state, self._table = self._prefill_paged_fn(
@@ -1770,8 +1729,7 @@ class GenerativeServing:
 
     def _evict_slots(self, mask):
         self._state = self._evict_fn(self._state, mask)
-        if self._paged:
-            self._table = self._table_clear_fn(self._table, mask)
+        self._table = self._table_clear_fn(self._table, mask)
 
     def _fetch_tokens(self, nxt) -> np.ndarray:
         # the one host sync per step, deliberately OUTSIDE the policed
@@ -1816,10 +1774,8 @@ class GenerativeServing:
             pending = None
         fill = (pending / float(max(allowed, 1))
                 if pending is not None else 0.0)
-        scarcity = 0.0
-        if self._paged:
-            scarcity = 1.0 - (len(self._free_pages)
-                              / float(max(self.num_pages - 1, 1)))
+        scarcity = 1.0 - (len(self._free_pages)
+                          / float(max(self.num_pages - 1, 1)))
         self._m_brownout.set(self._brownout.tick(max(fill, scarcity)))
         if dropped:
             self._count("shed", len(dropped))
@@ -1852,9 +1808,6 @@ class GenerativeServing:
         permanent reference, so the pages survive every stream's
         retirement. Admin-plane call — register before ``start()`` or
         between steps, not concurrently with the loop."""
-        if not self._paged:
-            raise RuntimeError("shared prefixes require the paged KV "
-                               "engine (set kv_pages)")
         if self._spec:
             raise RuntimeError("shared prefixes are not wired into the "
                                "speculative scheduler yet (the draft "
@@ -1997,8 +1950,6 @@ class GenerativeServing:
         ``seed`` the key schedule is rebuilt over the FULL original budget
         so step ``i`` uses the same key an uninterrupted stream would —
         the continuation is token-identical (docs/fleet.md)."""
-        from ..capture.lm import prefill_bucket
-
         cfg = self.config
         prompt = rec.get("prompt")
         if not prompt:
@@ -2045,29 +1996,13 @@ class GenerativeServing:
             _profiler.record_phase("serving", "host_input",
                                    time.perf_counter() - t0, start=t0)
             return began
-        if self._paged:
-            if not self._join_paged(slot, uri, full, t_full,
-                                    budget - len(prefix)):
-                _profiler.record_phase("serving", "host_input",
-                                       time.perf_counter() - t0, start=t0)
-                return False
-        elif t_full > 1:
-            # right-pad full[:-1] to its length bucket: the SAME compiled
-            # prefill program serial generate() uses (bit-parity anchor);
-            # an adopted prefix re-prefills here — the KV it rebuilds is
-            # bit-identical to what the dead server's decode steps wrote
-            tb = prefill_bucket(t_full - 1, self.lm.max_len)
-            padded = np.zeros((1, tb), np.int32)
-            padded[0, :t_full - 1] = full[:-1]
-            self._insert_request_device(padded, np.int32(slot),
-                                        np.int32(t_full - 1))
-        else:
-            self._state = self._join_fn(self._state, np.int32(slot),
-                                        np.int32(0))
+        joined = self._join_paged(slot, uri, full, t_full,
+                                  budget - len(prefix))
         _profiler.record_phase("serving", "host_input",
                                time.perf_counter() - t0, start=t0)
-        self._activate(slot, uri, rec, prompt, prefix, budget, exp, now)
-        return True
+        if joined:
+            self._activate(slot, uri, rec, prompt, prefix, budget, exp, now)
+        return joined
 
     def _activate(self, slot: int, uri: str, rec: Dict[str, Any], prompt,
                   prefix, budget: int, exp: Optional[float],
@@ -2452,7 +2387,7 @@ class GenerativeServing:
                 if self._recurrent:
                     out, read = out
                 nxt_host = self._fetch_tokens(out)
-                if self._paged and not self._recurrent:
+                if not self._recurrent:
                     nxt_host, read = nxt_host[:-1], nxt_host[-1]
         except Exception as e:
             logger.exception("decode step failed for %d streams", n_active)
@@ -2476,7 +2411,7 @@ class GenerativeServing:
             if self._prefilling:
                 self._count("steps_between_chunks")
             self._m_sparse_read.observe(float(read))
-        elif self._paged:
+        else:
             self._m_pages_read.observe(float(read))
         with time_it("serve.post"):
             self._post_tokens(nxt_host)
@@ -2704,13 +2639,12 @@ class GenerativeServing:
             "tokens_total": int(self._m_tokens.value()),
             "tokens_per_sec_ewma": (round(1.0 / self._ewma_token_s, 1)
                                     if self._ewma_token_s > 0 else None),
-            "kv_pages_free": (len(self._free_pages) if self._paged
-                              else None),
-            "kv_shards": (self._kv_shard if self._paged else None),
+            "kv_pages_free": len(self._free_pages),
+            "kv_shards": self._kv_shard,
             "kv_pool_rebuilds": int(self._m_pool_rebuilds.value()),
             "kv_pages_free_min_shard": (
                 min(self._pages_free_per_shard())
-                if self._paged and self._kv_shard > 1 else None),
+                if self._kv_shard > 1 else None),
             "spec_accept_ratio": (
                 round(float(self._m_spec_accept.value()), 4)
                 if self._spec else None),

@@ -1,11 +1,15 @@
-"""Continuous-batching generative serving: decode parity + per-token SLOs.
+"""Continuous-batching generative serving: the request lifecycle on the
+default page pool, decode parity + per-token SLOs.
 
 The load-bearing invariant is BIT-IDENTITY: N requests decoded through the
-slot-batched scheduler — with mid-stream joins and evictions — must produce
-exactly the token streams serial ``TransformerLM.generate()`` produces,
-greedy and sampled. Everything else (per-token deadlines, drain, step
-chaos, streaming client, metrics) layers on the exactly-one-terminal rule
-ClusterServing established.
+scheduler — with mid-stream joins and evictions — must produce exactly the
+token streams serial ``TransformerLM.generate()`` produces, greedy and
+sampled, on the pool a server works out for itself (``kv_pages=None``:
+every slot can reach ``max_len``) and on a small named one. Everything
+else (per-token deadlines, drain, step chaos, streaming client, metrics)
+layers on the exactly-one-terminal rule ClusterServing established. The
+pool's own mechanisms (prefixes, int8, speculation, sharding, exhaustion)
+are tests/test_paged_serving.py's.
 """
 import time
 import uuid
@@ -17,7 +21,7 @@ from analytics_zoo_tpu.common import faults
 from analytics_zoo_tpu.common import metrics as _metrics
 from analytics_zoo_tpu.serving import GenerativeServing, ServingConfig
 from analytics_zoo_tpu.serving.client import InputQueue, OutputQueue
-from analytics_zoo_tpu.serving.server import DEADLINE_ERROR
+from analytics_zoo_tpu.serving.server import DEADLINE_ERROR, PAGE_SHED_ERROR
 
 
 @pytest.fixture(autouse=True)
@@ -62,9 +66,17 @@ def _drive(srv, steps=200):
             idle = 0
 
 
+#: the pool a server works out from its slots, and a named one with pages
+#: of another length (the two files' parity cases, one test each)
+POOLS = pytest.mark.parametrize("pool", [
+    pytest.param({}, id="derived"),
+    pytest.param({"kv_pages": 16, "kv_page_len": 8}, id="16x8")])
+
+
 class TestDecodeParity:
-    @pytest.mark.slow
-    def test_greedy_bit_identical_with_midstream_joins(self, ctx, tmp_path):
+    @POOLS
+    def test_greedy_bit_identical_with_midstream_joins(self, ctx, tmp_path,
+                                                       pool):
         # 5 requests through 2 slots: requests 3..5 join slots mid-run as
         # earlier streams finish and are evicted — the continuous-batching
         # case, not just a static batch
@@ -75,7 +87,8 @@ class TestDecodeParity:
                   for p in prompts]
         src = _src(tmp_path)
         srv = GenerativeServing(
-            ServingConfig(data_src=src, slots=2, max_new_tokens=8), lm)
+            ServingConfig(data_src=src, slots=2, max_new_tokens=8, **pool),
+            lm)
         inq, outq = InputQueue(src), OutputQueue(src)
         for i, p in enumerate(prompts):
             inq.enqueue_prompt(f"r{i}", p)
@@ -84,10 +97,14 @@ class TestDecodeParity:
             res = outq.query(f"r{i}", timeout_s=5)
             assert res is not None and res.get("done") is True
             assert res["value"] == want, f"stream r{i} diverged"
-        assert srv.health_snapshot()["slots_occupied"] == 0
+        snap = srv.health_snapshot()
+        assert snap["slots_occupied"] == 0
+        # every page returned to the pool after the last retirement
+        assert snap["kv_pages_free"] == srv.num_pages - 1
 
-    @pytest.mark.slow
-    def test_sampled_bit_identical_per_request_seed(self, ctx, tmp_path):
+    @POOLS
+    def test_sampled_bit_identical_per_request_seed(self, ctx, tmp_path,
+                                                    pool):
         lm = _lm()
         rs = np.random.RandomState(4)
         prompts = [rs.randint(0, 16, (n,)).tolist() for n in (5, 2, 1, 7)]
@@ -98,7 +115,7 @@ class TestDecodeParity:
         src = _src(tmp_path)
         srv = GenerativeServing(
             ServingConfig(data_src=src, slots=2, max_new_tokens=8,
-                          temperature=0.9, top_k=8), lm)
+                          temperature=0.9, top_k=8, **pool), lm)
         inq, outq = InputQueue(src), OutputQueue(src)
         for i, (p, s) in enumerate(zip(prompts, seeds)):
             inq.enqueue_prompt(f"r{i}", p, seed=s)
@@ -131,8 +148,35 @@ class TestDecodeParity:
             assert res is not None and res["value"] == want
 
 
+class TestDerivedPool:
+    def test_every_slot_can_reach_max_len(self, ctx, tmp_path):
+        # kv_pages=None: slots * ceil(max_len / kv_page_len) + 1 pages, the
+        # null page included, so no join is shed for want of pages
+        lm = _lm()
+        src = _src(tmp_path)
+        srv = GenerativeServing(ServingConfig(data_src=src, slots=3), lm)
+        assert srv.num_pages == 3 * (32 // 16) + 1
+        assert srv.health_snapshot()["kv_pages_free"] == 6
+        inq, outq = InputQueue(src), OutputQueue(src)
+        inq.enqueue_prompt("one", [5, 2, 8], max_new_tokens=4)
+        _drive(srv)
+        assert len(outq.query("one", timeout_s=5)["value"]) == 4
+        assert srv.health_snapshot()["kv_pages_free"] == 6
+        lengths = (1, 7, 20)
+        for i, t in enumerate(lengths):
+            inq.enqueue_prompt(f"full{i}", [3] * t, max_new_tokens=32 - t)
+        assert srv.serve_step() == 3        # all resident at once
+        assert srv.health_snapshot()["kv_pages_free"] == 0
+        _drive(srv)
+        for i, t in enumerate(lengths):
+            res = outq.query(f"full{i}", timeout_s=5)
+            assert res.get("error") != PAGE_SHED_ERROR
+            assert len(res["value"]) == 32 - t
+        assert srv.counters["shed"] == 0
+        assert srv.health_snapshot()["kv_pages_free"] == 6
+
+
 class TestPerTokenSLO:
-    @pytest.mark.slow
     def test_deadline_mid_stream_exactly_one_terminal(self, ctx, tmp_path):
         lm = _lm(max_len=64)
         src = _src(tmp_path)

@@ -135,10 +135,16 @@ class TestQueueFeatureSet:
                      buffer_records=64)
         got = list(fs.train_iterator(4))
         assert len(got) == 2
-        # the 4 future records must not have been released
-        deadline = time.monotonic() + 2.0
-        while q.pending_count() and time.monotonic() < deadline:
+        # the 4 future records must not have been released. The ingest
+        # thread counts a release after its fsync, and the consumer above
+        # reads the bytes as soon as they are written: wait for the count
+        # too, then let the loop pass over the held records a few times
+        deadline = time.monotonic() + 10.0
+        while ((q.pending_count() or fs._journal_records < 8)
+               and time.monotonic() < deadline):
             time.sleep(0.01)
+        time.sleep(0.1)
+        assert q.pending_count() == 0
         assert fs._journal_records == 8
         fs.close()
 

@@ -1,11 +1,13 @@
-"""Paged KV scheduler: parity with the contiguous engine and with serial
-generate, copy-on-write shared prefixes (prefilled ONCE), speculative
-draft/verify token-identity, page-pool exhaustion chaos
-(``serving.page_alloc``), the paged metrics plane, and what a failed
-program costs now that the pools are donated to it.
+"""The page pool's own mechanisms under the scheduler: int8 pages against
+serial generate, copy-on-write shared prefixes (prefilled ONCE),
+speculative draft/verify token-identity, page-pool exhaustion chaos
+(``serving.page_alloc``), a sharded pool, the pool's metrics plane, and
+what a failed program costs now that the pools are donated to it.
 
-Op-level paged invariants live in tests/test_paged_kv.py; the contiguous
-scheduler's own parity suite is tests/test_generative_serving.py.
+Op-level paged invariants live in tests/test_paged_kv.py; the request
+lifecycle and greedy / sampled parity with serial generate, on the pool a
+server works out for itself and on this file's 16 pages of 8, are
+tests/test_generative_serving.py's.
 """
 import uuid
 
@@ -26,9 +28,6 @@ def _clean_faults():
     faults.reset()
 
 
-# the scheduler-level classes are slow (tier-1 covers the op layer in
-# tests/test_paged_kv.py); the donated pools' failure path at the end of
-# the file is not
 _LM_CACHE = {}
 
 
@@ -67,53 +66,7 @@ def _paged_cfg(src, **kw):
     return ServingConfig(data_src=src, **kw)
 
 
-@pytest.mark.slow
 class TestPagedParity:
-    @pytest.mark.slow
-    def test_greedy_bit_identical_with_midstream_joins(self, ctx, tmp_path):
-        # 5 requests through 2 slots: the page pool sees mid-stream joins
-        # reusing pages freed by earlier retirements
-        lm = _lm()
-        rs = np.random.RandomState(3)
-        prompts = [rs.randint(0, 16, (n,)).tolist() for n in (4, 1, 6, 3, 5)]
-        serial = [lm.generate(np.asarray([p]), max_new_tokens=8)[0].tolist()
-                  for p in prompts]
-        src = _src(tmp_path)
-        srv = GenerativeServing(_paged_cfg(src), lm)
-        inq, outq = InputQueue(src), OutputQueue(src)
-        for i, p in enumerate(prompts):
-            inq.enqueue_prompt(f"r{i}", p)
-        _drive(srv)
-        for i, want in enumerate(serial):
-            res = outq.query(f"r{i}", timeout_s=5)
-            assert res is not None and res.get("done") is True
-            assert res["value"] == want, f"stream r{i} diverged"
-        snap = srv.health_snapshot()
-        assert snap["slots_occupied"] == 0
-        # every page returned to the pool after the last retirement
-        assert snap["kv_pages_free"] == 15
-
-    @pytest.mark.slow
-    def test_sampled_bit_identical_per_request_seed(self, ctx, tmp_path):
-        lm = _lm()
-        rs = np.random.RandomState(4)
-        prompts = [rs.randint(0, 16, (n,)).tolist() for n in (5, 2, 1, 7)]
-        seeds = [11, 22, 33, 44]
-        serial = [lm.generate(np.asarray([p]), max_new_tokens=8,
-                              temperature=0.9, top_k=8, seed=s)[0].tolist()
-                  for p, s in zip(prompts, seeds)]
-        src = _src(tmp_path)
-        srv = GenerativeServing(
-            _paged_cfg(src, temperature=0.9, top_k=8), lm)
-        inq, outq = InputQueue(src), OutputQueue(src)
-        for i, (p, s) in enumerate(zip(prompts, seeds)):
-            inq.enqueue_prompt(f"r{i}", p, seed=s)
-        _drive(srv)
-        for i, want in enumerate(serial):
-            res = outq.query(f"r{i}", timeout_s=5)
-            assert res is not None and res["value"] == want
-
-    @pytest.mark.slow
     def test_int8_kv_token_parity(self, ctx, tmp_path):
         """int8 pool error (bounded at the op level) is far inside the
         tiny model's logit margins, so the token streams stay equal."""
@@ -133,9 +86,7 @@ class TestPagedParity:
             assert res is not None and res["value"] == want
 
 
-@pytest.mark.slow
 class TestSharedPrefixCoW:
-    @pytest.mark.slow
     def test_prefix_prefilled_once_and_bit_identical(self, ctx, tmp_path,
                                                      monkeypatch):
         lm = _lm()
@@ -173,7 +124,6 @@ class TestSharedPrefixCoW:
         # registry keeps its permanent page across all retirements
         assert srv.health_snapshot()["kv_pages_free"] == free0 - 1
 
-    @pytest.mark.slow
     def test_divergent_suffixes_only_prefill_the_suffix(self, ctx, tmp_path,
                                                         monkeypatch):
         lm = _lm()
@@ -208,9 +158,7 @@ class TestSharedPrefixCoW:
         assert len(scalls) >= 1         # joins ran the SUFFIX path only
 
 
-@pytest.mark.slow
 class TestSpeculative:
-    @pytest.mark.slow
     def test_spec_token_identical_to_serial_greedy(self, ctx, tmp_path):
         lm = _lm()
         draft = _lm(max_len=64, seed=1)   # different weights: a REAL draft
@@ -233,7 +181,6 @@ class TestSpeculative:
         assert snap["spec_accept_ratio"] is not None
         assert 0.0 <= snap["spec_accept_ratio"] <= 1.0
 
-    @pytest.mark.slow
     def test_spec_eos_terminates_streams(self, ctx, tmp_path):
         lm = _lm()
         draft = _lm(max_len=64, seed=1)
@@ -255,20 +202,22 @@ class TestSpeculative:
             res = outq.query(f"e{i}", timeout_s=5)
             assert res is not None and res["value"] == want
 
-    def test_spec_requires_paged_and_greedy(self, ctx, tmp_path):
+    def test_spec_refuses_sampling_and_sizes_its_own_pool(self, ctx,
+                                                          tmp_path):
         lm = _lm()
         draft = _lm(max_len=64, seed=1)
         src = _src(tmp_path)
-        with pytest.raises(ValueError, match="paged"):
-            GenerativeServing(
-                ServingConfig(data_src=src, slots=2, spec_k=2), lm,
-                draft_lm=draft)
         with pytest.raises(ValueError, match="greedy"):
             GenerativeServing(_paged_cfg(src, spec_k=2, temperature=0.8),
                               lm, draft_lm=draft)
+        # no kv_pages named: the derived pool covers the spec_k positions
+        # a round may write past max_len (34 positions of 16 a slot)
+        srv = GenerativeServing(
+            ServingConfig(data_src=src, slots=2, spec_k=2), lm,
+            draft_lm=draft)
+        assert srv.num_pages == 2 * 3 + 1
 
 
-@pytest.mark.slow
 class TestPagePoolChaos:
     def test_page_alloc_fault_sheds_join_keeps_serving(self, ctx, tmp_path):
         """The armed ``serving.page_alloc`` site simulates pool exhaustion
@@ -296,7 +245,6 @@ class TestPagePoolChaos:
         _drive(srv)
         assert outq.query("after", timeout_s=5)["value"] == serial
 
-    @pytest.mark.slow
     def test_real_exhaustion_sheds_then_recovers_after_retire(
             self, ctx, tmp_path):
         # 4 usable pages, 2 per stream: the third concurrent join finds
@@ -340,7 +288,6 @@ class TestPagePoolChaos:
         assert snap["spec_accept_ratio"] is None   # not a spec server
 
 
-@pytest.mark.slow
 class TestShardedPool:
     """``kv_shard``: the page pool's PAGE axis spread across devices —
     decode gathers each stream's pages to the compute device, so the

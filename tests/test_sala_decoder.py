@@ -426,7 +426,6 @@ def _server(model, tmp_path, **more):
     (dict(kv_int8=True), "kv_int8 is refused .* no dequantising gather"),
     (dict(spec_k=2), "speculative decoding is refused .* rolled back"),
     (dict(kv_shard=2), "kv_shard is refused .* state a slot"),
-    (dict(kv_pages=None), "served by the paged engine"),
     (dict(kv_page_len=8), "kv_page_len must be the model's selection block"),
     (dict(temperature=0.7), "sampling is not wired"),
 ])
@@ -434,6 +433,12 @@ def test_the_server_refuses_what_a_recurrent_model_cannot_have(
         model, tmp_path, more, reason):
     with pytest.raises(ValueError, match=reason):
         _server(model, tmp_path, **more)
+
+
+def test_no_pool_named_gives_every_slot_its_whole_context(model, tmp_path):
+    srv, _ = _server(model, tmp_path, kv_pages=None)
+    assert srv.num_pages == SLOTS * (srv.lm.max_len // PAGE) + 1
+    assert srv.health_snapshot()["kv_pages_free"] == srv.num_pages - 1
 
 
 def test_a_draft_model_is_refused_too(model, tmp_path):
