@@ -52,7 +52,8 @@ _GAUGE_UNITLESS_OK = {"serving.in_flight", "serving.slots_occupied",
                       "serving.kv_pages_free", "build.info",
                       "fleet.instances_alive", "fleet.desired_instances",
                       "cluster.leases_alive", "serving.brownout_level",
-                      "fleet.breaker_state", "serving.state_slots_in_use"}
+                      "fleet.breaker_state", "serving.state_slots_in_use",
+                      "serving.publish_backlog"}
 #: histograms of a count, not of a duration: exempt from the suffix rule
 _HISTOGRAM_UNITLESS_OK = {"serving.sparse_positions_read",
                            "serving.paged_pages_read"}

@@ -21,6 +21,7 @@ import base64
 import hashlib
 import json
 import os
+import threading
 
 from ..common import file_io
 from ..common.utils import wall_clock
@@ -401,7 +402,10 @@ class FileQueue(QueueBackend):
 
     def put_result(self, uri: str, value: Dict[str, Any]) -> None:
         key = hashlib.md5(uri.encode()).hexdigest()
-        tmp = file_io.join(self.res_dir, "." + key)
+        # a temporary name a writing thread: a server's publisher and its
+        # serve loop (shed) may write at once, and must never share one
+        tmp = file_io.join(self.res_dir,
+                           f".{key}.{threading.get_ident():x}")
         with file_io.fopen(tmp, "w") as f:
             f.write(json.dumps({"uri": uri, **value}))
         file_io.replace(tmp, file_io.join(self.res_dir, key + ".json"))
@@ -515,8 +519,11 @@ class RedisQueue(QueueBackend):
             "sheddable": f"{self.STREAM}:shed",
         }
         # uri -> (stream, entry id), claimed but not yet answered; the ack
-        # in put_result closes the loop (plain dict ops are GIL-atomic, and
-        # claim/result run on different serve-loop threads)
+        # in put_result closes the loop. claim_batch stores on the serve
+        # loop and put_result pops on the writer's thread (ClusterServing's
+        # write-back, GenerativeServing's publisher): one store and one pop
+        # a uri, each atomic under the interpreter lock, and nothing reads
+        # the dict and then acts on what it read
         self._unacked: Dict[str, Tuple[str, Any]] = {}
         for lane in CRITICALITY_LANES:
             try:

@@ -25,7 +25,8 @@ import logging
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from collections import deque
+from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -135,7 +136,8 @@ _M_SPEC_ACCEPT = _metrics.gauge(
     "Mean fraction of draft tokens accepted in the last verify round.",
     labels=("server",))
 #: chunked prefill and the recurrent state (models with linear-attention
-#: layers, capture/decoder.py); they read nought for every other model
+#: layers, capture/decoder.py), which read nought for every other model,
+#: and the result publisher
 _M_GEN_COUNTERS = {
     "prefill_chunks": _metrics.counter(
         "serving.prefill_chunks_total",
@@ -149,7 +151,18 @@ _M_GEN_COUNTERS = {
         "Decode steps of the resident streams that ran while a joining "
         "prompt was between two of its chunks or waited for its first.",
         labels=("server",)),
+    # how often the result publisher lands a stream's newest record in the
+    # place of one a token
+    "partials_superseded": _metrics.counter(
+        "serving.partials_superseded_total",
+        "Partial results replaced by a newer record of the same stream "
+        "before the publisher had written them.", labels=("server",)),
 }
+#: the result publisher (_ResultPublisher): how far it is behind the loop
+_M_PUBLISH_BACKLOG = _metrics.gauge(
+    "serving.publish_backlog",
+    "Result records handed to the publisher and not yet taken up for "
+    "writing.", labels=("server",))
 _M_STATE_SLOTS = _metrics.gauge(
     "serving.state_slots_in_use",
     "Slots whose recurrent state is live: resident streams and prompts "
@@ -1165,6 +1178,175 @@ class ClusterServing:
         self.check_health()
 
 
+class _ResultPublisher:
+    """Lands a ``GenerativeServing``'s result records, off the serve loop.
+
+    The loop hands a record over and goes on to the next decode step; one
+    daemon thread calls ``queue.put_result``, so a decode iteration does
+    not wait for one write a stream a token. What a client can read is
+    what the loop itself used to write, under three rules:
+
+    - **the newest record a stream**: at most one partial a uri is
+      pending. A partial still pending when the next one of its stream
+      falls due is replaced (it carries the whole stream so far and the
+      write overwrites one idempotent record), so a publisher that keeps
+      up writes one record a due partial and one that does not writes the
+      tokens folded since that stream's last write;
+    - **terminals are never merged, dropped or reordered**: a terminal
+      takes the place of its uri's pending partial, is written before any
+      pending partial of another stream and in the order it was handed
+      over, and its accounting (``GenerativeServing._settle``) runs when
+      it has landed;
+    - **back-pressure**: pending partials are bounded by the slots; once
+      more than ``slots`` terminals are pending (a wedged backend) the
+      hand-over blocks, as the write itself used to.
+
+    The thread starts with the first record and :meth:`close` joins it
+    when everything handed over has landed; an exception that kills it
+    reaches the server's ``_background_error`` and every later hand-over.
+    All state is under one condition: the loop (or, with no loop running,
+    the thread that steps, stops or hands off) is the one producer."""
+
+    def __init__(self, server: "GenerativeServing"):
+        self._srv = server
+        self._cv = threading.Condition()
+        # (uri, value, folded, first_claim), oldest first
+        self._terminals: Deque[Tuple[str, Dict[str, Any], float,
+                                     Optional[float]]] = deque()
+        # uri -> (tokens, n, seed, folded, first_claim), in the order each
+        # uri first fell due: a replaced partial keeps its place in line
+        self._partials: Dict[str, Tuple[List[int], int, Optional[int],
+                                        float, Optional[float]]] = {}
+        self._thread: Optional[threading.Thread] = None
+        self._closing = False
+        self._dead: Optional[BaseException] = None
+
+    def _note_backlog(self) -> None:
+        self._srv._m_backlog.set(len(self._terminals) + len(self._partials))
+
+    def _handed_over(self) -> None:
+        """With the condition held, after a record went in: the gauge,
+        and a thread to take it."""
+        self._note_backlog()
+        if self._thread is None:
+            self._thread = threading.Thread(
+                target=self._run, daemon=True,
+                name=f"{self._srv.metrics_label}-publisher")
+            self._thread.start()
+        self._cv.notify_all()
+
+    def _require_alive(self) -> None:
+        if self._dead is not None:
+            raise RuntimeError("the result publisher died") from self._dead
+
+    def partials(self, due: List[Tuple[str, List[int], int, Optional[int],
+                                       Optional[float]]],
+                 folded: float) -> None:
+        """One step's due partials, ``(uri, tokens, n, seed, first_claim)``
+        each: the stream's token list with the length that counts (the
+        list only grows until its slot is cleared, so the copy and the
+        serialisation are the publisher's), ``folded`` on ``perf_counter``
+        when the newest of them was folded, and the stream's claim time
+        where this record is the first to carry a token of it."""
+        superseded = 0
+        with self._cv:
+            self._require_alive()
+            for uri, tokens, n, seed, first in due:
+                old = self._partials.get(uri)
+                if old is not None:
+                    superseded += 1
+                    if first is None:  # its first token is still unwritten
+                        first = old[4]
+                self._partials[uri] = (tokens, n, seed, folded, first)
+            self._handed_over()
+        if superseded:
+            self._srv._count("partials_superseded", superseded)
+
+    def terminal(self, uri: str, value: Dict[str, Any], folded: float,
+                 first: Optional[float] = None) -> None:
+        with self._cv:
+            self._require_alive()
+            old = self._partials.pop(uri, None)
+            if old is not None and first is None:
+                first = old[4]
+            self._terminals.append((uri, value, folded, first))
+            self._handed_over()
+            while (len(self._terminals) > self._srv.slots
+                   and self._dead is None):
+                self._cv.wait()
+        if old is not None:
+            self._srv._count("partials_superseded")
+
+    def drop_partials(self) -> None:
+        """Forget every pending partial (:meth:`GenerativeServing.handoff`:
+        the streams go to another instance, whose records a late write of
+        this one must not overwrite). Terminals stay."""
+        with self._cv:
+            self._partials.clear()
+            self._note_backlog()
+
+    def close(self) -> None:
+        """Returns when every record handed over has landed (pending
+        partials too) and the thread has ended; the next record starts a
+        new one."""
+        with self._cv:
+            thread = self._thread
+            if thread is None:
+                return
+            self._closing = True
+            self._cv.notify_all()
+        thread.join()
+
+    def _run(self) -> None:
+        try:
+            while True:
+                with self._cv:
+                    while not (self._terminals or self._partials):
+                        if self._closing:
+                            self._thread, self._closing = None, False
+                            return
+                        self._cv.wait()
+                    if self._terminals:
+                        uri, value, folded, first = self._terminals.popleft()
+                        record = None
+                    else:
+                        uri = next(iter(self._partials))
+                        record = self._partials.pop(uri)
+                    self._note_backlog()
+                    self._cv.notify_all()  # a hand-over that was blocked
+                if record is not None:
+                    tokens, n, seed, folded, first = record
+                    value = {"stream": tokens[:n], "done": False}
+                    if seed is not None:
+                        value["seed"] = seed
+                self._land(uri, value, record is None, folded, first)
+        except Exception as e:
+            logger.exception("the result publisher died")
+            self._srv._background_error = e
+            with self._cv:
+                self._dead, self._thread = e, None
+                self._cv.notify_all()
+
+    def _land(self, uri: str, value: Dict[str, Any], terminal: bool,
+              folded: float, first: Optional[float]) -> None:
+        srv = self._srv
+        try:
+            with time_it("serve.put_result"):
+                srv.queue.put_result(uri, value)
+        except Exception:
+            logger.exception("posting result for %s failed" if terminal
+                             else "partial result for %s failed", uri)
+        else:
+            if _utils.span_hooks:
+                _utils.offer_span("serve.publish_lag", folded,
+                                  time.perf_counter() - folded)
+        if first is not None and _utils.span_hooks:
+            _utils.offer_span("serve.first_token", first,
+                              time.perf_counter() - first)
+        if terminal:
+            srv._settle(uri)
+
+
 class GenerativeServing:
     """Token-level continuous batching for ``TransformerLM`` generation.
 
@@ -1500,6 +1682,8 @@ class GenerativeServing:
         self._m_spec_accept = _M_SPEC_ACCEPT.labels(
             server=self.metrics_label)
         self._m_brownout = _M_BROWNOUT.labels(server=self.metrics_label)
+        self._m_backlog = _M_PUBLISH_BACKLOG.labels(
+            server=self.metrics_label)
         self._brownout = _Brownout(self.metrics_label)
         self._m_pages_free.set(len(self._free_pages))
         self._counter_lock = threading.Lock()
@@ -1516,6 +1700,9 @@ class GenerativeServing:
         self._thread: Optional[threading.Thread] = None
         self._loop_running = False
         self._terminal_state: Optional[str] = None
+        # every result record goes through it; nothing else of this class
+        # calls queue.put_result
+        self._publisher = _ResultPublisher(self)
 
     # -- terminal accounting (ClusterServing's exactly-one-terminal rule) --
 
@@ -1537,20 +1724,27 @@ class GenerativeServing:
         base = float(t0) if t0 is not None else wall_clock()
         return base + float(deadline_ms) / 1000.0
 
-    def _post_terminal(self, uri: str, value: Dict[str, Any]) -> None:
+    def _post_terminal(self, uri: str, value: Dict[str, Any],
+                       folded: Optional[float] = None,
+                       first: Optional[float] = None) -> None:
         """Every claimed request funnels its ONE terminal result (value or
         error) through here — partial ``stream`` records do NOT. Error
         terminals carry ``retriable`` (shed yes; deadline/validation/
-        shutdown no) for the client's retry-budget discipline."""
+        shutdown no) for the client's retry-budget discipline. The
+        publisher writes it and then calls :meth:`_settle`; ``folded`` and
+        ``first`` are the publisher's (a terminal that carries a step's
+        token, the stream's first)."""
         if "error" in value and "retriable" not in value:
             value = dict(value)
             value["retriable"] = value["error"] in (SHED_ERROR,
                                                     PAGE_SHED_ERROR)
-        try:
-            with time_it("serve.put_result"):
-                self.queue.put_result(uri, value)
-        except Exception:
-            logger.exception("posting result for %s failed", uri)
+        self._publisher.terminal(
+            uri, value, time.perf_counter() if folded is None else folded,
+            first)
+
+    def _settle(self, uri: str) -> None:
+        """The accounting of a terminal that has landed (or whose write
+        failed and was logged): on the publisher's thread."""
         with self._counter_lock:
             self._in_flight = max(0, self._in_flight - 1)
             in_flight = self._in_flight
@@ -1562,10 +1756,12 @@ class GenerativeServing:
             _trace.flow_point(flow_id, "serving.result", "f")
 
     def _retire(self, slot: int, value: Dict[str, Any],
-                counter: Optional[str] = None) -> None:
+                counter: Optional[str] = None,
+                folded: Optional[float] = None,
+                first: Optional[float] = None) -> None:
         """Terminal-result a slot's stream and free its host bookkeeping
         (the DEVICE evict is the caller's one vectorized ``_evict_slots``)."""
-        self._post_terminal(self._uri[slot], value)
+        self._post_terminal(self._uri[slot], value, folded, first)
         if counter is not None:
             self._count(counter)
         elif "value" in value:
@@ -2211,37 +2407,74 @@ class GenerativeServing:
             self._evict_slots(mask)
 
     def _post_tokens(self, nxt: np.ndarray) -> None:
-        """Fold one step's tokens into every active stream: TTFT on the
-        first token, partial results every ``stream_interval`` tokens,
-        terminal value + evict on eos / budget exhaustion."""
-        now = wall_clock()
+        """Fold one step's tokens, one an active stream."""
+        self._fold((i, (int(nxt[i]),)) for i in range(self.slots)
+                   if self._active_host[i])
+
+    def _post_tokens_spec(self, emitted: np.ndarray,
+                          n_acc: np.ndarray) -> None:
+        """Fold one speculative round's ACCEPTED tokens — the rules of
+        :meth:`_fold`, but up to ``spec_k + 1`` tokens land per stream per
+        round. The budget clamp and eos truncation are host-side; a stream
+        they cut short is retired in the same pass, so the device's
+        over-advanced length never feeds another step."""
+        eos = self.config.eos_id
+
+        def accepted():
+            for i in range(self.slots):
+                if not self._active_host[i]:
+                    continue
+                take = min(int(n_acc[i]),
+                           self._budget[i] - len(self._tokens[i]))
+                toks = [int(x) for x in emitted[i, :take]]
+                if eos is not None and eos in toks:
+                    toks = toks[:toks.index(eos) + 1]
+                if toks:
+                    yield i, toks
+        self._fold(accepted())
+
+    def _fold(self, taken) -> None:
+        """Fold a step's new tokens, ``(slot, tokens)`` a stream, into the
+        streams: TTFT on the first token, a partial result due every
+        ``stream_interval`` tokens, terminal value + evict on eos / budget
+        exhaustion. The records go to the publisher; no write is waited
+        for here. A stream's claim time rides with the first record that
+        carries a token of it, so that ``serve.first_token`` ends when a
+        client could see one."""
+        now, folded = wall_clock(), time.perf_counter()
         cfg = self.config
         # brownout L1+: coarser partials — every queue write the streamers
         # skip is backend bandwidth returned to terminals
-        stream_stride = self._brownout.stream_stride(cfg.stream_interval)
+        stride = self._brownout.stream_stride(cfg.stream_interval)
         finished = np.zeros(self.slots, bool)
-        n_tok = 0
-        for i in range(self.slots):
-            if not self._active_host[i]:
-                continue
-            tok = int(nxt[i])
-            self._tokens[i].append(tok)
-            self._next_tokens[i] = tok
-            n_tok += 1
-            first = self._first_t[i] is None
-            if first:
+        due, n_tok = [], 0
+        for i, toks in taken:
+            self._tokens[i].extend(toks)
+            self._next_tokens[i] = toks[-1]
+            n_tok += len(toks)
+            claimed = None
+            if self._first_t[i] is None:
                 self._first_token_seen(i, now)
-            if (len(self._tokens[i]) >= self._budget[i]
-                    or (cfg.eos_id is not None and tok == cfg.eos_id)):
+                if _utils.span_hooks:
+                    claimed = self._claim_pc[i]
+            have = len(self._tokens[i])
+            if (have >= self._budget[i]
+                    or (cfg.eos_id is not None and toks[-1] == cfg.eos_id)):
                 finished[i] = True
-                self._retire(i, {"value": list(self._tokens[i]),
-                                 "done": True})
-            elif (stream_stride > 0
-                  and (len(self._tokens[i]) - self._streamed[i]
-                       >= stream_stride)):
-                self._post_partial(i)
-            if first and _utils.span_hooks:
-                self._first_token_posted(i)
+                # the list is the record's from here: the slot lets go of it
+                self._retire(i, {"value": self._tokens[i], "done": True},
+                             folded=folded, first=claimed)
+            elif stride > 0 and have - self._streamed[i] >= stride:
+                # the list and the length that counts: the copy and the
+                # serialisation are the publisher's
+                due.append((self._uri[i], self._tokens[i], have,
+                            self._seed[i], claimed))
+                self._streamed[i] = have
+            elif claimed is not None:  # no record carries this token
+                _utils.offer_span("serve.first_token", claimed,
+                                  time.perf_counter() - claimed)
+        if due:
+            self._publisher.partials(due, folded)
         if n_tok:
             self._m_tokens.inc(n_tok)
         if finished.any():
@@ -2257,92 +2490,26 @@ class GenerativeServing:
             if meta is not None:
                 _trace.flow_point(meta[1], "serving.first_token", "t")
 
-    def _first_token_posted(self, slot: int) -> None:
-        """The span from a stream's claim to its first token posted (the
-        write just before this call)."""
-        _utils.offer_span("serve.first_token", self._claim_pc[slot],
-                          time.perf_counter() - self._claim_pc[slot])
-
-    def _post_partial(self, slot: int) -> None:
-        try:
-            with time_it("serve.put_result"):
-                self.queue.put_result(self._uri[slot], self._partial(slot))
-            self._streamed[slot] = len(self._tokens[slot])
-        except Exception:
-            logger.exception("partial result for %s failed",
-                             self._uri[slot])
-
-    def _partial(self, slot: int) -> Dict[str, Any]:
-        """A stream-progress record: accumulated tokens + the sampling seed
-        (when sampling). The seed is the failover handle — a router that
-        adopts the stream re-enqueues ``{prefix: stream, seed: seed}`` and
-        the adopting server's key schedule resumes bit-identically."""
-        out: Dict[str, Any] = {"stream": list(self._tokens[slot]),
-                               "done": False}
-        if self._seed[slot] is not None:
-            out["seed"] = self._seed[slot]
-        return out
-
-    def _post_tokens_spec(self, emitted: np.ndarray,
-                          n_acc: np.ndarray) -> None:
-        """Fold one speculative round's ACCEPTED tokens into every active
-        stream — same TTFT/stream/terminal rules as ``_post_tokens``, but
-        up to ``spec_k + 1`` tokens land per stream per round. The budget
-        clamp and eos truncation are host-side; a stream they cut short is
-        retired in the same pass, so the device's over-advanced length
-        never feeds another step."""
-        now = wall_clock()
-        cfg = self.config
-        # brownout L1+: coarser partials — every queue write the streamers
-        # skip is backend bandwidth returned to terminals
-        stream_stride = self._brownout.stream_stride(cfg.stream_interval)
-        finished = np.zeros(self.slots, bool)
-        n_tok = 0
-        for i in range(self.slots):
-            if not self._active_host[i]:
-                continue
-            take = min(int(n_acc[i]),
-                       self._budget[i] - len(self._tokens[i]))
-            toks = [int(x) for x in emitted[i, :take]]
-            if cfg.eos_id is not None and cfg.eos_id in toks:
-                toks = toks[:toks.index(cfg.eos_id) + 1]
-            if not toks:
-                continue
-            self._tokens[i].extend(toks)
-            self._next_tokens[i] = toks[-1]
-            n_tok += len(toks)
-            first = self._first_t[i] is None
-            if first:
-                self._first_token_seen(i, now)
-            if (len(self._tokens[i]) >= self._budget[i]
-                    or (cfg.eos_id is not None and toks[-1] == cfg.eos_id)):
-                finished[i] = True
-                self._retire(i, {"value": list(self._tokens[i]),
-                                 "done": True})
-            elif (stream_stride > 0
-                  and (len(self._tokens[i]) - self._streamed[i]
-                       >= stream_stride)):
-                self._post_partial(i)
-            if first and _utils.span_hooks:
-                self._first_token_posted(i)
-        if n_tok:
-            self._m_tokens.inc(n_tok)
-        if finished.any():
-            self._evict_slots(finished)
-
     def serve_step(self) -> int:
         """One scheduler step: evict expired streams, admit new requests
         into free slots (shed + bucketed prefill), run ONE fused decode
         step over every occupied slot, stream/terminate per token. Returns
         the number of streams stepped — the single-step form tests and
-        the bench drive directly; :meth:`run` loops it."""
-        if not _utils.span_hooks:
-            return self._serve_step()
-        t0 = time.perf_counter()
-        stepped = self._serve_step()
-        if stepped:  # an iteration that dispatched a step: parent of the rest
-            _utils.offer_span("serve.step", t0, time.perf_counter() - t0)
-        return stepped
+        the bench drive directly; :meth:`run` loops it. Stepped by hand,
+        with no loop running, it returns when the step's records have
+        landed, so the caller reads the result store as the step left
+        it."""
+        try:
+            if not _utils.span_hooks:
+                return self._serve_step()
+            t0 = time.perf_counter()
+            stepped = self._serve_step()
+            if stepped:  # an iteration that dispatched a step: parent of the rest
+                _utils.offer_span("serve.step", t0, time.perf_counter() - t0)
+            return stepped
+        finally:
+            if not self._loop_running:
+                self._publisher.close()
 
     def _serve_step(self) -> int:
         self._maybe_write_health()
@@ -2438,9 +2605,17 @@ class GenerativeServing:
                     with time_it("serve.idle"):
                         time.sleep(poll_interval_s)
         finally:
-            self._loop_running = False
-            if self._stop.is_set():
-                self._fail_active(SHUTDOWN_ERROR)
+            # the loop counts as running until its last record has landed:
+            # drain()'s terminal health, stop()'s return and handoff()'s
+            # re-enqueue all come after every write of this instance
+            try:
+                if self._stop.is_set():
+                    self._fail_active(SHUTDOWN_ERROR)
+                if self._handoff_evt.is_set():
+                    self._publisher.drop_partials()
+                self._publisher.close()
+            finally:
+                self._loop_running = False
             self._maybe_write_health()
 
     def start(self) -> "GenerativeServing":
@@ -2450,6 +2625,8 @@ class GenerativeServing:
         self._handoff_evt.clear()
         self._terminal_state = None
         self._background_error: Optional[BaseException] = None
+        # a new start takes a new publisher: one that died stays dead
+        self._publisher = _ResultPublisher(self)
 
         def _run() -> None:
             try:
@@ -2515,6 +2692,11 @@ class GenerativeServing:
                         f"handoff: serve loop did not pause within "
                         f"{timeout_s}s")
                 time.sleep(0.002)
+        # no partial of a stream that is about to be another instance's may
+        # land after its re-enqueue: forget them, wait for the write in
+        # flight (the loop did both as it paused; with no loop, here)
+        self._publisher.drop_partials()
+        self._publisher.close()
         moved = 0
         mask = np.zeros(self.slots, bool)
         for i in range(self.slots):
@@ -2557,6 +2739,7 @@ class GenerativeServing:
             moved += 1
         if mask.any():
             self._evict_slots(mask)
+        self._publisher.close()  # the terminals of failed re-enqueues
         if self._terminal_state is None:
             self._terminal_state = "drained"
             _E_LIFECYCLE.emit(label=self.metrics_label, state="drained")
@@ -2577,7 +2760,10 @@ class GenerativeServing:
                     "(queue backend wedged?); thread leaked")
             self._thread = None
         else:
-            self._fail_active(SHUTDOWN_ERROR)
+            try:
+                self._fail_active(SHUTDOWN_ERROR)
+            finally:
+                self._publisher.close()
         if self._terminal_state is None:
             self._terminal_state = "stopped"
             _E_LIFECYCLE.emit(label=self.metrics_label, state="stopped")

@@ -129,12 +129,22 @@ def test_one_serve_step_under_a_listening_hook(ctx, tmp_path, tiny_lm):
     for wait in waits:
         assert wait[2] >= 0.0
         assert claim[1] <= wait[1] + wait[2] <= admit[1] + admit[2]
+    # the first token of each stream was posted: the publisher's two
+    # writes, behind the hand-over in the post and before serve_step, with
+    # no loop running, returned; a first token ends with the write that
+    # carries it, and each written record says how long it waited
+    writes = heard.named("serve.put_result")
+    assert len(writes) == 2 and all(w[1] >= post[1] for w in writes)
+    ends = sorted(w[1] + w[2] for w in writes)
     for first in firsts:
         assert claim[1] <= first[1] <= admit[1] + admit[2]
-        assert inside((None, first[1] + first[2], 0.0), post)
-    # the first token of each stream was posted: a write inside the post
-    writes = heard.named("serve.put_result")
-    assert len(writes) == 2 and all(inside(w, post) for w in writes)
+    assert all(a <= b for a, b in zip(
+        ends, sorted(f[1] + f[2] for f in firsts)))
+    lags = heard.named("serve.publish_lag")
+    assert len(lags) == 2
+    assert all(inside((None, lag[1], 0.0), post) for lag in lags)
+    assert ends == pytest.approx(sorted(g[1] + g[2] for g in lags),
+                                 abs=5e-3)
     # the profiler is off: its phase spans reach the listener all the same
     # (the dispatch, the fetch, a prefill dispatch a join), its histograms
     # take nothing
