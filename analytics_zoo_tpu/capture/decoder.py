@@ -5,10 +5,12 @@ by layer, and :class:`LayeredDecoder` runs it behind
 the server: ``params``, ``max_len``, cache construction, a paged decode
 step, a prefill; here ``paged_state_step`` and ``prefill_chunk``).
 
-What it covers today is what MiniCPM-SALA needs (docs/models.md): RMS
-norms, a gated SiLU feed-forward, an untied head, MiniCPM's embedding,
-residual and logit scalings, bfloat16 parameters, and a mixer chosen by
-layer:
+What it covers today is what MiniCPM-SALA and SmallThinker need
+(docs/models.md): RMS norms, an untied head, MiniCPM's embedding, residual
+and logit scalings, bfloat16 parameters, a feed-forward chosen by the spec
+(``"silu"``: gated SiLU; ``"moe"``: a softmax router on the layer's input,
+before attention, and dropless top-k ReGLU experts, ``ops/moe.py``), and a
+mixer chosen by layer:
 
 - ``"lightning-attn"``: linear attention with a decay a head
   (``ops/linear_attention.py``), ``qk_norm``, rotary positions, an output
@@ -16,13 +18,19 @@ layer:
 - ``"minicpm4"``: grouped-query block-sparse softmax attention
   (``ops/sparse_attention.py``), ``qk_norm``, no positions, an output gate.
   Its cache is a K/V page pool with a compressed-key pool beside it.
+- ``"full"``: grouped-query causal softmax attention with no positions,
+  every key up to the query's own (``ops/grouped_attention.py``).
+- ``"window"``: the same over the last ``window`` positions, with rotary
+  positions. Its K/V page pool is a budget of its own: the pages that lie
+  wholly behind a stream's window go back to the server's window free list.
 
-Both kinds of cache live in the one list the server holds and donates. A
-model with a recurrent layer is prefilled in chunks
-(:meth:`LayeredDecoder.prefill_chunk`): each chunk carries the states and
-the pages on from where the last one left them, so the server can run
-decode steps of the resident streams between two chunks of a joining
-prompt. ``TransformerLM`` stays as it is for GPT-2-style models (ROADMAP
+Every kind of cache lives in the one list the server holds and donates.
+The model is prefilled in chunks (:meth:`LayeredDecoder.prefill_chunk`,
+``chunked``): each chunk carries the states and the pages on from where the
+last one left them, so the server can run decode steps of the resident
+streams between two chunks of a joining prompt. ``recurrent`` says whether
+a slot also holds a state (a lightning layer). ``TransformerLM`` stays as
+it is for GPT-2-style models (ROADMAP
 D6)."""
 from __future__ import annotations
 
@@ -33,7 +41,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.decode import _page_positions, _paged_write
+from ..ops import grouped_attention, moe
+from ..ops.decode import _page_positions, _paged_write, init_paged_pool
 from ..ops.linear_attention import (lightning_slopes, linear_attention_chunk,
                                     linear_attention_step)
 from ..ops.sparse_attention import (SparseSpec, attend_chunk, attend_step,
@@ -42,6 +51,8 @@ from ..ops.sparse_attention import (SparseSpec, attend_chunk, attend_step,
                                     select_step)
 
 LINEAR, SPARSE = "lightning-attn", "minicpm4"
+FULL, WINDOW = "full", "window"
+SILU, MOE = "silu", "moe"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,24 +77,54 @@ class DecoderSpec:
     logit_divisor: float = 1.0     # logits = W_head (N(x) / logit_divisor)
     param_dtype: str = "bfloat16"
     init_std: float = 0.02
+    #: the feed-forward: ``"silu"`` (gated SiLU, ``intermediate`` wide) or
+    #: ``"moe"`` (``experts`` ReGLU experts ``intermediate`` wide,
+    #: ``experts_per_token`` a token, routed from the layer's input)
+    ffn: str = SILU
+    experts: int = 0
+    experts_per_token: int = 0
+    #: the experts whose tables this chip holds (``None``: all of them)
+    held_experts: Optional[Tuple[int, ...]] = None
+    window: int = 0                # positions a ``"window"`` layer sees
+    page_len: int = 0              # 0: one page is one selection block
 
     def __post_init__(self):
-        unknown = set(self.mixers) - {LINEAR, SPARSE}
+        known = (LINEAR, SPARSE, FULL, WINDOW)
+        unknown = set(self.mixers) - set(known)
         if unknown:
             raise ValueError(f"no mixer named {sorted(unknown)}; have "
-                             f"{LINEAR!r} and {SPARSE!r}")
+                             f"{', '.join(map(repr, known))}")
+        if self.ffn not in (SILU, MOE):
+            raise ValueError(f"no feed-forward named {self.ffn!r}; have "
+                             f"{SILU!r} and {MOE!r}")
+        if self.ffn == MOE and not 0 < self.experts_per_token <= self.experts:
+            raise ValueError(f"{self.experts_per_token} experts a token of "
+                             f"{self.experts}")
+        if WINDOW in self.mixers and (self.window < 1
+                                      or self.window % self.page):
+            raise ValueError(f"a window layer's window must be whole pages "
+                             f"of {self.page}; got {self.window}")
         if self.heads % self.kv_heads:
             raise ValueError(f"{self.heads} query heads over "
                              f"{self.kv_heads} key/value heads")
-        if self.max_len % self.sparse.block_size:
+        if self.max_len % self.page:
             raise ValueError(f"max_len {self.max_len} is no whole number of "
-                             f"pages of {self.sparse.block_size}")
+                             f"pages of {self.page}")
+
+    @property
+    def page(self) -> int:
+        return self.page_len or self.sparse.block_size
 
     @classmethod
-    def from_config(cls, cfg: Dict[str, Any], max_len: int) -> "DecoderSpec":
-        """From a ``minicpm_sala`` ``config.json`` (its keys as published;
-        ``sparse_attention`` holds InfLLM-V2's sizes, which the published
-        file leaves to the code)."""
+    def from_config(cls, cfg: Dict[str, Any], max_len: int,
+                    page_len: int = 64) -> "DecoderSpec":
+        """From a ``config.json`` with its keys as published: a
+        ``minicpm_sala`` one (``mixer_types``; ``sparse_attention`` holds
+        InfLLM-V2's sizes, which the published file leaves to the code) or
+        a SmallThinker one (``sliding_window_layout`` with ``rope_layout``
+        and the ``moe_*`` keys; ``page_len`` is the K/V page)."""
+        if "sliding_window_layout" in cfg:
+            return cls._from_window_layout(cfg, max_len, page_len)
         return cls(
             vocab_size=cfg["vocab_size"], hidden=cfg["hidden_size"],
             intermediate=cfg["intermediate_size"],
@@ -101,6 +142,29 @@ class DecoderSpec:
             param_dtype=cfg.get("param_dtype", "bfloat16"),
             init_std=cfg.get("initializer_range", 0.02))
 
+    @classmethod
+    def _from_window_layout(cls, cfg, max_len, page_len):
+        layout = list(cfg["sliding_window_layout"])
+        if layout != list(cfg["rope_layout"]) \
+                or len(layout) != cfg["num_hidden_layers"]:
+            raise ValueError(
+                "sliding_window_layout and rope_layout must name the same "
+                "num_hidden_layers layers: a window layer has rotary "
+                "positions and a full layer has none")
+        return cls(
+            vocab_size=cfg["vocab_size"], hidden=cfg["hidden_size"],
+            intermediate=cfg["moe_ffn_hidden_size"],
+            mixers=tuple(WINDOW if w else FULL for w in layout),
+            heads=cfg["num_attention_heads"],
+            kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+            linear_heads=0, linear_head_dim=0, max_len=max_len,
+            rms_eps=cfg["rms_norm_eps"], rope_theta=cfg["rope_theta"],
+            param_dtype=cfg.get("param_dtype", "bfloat16"),
+            init_std=cfg.get("initializer_range", 0.02), ffn=MOE,
+            experts=cfg["moe_num_primary_experts"],
+            experts_per_token=cfg["moe_num_active_primary_experts"],
+            window=cfg["sliding_window_size"], page_len=page_len)
+
     def layer_shapes(self, kind: str):
         d, f = self.hidden, self.intermediate
         if kind == LINEAR:
@@ -109,13 +173,20 @@ class DecoderSpec:
         else:
             h, kv, hd = (self.heads * self.head_dim,
                          self.kv_heads * self.head_dim, self.head_dim)
-        mats = {"q": (d, h), "k": (d, kv), "v": (d, kv), "o": (h, d),
-                "g": (d, h), "gate_proj": (d, f), "up_proj": (d, f),
-                "down_proj": (f, d)}
-        ones = {"norm1": (d,), "norm2": (d,), "q_norm": (hd,),
-                "k_norm": (hd,)}
+        mats = {"q": (d, h), "k": (d, kv), "v": (d, kv), "o": (h, d)}
+        ones = {"norm1": (d,), "norm2": (d,)}
+        if kind in (LINEAR, SPARSE):    # qk_norm and an output gate
+            mats["g"] = (d, h)
+            ones.update(q_norm=(hd,), k_norm=(hd,))
         if kind == LINEAR:
             ones["o_norm"] = (h,)
+        if self.ffn == MOE:
+            e = self.experts if self.held_experts is None \
+                else len(self.held_experts)
+            mats.update(router=(d, self.experts), w_gate=(e, d, f),
+                        w_up=(e, d, f), w_down=(e, f, d))
+        else:
+            mats.update(gate_proj=(d, f), up_proj=(d, f), down_proj=(f, d))
         return mats, ones
 
 
@@ -148,25 +219,34 @@ class LayeredDecoder:
     a dict a layer (:meth:`DecoderSpec.layer_shapes`); they come from
     :meth:`init_params` or are handed over whole (:meth:`set_params`)."""
 
-    #: the server prefills such a model in chunks, keeps a state a slot
-    #: beside the pages, and refuses what would need a snapshot of the
-    #: state (shared prefixes, speculative decoding)
-    recurrent = True
+    #: the server prefills such a model in chunks (:meth:`prefill_chunk`)
+    chunked = True
 
     def __init__(self, spec: DecoderSpec, seed: int = 0,
                  prefill_chunk: int = 2048):
         self.spec = spec
+        #: a slot holds a state beside its pages (a lightning layer): the
+        #: server refuses what would need a snapshot of it
+        self.recurrent = LINEAR in spec.mixers
+        #: positions a window layer sees, 0 where the model has none: the
+        #: server then keeps a second page budget (:meth:`window_pages`)
+        self.window_len = spec.window if WINDOW in spec.mixers else 0
+        #: what ``paged_state_step`` returns third, by name, in order
+        self.step_stats = (("moe_experts_touched", "moe_expert_load",
+                            "moe_assignments") if spec.ffn == MOE
+                           else ("sparse_positions_read",))
         #: a prompt is fed in chunks of ``prefill_chunk`` positions, the
         #: last padded to one of these: a closed set of compiled programs
         self.chunk_buckets = (prefill_chunk // 4, prefill_chunk // 2,
                               prefill_chunk)
-        if any(b % spec.sparse.block_size for b in self.chunk_buckets):
+        if any(b % spec.page for b in self.chunk_buckets):
             raise ValueError(f"chunk buckets {self.chunk_buckets} must be "
-                             f"whole pages of {spec.sparse.block_size}")
+                             f"whole pages of {spec.page}")
         self.vocab_size = spec.vocab_size
         self.max_len = spec.max_len
         self.n_head = spec.heads
-        self.page_len = spec.sparse.block_size   # one page, one block
+        self.page_len = spec.page   # a sparse layer's page is its block
+        self.page_is = "selection block" if SPARSE in spec.mixers else "page"
         self.prefill_chunk_len = prefill_chunk
         self._seed = seed
         self._params: Optional[Dict[str, Any]] = None
@@ -210,37 +290,53 @@ class LayeredDecoder:
 
     def fit(self, *args, **kwargs):
         raise NotImplementedError(
-            "LayeredDecoder has no training path: a linear-attention layer "
-            "needs the chunked scan's backward, which is not written "
-            "(ROADMAP R3.5)")
+            "LayeredDecoder has no training path: it has no loss, and a "
+            "linear-attention layer needs the chunked scan's backward, "
+            "which is not written (ROADMAP R3.5)")
 
     # -- caches ---------------------------------------------------------------
 
     def init_paged_caches(self, num_pages: int, page_len: int,
                           int8: bool = False, slots: int = 1) -> List[Dict]:
         """One cache a layer, in layer order: a sparse layer's K/V page
-        pool with its compressed-key pool, a lightning layer's state
-        ``[slots, H, D, D]`` in float32."""
+        pool with its compressed-key pool, a full layer's K/V page pool
+        (``num_pages`` each), a window layer's (:meth:`window_pages`), a
+        lightning layer's state ``[slots, H, D, D]`` in float32."""
         spec = self.spec
         if int8:
             raise NotImplementedError(
-                "int8 pages are not wired into the sparse layers' pools "
-                "(the selected-page read has no dequantising gather)")
-        if page_len != spec.sparse.block_size:
-            raise ValueError(f"kv_page_len must be the model's selection "
-                             f"block, {spec.sparse.block_size}; got "
-                             f"{page_len}")
+                "int8 pages are not wired into this decoder's pools (the "
+                "paged reads have no dequantising gather)")
+        if page_len != spec.page:
+            raise ValueError(f"kv_page_len must be the model's "
+                             f"{self.page_is}, {spec.page}; got {page_len}")
+        dtype = jnp.dtype(spec.param_dtype)
         caches = []
         for kind in spec.mixers:
             if kind == SPARSE:
                 caches.append(init_sparse_pool(
                     num_pages, spec.sparse, spec.kv_heads, spec.head_dim,
-                    jnp.dtype(spec.param_dtype)))
+                    dtype))
+            elif kind in (FULL, WINDOW):
+                caches.append(init_paged_pool(
+                    num_pages if kind == FULL else self.window_pages(slots),
+                    spec.kv_heads, page_len, spec.head_dim, dtype))
             else:
                 caches.append({"state": jnp.zeros(
                     (slots, spec.linear_heads, spec.linear_head_dim,
                      spec.linear_head_dim), jnp.float32)})
         return caches
+
+    def window_pages(self, slots: int) -> int:
+        """Pages of the window layers' budget: what ``slots`` decoding
+        streams hold at most (the pages their window can lie on), what the
+        one prompt being fed holds beyond that while a chunk runs, and the
+        null page. 0 where the model has no window layer."""
+        if not self.window_len:
+            return 0
+        return (slots * grouped_attention.window_pages(self.window_len,
+                                                       self.page_len)
+                + self.prefill_chunk_len // self.page_len + 1)
 
     # -- the block ------------------------------------------------------------
 
@@ -258,10 +354,12 @@ class LayeredDecoder:
         rotary where ``positions`` is given."""
         spec = self.spec
         lead = u.shape[:-1]
-        q = _rms_norm(p["q_norm"], _product(u, p["q"]).reshape(
-            lead + (heads, head_dim)), spec.rms_eps)
-        k = _rms_norm(p["k_norm"], _product(u, p["k"]).reshape(
-            lead + (kv_heads, head_dim)), spec.rms_eps)
+        q = _product(u, p["q"]).reshape(lead + (heads, head_dim))
+        if "q_norm" in p:   # a lightning or a sparse layer's qk_norm
+            q = _rms_norm(p["q_norm"], q, spec.rms_eps)
+        k = _product(u, p["k"]).reshape(lead + (kv_heads, head_dim))
+        if "k_norm" in p:
+            k = _rms_norm(p["k_norm"], k, spec.rms_eps)
         v = _product(u, p["v"]).reshape(lead + (kv_heads, head_dim))
         if positions is not None:
             q = _rotary(q, positions, spec.rope_theta)
@@ -273,6 +371,38 @@ class LayeredDecoder:
         gate = jax.nn.sigmoid(_product(u, p["g"]))
         return x + self.spec.residual_scale * _product(o * gate, p["o"])
 
+    def _attend_paged(self, kind, p, x, u, cache, table, positions, attend):
+        """A full or window layer over ``u [T, d]``: project, write the new
+        K and V through ``table``, read (``attend(q, cache)``), project out.
+        A step hands ``table [S, W]`` and ``positions [S, 1]``, a chunk
+        ``table [1, W]`` and ``positions [1, T]``."""
+        spec = self.spec
+        with jax.named_scope("attention"):
+            q, k, v = self._project(
+                p, u, spec.heads, spec.kv_heads, spec.head_dim,
+                positions.reshape(-1) if kind == WINDOW else None)
+            q = q.reshape(q.shape[0], spec.kv_heads, -1, spec.head_dim)
+        pages, offs = _page_positions(table, positions, self.page_len)
+        cache = _paged_write(cache, pages, offs,
+                             k.reshape(positions.shape + k.shape[1:]),
+                             v.reshape(positions.shape + v.shape[1:]),
+                             inline_amax=False)
+        o = attend(q, cache)
+        with jax.named_scope("attention"):
+            x = x + spec.residual_scale * _product(
+                o.reshape(o.shape[0], -1), p["o"])
+        return x, cache
+
+    def _moe(self, p, x, choice, gates, valid):
+        """``x + experts(N(x))`` under the routing made from the layer's
+        input; also each expert's assignments."""
+        spec = self.spec
+        with jax.named_scope("layer_norm"):
+            h = _rms_norm(p["norm2"], x, spec.rms_eps)
+        y, sizes = moe.experts(h, choice, gates, p["w_gate"], p["w_up"],
+                               p["w_down"], spec.held_experts, valid)
+        return x + spec.residual_scale * y, sizes
+
     def _embed(self, params, tokens):
         with jax.named_scope("embed"):
             return self.spec.embed_scale * jnp.take(
@@ -283,20 +413,38 @@ class LayeredDecoder:
     def paged_state_step(self, params, tokens, lengths, table, caches,
                          active=None):
         """One decode step over all slots: ``tokens [S]`` at positions
-        ``lengths [S]``. Returns ``(logits [S, V], caches, read)``, ``read``
-        the positions a sparse layer gathered for a stream in this step
-        (the mean over the sparse layers, as the device counted them). Only
-        an ``active`` slot's state moves."""
+        ``lengths [S]``. ``table`` is the page table, or ``(full, window)``
+        where the model has window layers. Returns ``(logits [S, V],
+        caches, stats)``, ``stats`` what :attr:`step_stats` names: the
+        positions a sparse layer gathered for a stream in this step (the
+        mean over the sparse layers, as the device counted them), or, for
+        routed experts, the distinct experts a layer used, its busiest
+        expert's assignments over the mean (both means over the layers) and
+        the assignments of all layers, the active slots' alone. Only an
+        ``active`` slot's state moves, and only an active slot's token
+        reads an expert."""
         spec = self.spec
         tokens = jnp.asarray(tokens, jnp.int32)
         if active is None:
             active = jnp.ones(tokens.shape, bool)
+        table, window_table = table if isinstance(table, (tuple, list)) \
+            else (table, table)
         x = self._embed(params, tokens)                         # [S, d]
-        new_caches, reads = [], []
+        new_caches, reads, loads = [], [], []
         for kind, p, cache in zip(spec.mixers, params["layers"], caches):
+            if spec.ffn == MOE:  # the router sees the layer's input as it is
+                choice, gates = moe.route(x, p["router"],
+                                          spec.experts_per_token)
             with jax.named_scope("layer_norm"):
                 u = _rms_norm(p["norm1"], x, spec.rms_eps)
-            if kind == LINEAR:
+            if kind in (FULL, WINDOW):
+                window = spec.window if kind == WINDOW else None
+                tab = window_table if kind == WINDOW else table
+                x, cache = self._attend_paged(
+                    kind, p, x, u, cache, tab, lengths[:, None],
+                    lambda q, c: grouped_attention.attend_step(
+                        q, c, tab, lengths, active, window))
+            elif kind == LINEAR:
                 with jax.named_scope("attention"):
                     q, k, v = self._project(
                         p, u, spec.linear_heads, spec.linear_heads,
@@ -330,10 +478,19 @@ class LayeredDecoder:
                 reads.append(read)
                 with jax.named_scope("attention"):
                     x = self._mix_out(p, x, u, o.reshape(s, -1))
-            x = self._ffn(p, x)
+            if spec.ffn == MOE:
+                x, sizes = self._moe(p, x, choice, gates, active)
+                loads.append(moe.load_stats(sizes))
+            else:
+                x = self._ffn(p, x)
             new_caches.append(cache)
-        read = jnp.mean(jnp.stack(reads).astype(jnp.float32)) if reads \
-            else jnp.float32(0)
+        if loads:
+            loads = jnp.stack(loads)                            # [layers, 3]
+            read = jnp.concatenate([jnp.mean(loads[:, :2], axis=0),
+                                    jnp.sum(loads[:, 2:], axis=0)])
+        else:
+            read = jnp.mean(jnp.stack(reads).astype(jnp.float32)) if reads \
+                else jnp.float32(0)
         return self._head(params, x), new_caches, read
 
     def _head(self, params, x):
@@ -366,8 +523,9 @@ class LayeredDecoder:
     def prefill_chunk(self, params, tokens, caches, row, slot, start,
                       n_valid):
         """Feed ``tokens [1, T]`` at positions ``start .. start+T-1`` of the
-        stream in ``slot`` whose pages ``row [W]`` names; the first
-        ``n_valid`` are real. ``start`` is a multiple of the page. States
+        stream in ``slot`` whose pages ``row [W]`` names (``(full, window)``
+        rows where the model has window layers); the first ``n_valid`` are
+        real. ``start`` is a multiple of the page. States
         and pages are carried on from the chunk before (a chunk at 0 starts
         them anew), so the caller may run other programs over the same
         caches between two chunks. Returns the caches."""
@@ -375,11 +533,24 @@ class LayeredDecoder:
         x = self._embed(params, jnp.asarray(tokens, jnp.int32)[0])  # [T, d]
         t = x.shape[0]
         positions = start + jnp.arange(t, dtype=jnp.int32)
+        row, window_row = row if isinstance(row, (tuple, list)) \
+            else (row, row)
+        real = jnp.arange(t) < n_valid
         new_caches = []
         for kind, p, cache in zip(spec.mixers, params["layers"], caches):
+            if spec.ffn == MOE:
+                choice, gates = moe.route(x, p["router"],
+                                          spec.experts_per_token)
             with jax.named_scope("layer_norm"):
                 u = _rms_norm(p["norm1"], x, spec.rms_eps)
-            if kind == LINEAR:
+            if kind in (FULL, WINDOW):
+                window = spec.window if kind == WINDOW else None
+                pages = window_row if kind == WINDOW else row
+                x, cache = self._attend_paged(
+                    kind, p, x, u, cache, pages[None], positions[None],
+                    lambda q, c: grouped_attention.attend_chunk(
+                        q, c, pages, start, window))
+            elif kind == LINEAR:
                 with jax.named_scope("attention"):
                     q, k, v = self._project(
                         p, u, spec.linear_heads, spec.linear_heads,
@@ -415,6 +586,9 @@ class LayeredDecoder:
                 o = attend_chunk(spec.sparse, q, cache, row, start, allowed)
                 with jax.named_scope("attention"):
                     x = self._mix_out(p, x, u, o.reshape(t, -1))
-            x = self._ffn(p, x)
+            if spec.ffn == MOE:
+                x, _ = self._moe(p, x, choice, gates, real)
+            else:
+                x = self._ffn(p, x)
             new_caches.append(cache)
         return new_caches

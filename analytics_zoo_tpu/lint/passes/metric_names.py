@@ -53,10 +53,12 @@ _GAUGE_UNITLESS_OK = {"serving.in_flight", "serving.slots_occupied",
                       "fleet.instances_alive", "fleet.desired_instances",
                       "cluster.leases_alive", "serving.brownout_level",
                       "fleet.breaker_state", "serving.state_slots_in_use",
-                      "serving.publish_backlog"}
+                      "serving.publish_backlog", "serving.kv_pages_in_use"}
 #: histograms of a count, not of a duration: exempt from the suffix rule
 _HISTOGRAM_UNITLESS_OK = {"serving.sparse_positions_read",
-                           "serving.paged_pages_read"}
+                           "serving.paged_pages_read",
+                           "serving.moe_experts_touched",
+                           "serving.moe_expert_load"}
 
 
 def _is_registration(node: ast.Call) -> bool:

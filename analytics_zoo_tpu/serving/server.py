@@ -179,6 +179,33 @@ _M_PAGES_READ = _metrics.histogram(
     "given: the live pages where the kernel reads them in place, the "
     "table's rectangle under the XLA form; one observation a step.",
     labels=("server",))
+#: a model whose layers keep two page budgets (window layers beside full
+#: ones) and routes tokens to experts (capture/decoder.py, ops/moe.py)
+_M_PAGES_IN_USE = _metrics.gauge(
+    "serving.kv_pages_in_use",
+    "KV pages that streams or registered prefixes hold, by the kind of "
+    "layer whose budget they come from: full (every model's one page "
+    "table) or window (pages that lie behind a stream's window go back).",
+    labels=("server", "kind"))
+_M_WINDOW_RELEASED = _metrics.counter(
+    "serving.window_pages_released_total",
+    "Window-layer pages given back because they lay wholly behind their "
+    "stream's window (not those freed by the stream's end).",
+    labels=("server",))
+_M_MOE_TOUCHED = _metrics.histogram(
+    "serving.moe_experts_touched",
+    "Distinct experts that a layer's active tokens were routed to in one "
+    "decode step, the mean over the layers, as the step program counted "
+    "them: one observation a step.", labels=("server",))
+_M_MOE_LOAD = _metrics.histogram(
+    "serving.moe_expert_load",
+    "Assignments that the busiest expert of a layer got in one decode "
+    "step over the mean of all experts, the mean over the layers: one "
+    "observation a step.", labels=("server",))
+_M_MOE_ASSIGNMENTS = _metrics.counter(
+    "serving.moe_assignments_total",
+    "Token-to-expert assignments of the decode steps, all layers, active "
+    "slots only.", labels=("server",))
 _M_BROWNOUT = _metrics.gauge(
     "serving.brownout_level",
     "Current brownout degradation rung: 0=normal, 1=coarse streaming/wide "
@@ -1347,6 +1374,68 @@ class _ResultPublisher:
             srv._settle(uri)
 
 
+class _WindowPages:
+    """The window layers' page budget: a table row a slot and a free list
+    of its own, beside the page table that the full layers (and every other
+    model) use. A stream holds the pages its window can lie on and no more:
+    pages are handed out as its positions are written (:meth:`cover`) and
+    go back once they lie wholly behind the window of its next query
+    (:meth:`behind`). The budget is derived so that it cannot run out
+    (``LayeredDecoder.window_pages``); serve-loop thread only."""
+
+    def __init__(self, slots: int, width: int, num_pages: int,
+                 page_len: int, window: int):
+        self.rows = np.zeros((slots, width), np.int32)
+        self.free = list(range(num_pages - 1, 0, -1))  # page 0: null page
+        self.num_pages, self.page_len, self.window = (num_pages, page_len,
+                                                      window)
+
+    def in_use(self) -> int:
+        return self.num_pages - 1 - len(self.free)
+
+    def cover(self, slot: int, first: int, end: int) -> None:
+        """A page for every position in ``[first, end)`` that has none
+        (positions past the table's width land on the null page)."""
+        row = self.rows[slot]
+        for j in range(first // self.page_len,
+                       min(-(-end // self.page_len), len(row))):
+            if not row[j]:
+                if not self.free:
+                    raise RuntimeError(
+                        "the window layers' page budget ran out: it is "
+                        "derived so that it cannot (a bug)")
+                row[j] = self.free.pop()
+
+    def _give_back(self, slot: int, where) -> int:
+        row = self.rows[slot]
+        pages = row[where]
+        pages = pages[pages != 0]
+        self.free.extend(int(p) for p in pages)
+        row[where] = 0
+        return len(pages)
+
+    def behind(self, slot: int, query: int) -> int:
+        """Give back the pages whose last position the query at ``query``
+        no longer sees; returns how many."""
+        last = (query - self.window - self.page_len + 1) // self.page_len
+        if last < 0 or not self.rows[slot, :last + 1].any():
+            return 0
+        return self._give_back(slot, slice(0, last + 1))
+
+    def beyond(self, slot: int, position: int) -> None:
+        """Give back the pages past the one that holds ``position``: what a
+        last chunk's padding was written to."""
+        self._give_back(slot, slice(position // self.page_len + 1, None))
+
+    def release(self, slot: int) -> None:
+        self._give_back(slot, slice(None))
+
+    def table(self, active: np.ndarray) -> np.ndarray:
+        """The rows of the resident streams (a prompt still joining reads
+        its own row in its chunks; a step must not write through it)."""
+        return np.where(active[:, None], self.rows, 0)
+
+
 class GenerativeServing:
     """Token-level continuous batching for ``TransformerLM`` generation.
 
@@ -1430,37 +1519,59 @@ class GenerativeServing:
                              "greedy-only (per-request sampled accept is a "
                              "follow-up); unset temperature/top_k/top_p")
         self._spec_k = int(config.spec_k) if self._spec else 0
-        # a model with recurrent layers (capture/decoder.py): a state a slot
-        # beside the pages, prefill in chunks; what would need a snapshot of
-        # the state, or a pool the sparse read cannot gather from, is
-        # refused here rather than run wrongly
+        # a layered decoder (capture/decoder.py) is prefilled in chunks; it
+        # may keep a state a slot beside the pages (recurrent layers) or a
+        # second page budget (window layers). What would need a snapshot of
+        # the state, a page that a window layer has given back, or a pool
+        # its reads cannot dequantise is refused here, each for its reason,
+        # rather than run wrongly
+        self._chunked = bool(getattr(lm, "chunked", False))
         self._recurrent = bool(getattr(lm, "recurrent", False))
+        self._window_len = int(getattr(lm, "window_len", 0) or 0)
         if self._recurrent:
             why = "a model with recurrent (linear-attention) layers"
             if draft_lm is not None or config.spec_k:
                 raise ValueError(
                     f"speculative decoding is refused for {why}: rejected "
                     f"drafts would have to be rolled back out of the state")
-            if config.kv_int8:
-                raise ValueError(
-                    f"kv_int8 is refused for {why}: its sparse layers' "
-                    f"selected-page read has no dequantising gather")
             if int(getattr(config, "kv_shard", 1) or 1) > 1:
                 raise ValueError(
                     f"kv_shard is refused for {why}: the state a slot is "
                     f"not sharded with the pages")
+        if self._window_len:
+            why = "a model with window-attention layers"
+            if draft_lm is not None or config.spec_k:
+                raise ValueError(
+                    f"speculative decoding is refused for {why}: a window "
+                    f"layer gives back the pages behind the drafted "
+                    f"positions' window, and a rejected draft's roll-back "
+                    f"would read them")
+            if int(getattr(config, "kv_shard", 1) or 1) > 1:
+                raise ValueError(
+                    f"kv_shard is refused for {why}: the window layers' "
+                    f"page budget is not sharded with the full layers'")
+        if self._chunked:
+            why = "a model that is prefilled in chunks"
+            if config.kv_int8:
+                raise ValueError(
+                    f"kv_int8 is refused for {why}: its layers' paged "
+                    f"reads have no dequantising gather")
+            if draft_lm is not None or config.spec_k:
+                raise ValueError(
+                    f"speculative decoding is refused for {why}: its "
+                    f"decoder has no verify step over several positions")
             if self._sampling:
                 raise ValueError(f"sampling is not wired for {why} yet: "
                                  f"unset temperature/top_k/top_p")
         # -- device state: the page pools + ONE shared occupancy ----------
         self._params = lm.params
         pl = int(config.kv_page_len)
-        if self._recurrent:
-            # one page is one block of the model's sparse selection
+        if self._chunked:
+            # the model names its page (a sparse layer's selection block)
             if pl != lm.page_len:
                 raise ValueError(
-                    f"kv_page_len must be the model's selection "
-                    f"block, {lm.page_len}; got {pl}")
+                    f"kv_page_len must be the model's {lm.page_is}, "
+                    f"{lm.page_len}; got {pl}")
         elif pl < 1 or (pl & (pl - 1)) or pl > 16:
             raise ValueError(f"kv_page_len must be a power of two "
                              f"<= 16 (divides every prefill bucket), "
@@ -1502,9 +1613,10 @@ class GenerativeServing:
                 kk, row, axis=-1))(keys, filt)
 
         def _step_paged(params, tokens, keys, state, table, caches):
-            if self._recurrent:
+            if self._chunked:
                 # a recurrent layer's state moves for active slots only: a
-                # prompt between two chunks keeps what its last chunk left
+                # prompt between two chunks keeps what its last chunk left.
+                # ``table`` is (full, window) where there are window layers
                 logits, caches, read = lm.paged_state_step(
                     params, tokens, state["length"], table, caches,
                     state["active"])
@@ -1512,7 +1624,7 @@ class GenerativeServing:
                 logits, caches, read = lm.paged_slot_step(
                     params, tokens, state["length"], table, caches)
             nxt = _select(logits, keys)
-            if self._recurrent:  # the host fetches both
+            if self._chunked:  # the host fetches both
                 nxt = (nxt, read)
             else:
                 # the step's own count of the pages it read rides behind
@@ -1586,9 +1698,13 @@ class GenerativeServing:
                            start, n_valid, length, last):
             """One chunk of a joining prompt (chunked prefill): states and
             pages carried on from the chunk before; the ``last`` one
-            joins the slot and installs its table row."""
+            joins the slot and installs its table row. ``row`` is (full,
+            window) where there are window layers: the window rows are the
+            host's (:class:`_WindowPages`)."""
             caches = lm.prefill_chunk(params, padded, caches, row, slot,
                                       start, n_valid)
+            if isinstance(row, tuple):
+                row = row[0]
             joined = slot_join(state, slot, length)
             state = {k: jnp.where(last, joined[k], state[k]) for k in state}
             table = jnp.where(last, page_table_set(table, slot, row), table)
@@ -1625,7 +1741,7 @@ class GenerativeServing:
         self._copy_fn = jax.jit(_copy_pages, donate_argnames=pools)
         self._table_set_fn = jax.jit(page_table_set)
         self._table_clear_fn = jax.jit(page_table_clear)
-        if self._recurrent:  # one compile per chunk bucket
+        if self._chunked:  # one compile per chunk bucket
             self._prefill_chunk_fn = jax.jit(_prefill_chunk,
                                              donate_argnames=pools)
         self._join_fn = jax.jit(slot_join)    # T==1 prompts: no prefill
@@ -1666,6 +1782,24 @@ class GenerativeServing:
         self._m_sparse_read = _M_SPARSE_READ.labels(
             server=self.metrics_label)
         self._m_pages_read = _M_PAGES_READ.labels(server=self.metrics_label)
+        self._m_pages_in_use = {
+            kind: _M_PAGES_IN_USE.labels(server=self.metrics_label,
+                                         kind=kind)
+            for kind in ("full", "window")}
+        self._m_window_released = _M_WINDOW_RELEASED.labels(
+            server=self.metrics_label)
+        self._m_moe_touched = _M_MOE_TOUCHED.labels(
+            server=self.metrics_label)
+        self._m_moe_load = _M_MOE_LOAD.labels(server=self.metrics_label)
+        self._m_moe_assignments = _M_MOE_ASSIGNMENTS.labels(
+            server=self.metrics_label)
+        # what a layered decoder's step returns beside the tokens, by the
+        # names it gives (``LayeredDecoder.step_stats``)
+        self._step_observers = {
+            "sparse_positions_read": self._m_sparse_read.observe,
+            "moe_experts_touched": self._m_moe_touched.observe,
+            "moe_expert_load": self._m_moe_load.observe,
+            "moe_assignments": self._m_moe_assignments.inc}
         self._m_records = _M_RECORDS.labels(server=self.metrics_label)
         self._m_latency = _M_LATENCY.labels(server=self.metrics_label)
         self._m_depth = _M_QUEUE_DEPTH.labels(server=self.metrics_label)
@@ -1685,7 +1819,7 @@ class GenerativeServing:
         self._m_backlog = _M_PUBLISH_BACKLOG.labels(
             server=self.metrics_label)
         self._brownout = _Brownout(self.metrics_label)
-        self._m_pages_free.set(len(self._free_pages))
+        self._note_pages()
         self._counter_lock = threading.Lock()
         self._in_flight = 0
         self._meta: Dict[str, Tuple[float, Optional[int]]] = {}
@@ -1801,7 +1935,7 @@ class GenerativeServing:
         import jax.numpy as jnp
 
         from ..ops.decode import init_slot_state, shard_paged_pool
-        more = {"slots": self.slots} if self._recurrent else {}
+        more = {"slots": self.slots} if self._chunked else {}
         self._caches = self.lm.init_paged_caches(
             self.num_pages, self.page_len, int8=self.config.kv_int8, **more)
         if self._kv_shard > 1:
@@ -1816,6 +1950,11 @@ class GenerativeServing:
                                                     self._kv_shard)
         self._page_refs = np.zeros(self.num_pages, np.int64)
         self._slot_pages: List[List[int]] = [[] for _ in range(self.slots)]
+        # window layers draw on a budget of their own, derived from the
+        # slots so that it cannot run out; kv_pages is the full layers'
+        self._window = (_WindowPages(
+            self.slots, self._table_w, self.lm.window_pages(self.slots),
+            self.page_len, self._window_len) if self._window_len else None)
         # the occupancy (length, active) is the slot state of ops/decode.py
         self._state = init_slot_state(self.slots)
         if self._spec:
@@ -1836,7 +1975,7 @@ class GenerativeServing:
         for pfx in prefixes:
             self.register_prefix(pfx["tokens"])
         self._m_pool_rebuilds.inc()
-        self._m_pages_free.set(len(self._free_pages))
+        self._note_pages()
         logger.warning("kv caches rebuilt after a failed dispatch "
                        "(%d prefixes prefilled again)", len(prefixes))
 
@@ -1869,7 +2008,7 @@ class GenerativeServing:
     def _release_pages(self, slot: int) -> None:
         """Decrement every page the slot holds; refcount-0 pages return to
         the free stack (shared prefix pages outlive the stream via the
-        registry's own reference)."""
+        registry's own reference). The slot's window pages go back too."""
         pages, self._slot_pages[slot] = self._slot_pages[slot], []
         freed = 0
         for p in pages:
@@ -1879,7 +2018,17 @@ class GenerativeServing:
                 freed += 1
         if freed:
             self._m_page_evict.inc(freed)
-        self._m_pages_free.set(len(self._free_pages))
+        if self._window is not None:
+            self._window.release(slot)
+        self._note_pages()
+
+    def _note_pages(self) -> None:
+        """The allocators' gauges, after any change to a free list."""
+        free = len(self._free_pages)
+        self._m_pages_free.set(free)
+        self._m_pages_in_use["full"].set(self.num_pages - 1 - free)
+        if self._window is not None:
+            self._m_pages_in_use["window"].set(self._window.in_use())
 
     # -- device hot path (policed by scripts/check_hot_path_syncs.py) ------
 
@@ -1895,8 +2044,11 @@ class GenerativeServing:
                 self._table, self._caches, self._dcaches)
             out = (emitted, n_acc)
         else:
+            table = self._table
+            if self._window is not None:
+                table = (table, self._window.table(self._active_host))
             out, self._state, self._caches = self._step_fn(
-                self._params, tokens, keys, self._state, self._table,
+                self._params, tokens, keys, self._state, table,
                 self._caches)
         _profiler.record_phase("serving", "dispatch",
                                time.perf_counter() - t0, start=t0)
@@ -2014,6 +2166,17 @@ class GenerativeServing:
                 "(linear-attention) layers: a shared prefix would need a "
                 "snapshot of every layer's state at its end, which is not "
                 "kept")
+        if self._window_len:
+            raise RuntimeError(
+                "register_prefix is refused for a model with "
+                "window-attention layers: a window layer gives back the "
+                "pages that lie behind a stream's window, so a prefix's "
+                "pages cannot be held for the streams that share it")
+        if self._chunked:
+            raise RuntimeError(
+                "register_prefix is refused for a model that is prefilled "
+                "in chunks: its chunk program has no form that starts "
+                "from another stream's pages")
         from ..capture.lm import prefill_bucket
         toks = [int(x) for x in tokens]
         n = len(toks)
@@ -2036,7 +2199,7 @@ class GenerativeServing:
         self._caches = self._prefill_prefix_fn(self._params, padded,
                                                self._caches, row)
         self._prefixes.append({"tokens": toks, "len": n, "pages": pages})
-        self._m_pages_free.set(len(self._free_pages))
+        self._note_pages()
         return len(self._prefixes) - 1
 
     def _take_pages(self, uri: str, needed: int) -> Optional[List[int]]:
@@ -2091,7 +2254,7 @@ class GenerativeServing:
         for p in fresh:
             self._page_refs[p] = 1
         self._slot_pages[slot] = shared + fresh
-        self._m_pages_free.set(len(self._free_pages))
+        self._note_pages()
         try:
             if pfx and rem:
                 # CoW: the stream appends into logical page ``full``, which
@@ -2184,7 +2347,7 @@ class GenerativeServing:
         # the profiler's phase for what follows keeps the name it has in
         # ClusterServing, host_input; here it is the prefill's dispatch
         t0 = time.perf_counter()
-        if self._recurrent:
+        if self._chunked:
             # pages now, chunks over the coming iterations; the stream is
             # resident once its last chunk has run (_advance_prefill)
             began = self._begin_prefill(slot, uri, rec, prompt, prefix,
@@ -2233,7 +2396,7 @@ class GenerativeServing:
             self._keys[slot] = self._split(int(seed), budget)
         self._active_host[slot] = True
 
-    # -- chunked prefill (models with recurrent layers) ----------------------
+    # -- chunked prefill (layered decoders) ----------------------------------
 
     def _begin_prefill(self, slot: int, uri: str, rec: Dict[str, Any],
                        prompt, prefix, budget: int, exp: Optional[float],
@@ -2255,7 +2418,7 @@ class GenerativeServing:
         for p in pages:
             self._page_refs[p] = 1
         self._slot_pages[slot] = pages
-        self._m_pages_free.set(len(self._free_pages))
+        self._note_pages()
         self._reserved[slot] = True
         self._prefilling.append({
             "slot": slot, "uri": uri, "rec": rec, "prompt": prompt,
@@ -2288,11 +2451,16 @@ class GenerativeServing:
         padded = np.zeros((1, width), np.int32)
         padded[0, :n] = job["full"][start:start + n]
         last = index == count - 1
+        row = job["row"]
+        if self._window is not None:
+            # the chunk's own pages now; what it passes goes back below
+            self._window.cover(slot, start, start + width)
+            row = (row, self._window.rows[slot].copy())
         t0 = time.perf_counter()
         try:
             self._caches, self._state, self._table = self._prefill_chunk_fn(
                 self._params, padded, self._caches, self._state,
-                self._table, job["row"], np.int32(slot), np.int32(start),
+                self._table, row, np.int32(slot), np.int32(start),
                 np.int32(n), np.int32(job["fed"]), np.bool_(last))
         except Exception as e:
             # the chunk had been given the caches: as after a failed
@@ -2307,6 +2475,14 @@ class GenerativeServing:
         self._count("prefill_chunks")
         self._count("prompt_tokens", n)
         job["next"] += 1
+        if self._window is not None:
+            # the next query is the next chunk's first, or the first decode
+            # step's; every later program runs after this chunk has read
+            if last:
+                self._window.beyond(slot, job["fed"])
+            self._m_window_released.inc(self._window.behind(
+                slot, job["fed"] if last else start + width))
+            self._note_pages()
         if last:
             self._prefilling.pop(0)
             self._reserved[slot] = False
@@ -2314,6 +2490,20 @@ class GenerativeServing:
                            job["prefix"], job["budget"], job["exp"],
                            job["now"])
         return True
+
+    def _window_advance(self) -> None:
+        """Before a decode step: each resident stream gives back the window
+        pages that its query no longer sees and gets the page that the
+        step writes, if it starts one."""
+        released, held = 0, self._window.in_use()
+        for i in np.flatnonzero(self._active_host):
+            t = len(self._prompt[i]) - 1 + len(self._tokens[i])
+            released += self._window.behind(i, t)
+            self._window.cover(i, t, t + 1)
+        if released:
+            self._m_window_released.inc(released)
+        if released or self._window.in_use() != held:
+            self._note_pages()
 
     def _admit(self) -> None:
         free = [i for i in range(self.slots)
@@ -2530,6 +2720,8 @@ class GenerativeServing:
         self._m_slots.set(n_active)
         if self._recurrent:
             self._m_state_slots.set(n_active + len(self._prefilling))
+        if self._window is not None and n_active:
+            self._window_advance()
         if n_active == 0:  # a prompt still joining keeps the loop awake
             return int(chunked or bool(self._prefilling))
         tokens = np.ascontiguousarray(self._next_tokens)
@@ -2551,10 +2743,10 @@ class GenerativeServing:
                 n_host = self._fetch_tokens(n_acc)
             else:
                 out = self._dispatch_step(tokens, keys)
-                if self._recurrent:
+                if self._chunked:
                     out, read = out
                 nxt_host = self._fetch_tokens(out)
-                if not self._recurrent:
+                if not self._chunked:
                     nxt_host, read = nxt_host[:-1], nxt_host[-1]
         except Exception as e:
             logger.exception("decode step failed for %d streams", n_active)
@@ -2573,11 +2765,14 @@ class GenerativeServing:
         per = (time.perf_counter() - t_step) / n_active
         self._ewma_token_s = (per if self._ewma_token_s == 0.0
                               else 0.8 * self._ewma_token_s + 0.2 * per)
-        if self._recurrent:
+        if self._chunked:
             self._steps_since_chunk += 1
             if self._prefilling:
                 self._count("steps_between_chunks")
-            self._m_sparse_read.observe(float(read))
+            # the step's own counts, computed with the tokens just fetched
+            for name, value in zip(self.lm.step_stats,
+                                   np.atleast_1d(np.asarray(read))):
+                self._step_observers[name](float(value))
         else:
             self._m_pages_read.observe(float(read))
         with time_it("serve.post"):
@@ -2784,11 +2979,11 @@ class GenerativeServing:
             v = fam.percentile(p)
             return None if v is None else round(v * 1e3, 3)
 
-        def _mean_of(hist) -> Dict[str, Any]:
+        def _mean_of(hist, digits: int = 1) -> Dict[str, Any]:
             # a histogram of counts: its values lie above the shared
             # buckets, so the exact sum over the count, not a percentile
             n = hist.count()
-            return {"mean": round(hist.sum() / n, 1) if n else None,
+            return {"mean": round(hist.sum() / n, digits) if n else None,
                     "window": n}
 
         err = getattr(self, "_background_error", None)
@@ -2844,6 +3039,15 @@ class GenerativeServing:
                 int(np.sum(self._active_host)) + len(self._prefilling)
                 if self._recurrent else None),
             "sparse_positions_read": _mean_of(self._m_sparse_read),
+            "kv_pages_in_use": {
+                "full": self.num_pages - 1 - len(self._free_pages),
+                "window": (self._window.in_use()
+                           if self._window is not None else None)},
+            "window_pages_released_total": int(
+                self._m_window_released.value()),
+            "moe_experts_touched": _mean_of(self._m_moe_touched),
+            "moe_expert_load": _mean_of(self._m_moe_load, 3),
+            "moe_assignments_total": int(self._m_moe_assignments.value()),
             "paged_pages_read": _mean_of(self._m_pages_read),
             "last_claim_age_s": claim_age,
             "ttft_ms": {"p50": _pct(self._m_ttft, 0.50),

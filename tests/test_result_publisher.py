@@ -212,8 +212,11 @@ def test_a_backend_that_keeps_up_gets_one_write_a_token(ctx, file_src):
     spy = Spy(srv.queue)
     claim = srv.queue.claim_batch
 
-    def slow_claim(n):  # an iteration far longer than a write
-        time.sleep(0.01)
+    def slow_claim(n):
+        # the backend keeps up: every record handed over has landed before
+        # the iteration goes on (the publisher's own barrier, not a sleep
+        # that a loaded machine's writes outlast)
+        srv._publisher.close()
         return claim(n)
     srv.queue.claim_batch = slow_claim
     inq.enqueue_prompt("one", [3, 1, 4])

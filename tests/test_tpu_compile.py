@@ -476,6 +476,92 @@ def test_sala_programs_keep_pools_and_states_in_place(v5e, tmp_path,
     assert held < 14.5e9, held   # of the chip's 16 GB
 
 
+_MIXED = dict(slots=32, pages=7937, window_pages=2113, page_len=64, width=248)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "chunk_2048",
+                                     "chunk_512"])
+def test_smallthinker_programs_keep_pools_and_expert_tables_in_place(
+        v5e, tmp_path, monkeypatch, program):
+    """SmallThinker's decode step and prefill chunk as the server declares
+    them, compiled for the chip at the benchmark's sizes (8 layers at the
+    published widths, all 64 experts, 7,937 full-layer and 2,113
+    window-layer pages of 64, 32 slots): every pool leaf of both budgets is
+    aliased to an output, no copy of a whole pool or of an expert table is
+    left in the program, the grouped products are the compiler's own
+    kernel, and the program with its temporaries fits the chip beside the
+    weights. The decode step reads each stream's pages in place: one
+    Mosaic call a layer (2 ``attn_full``, 6 ``attn_window``) and none of
+    the XLA form's gathered tiles."""
+    import json
+    import pathlib
+    import re
+    from analytics_zoo_tpu.ops import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    monkeypatch.setattr(dispatch, "_seen", set())
+    from analytics_zoo_tpu.capture.decoder import DecoderSpec, LayeredDecoder
+    from analytics_zoo_tpu.serving import GenerativeServing, ServingConfig
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cfg = json.loads((root / "perfbench" / "configs"
+                      / "smallthinker_21b.json").read_text())
+    lm = LayeredDecoder(DecoderSpec.from_config(cfg, cfg["n_positions"]))
+    lm.set_params(jax.eval_shape(lm.init_params))
+    slots = _MIXED["slots"]
+    assert lm.window_pages(slots) == _MIXED["window_pages"]
+    srv = GenerativeServing(ServingConfig(
+        data_src=f"dir://{tmp_path}/q", slots=2, kv_pages=2,
+        kv_page_len=_MIXED["page_len"]), lm)
+
+    def described(tree):
+        return jax.tree_util.tree_map(lambda a: v5e(a.shape, a.dtype), tree)
+
+    params = described(srv._params)
+    state = described(jax.eval_shape(
+        lambda: {"length": jnp.zeros((slots,), I32),
+                 "active": jnp.zeros((slots,), bool)}))
+    pools = described(jax.eval_shape(lambda: lm.init_paged_caches(
+        _MIXED["pages"], _MIXED["page_len"], slots=slots)))
+    assert [p["k"].shape[0] for p in pools] == [7937, 2113, 2113, 2113] * 2
+    table = v5e((slots, _MIXED["width"]), I32)
+    row, scalar = v5e((_MIXED["width"],), I32), v5e((), I32)
+    if program == "decode_step":
+        lowered = srv._step_fn.lower(params, v5e((slots,), I32),
+                                     v5e((slots, 2), jnp.uint32), state,
+                                     (table, table), pools)
+    else:
+        width = int(program.split("_")[1])
+        lowered = srv._prefill_chunk_fn.lower(
+            params, v5e((1, width), I32), pools, state, table, (row, row),
+            scalar, scalar, scalar, scalar, v5e((), jnp.bool_))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    leaves = {int(n) for n in re.findall(
+        r"%caches_\S+ = \S+ parameter\((\d+)\)", entry)}
+    assert len(leaves) == len(jax.tree_util.tree_leaves(pools)) == 16
+    aliased = {int(n) for n in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
+    assert leaves <= aliased, sorted(leaves - aliased)
+    for dims in ("7937,64,512", "2113,64,512", "64,2560,768", "64,768,2560"):
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(r"= \w+\[%s\]\S* copy\(" % dims, line)]
+        assert not copies, copies
+    assert "ragged-dot" in text
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    assert held < 14.5e9, held   # of the chip's 16 GB
+    assert dispatch.fallbacks_seen() == []
+    if program == "decode_step":
+        calls = re.findall(r"%(attn_\w+?)[.\d]* = \S+ custom-call\(", text)
+        assert sorted(set(calls)) and len(
+            [c for c in calls if c.startswith("attn_full")]) == 2, calls
+        assert len([c for c in calls if c.startswith("attn_window")]) == 6
+        gathered = [line.strip()[:160] for line in text.splitlines()
+                    if re.search(r"bf16\[32,1024,512\]", line)]
+        assert not gathered, gathered[:4]
+
+
 # -- which branch ran: the reason strings, on the CPU -------------------------
 
 def test_fallback_reasons_are_logged_once(monkeypatch, caplog):
