@@ -157,6 +157,21 @@ _M_GEN_COUNTERS = {
         "serving.partials_superseded_total",
         "Partial results replaced by a newer record of the same stream "
         "before the publisher had written them.", labels=("server",)),
+    # the serve loop keeps one decode step in flight (docs/serving.md)
+    "decode_steps": _metrics.counter(
+        "serving.decode_steps_total",
+        "Decode steps dispatched (a speculative round counts as one).",
+        labels=("server",)),
+    "steps_ahead": _metrics.counter(
+        "serving.steps_ahead_total",
+        "Decode steps dispatched while the step before them was not yet "
+        "folded: the device ran them beside the host's iteration.",
+        labels=("server",)),
+    "overrun_slot_steps": _metrics.counter(
+        "serving.overrun_slot_steps_total",
+        "Slot-steps whose token was thrown away because the stream had "
+        "ended on eos_id in the step before, which was still in flight "
+        "when they were dispatched.", labels=("server",)),
 }
 #: the result publisher (_ResultPublisher): how far it is behind the loop
 _M_PUBLISH_BACKLOG = _metrics.gauge(
@@ -1436,6 +1451,40 @@ class _WindowPages:
         return np.where(active[:, None], self.rows, 0)
 
 
+class _Stream:
+    """What the serve loop keeps of one request while it decodes. A stream
+    is resident in ``slot`` until its last step has been dispatched or it
+    ends another way; a step in flight holds the streams it stepped, so a
+    token finds its stream whoever has the slot by then. Serve-loop thread
+    only."""
+
+    __slots__ = ("uri", "slot", "tokens", "budget", "dispatched", "expires",
+                 "enqueue_t", "first_t", "claim_pc", "streamed", "keys",
+                 "prompt", "seed", "deadline_ms", "ended")
+
+    def __init__(self, uri: str, slot: int, prompt: List[int],
+                 prefix: List[int], budget: int):
+        self.uri, self.slot, self.prompt = uri, slot, prompt
+        self.tokens = list(prefix)
+        self.budget = budget
+        # positions handed to the device: what the NEXT step does (its
+        # sampling key, its window, whether there is one) goes by this
+        # count, not by the tokens folded, which lag it by the step in
+        # flight
+        self.dispatched = len(prefix)
+        self.streamed = len(prefix)
+        self.expires: Optional[float] = None
+        self.enqueue_t = 0.0
+        self.first_t: Optional[float] = None
+        self.claim_pc = 0.0
+        self.keys: Optional[np.ndarray] = None
+        self.seed: Optional[int] = None
+        self.deadline_ms: Optional[float] = None
+        # why it takes no more tokens: "budget" or "eos" (its value is
+        # posted), "gone" (an error, or another instance's now)
+        self.ended: Optional[str] = None
+
+
 class GenerativeServing:
     """Token-level continuous batching for ``TransformerLM`` generation.
 
@@ -1472,6 +1521,17 @@ class GenerativeServing:
     with delayed scaling; ``config.spec_k`` + a ``draft_lm`` makes the
     step a speculative draft/verify round (greedy-only, token-identical
     to serial greedy).
+
+    One step ahead: inside :meth:`run` the loop keeps ONE decode step in
+    flight. Step N+1 is dispatched from the device's own tokens of step N
+    (the host names a token only for a slot joined since) before step N's
+    are fetched and folded, so the device's step and the host's iteration
+    overlap. The host counts the positions it has dispatched
+    (``_Stream.dispatched``): a stream whose budget a step reaches leaves
+    its slot at that dispatch; one that ends on ``eos_id`` is seen a step
+    late and its one overrun token is thrown away. ``serve_step()`` by
+    hand and speculative rounds fold at once (docs/serving.md "One step
+    ahead"; tests/test_step_ahead.py).
 
     Decode parity: served streams are BIT-IDENTICAL to serial
     ``TransformerLM.generate()`` runs on the CPU — both share the bucketed
@@ -1612,7 +1672,14 @@ class GenerativeServing:
             return jax.vmap(lambda kk, row: jax.random.categorical(
                 kk, row, axis=-1))(keys, filt)
 
-        def _step_paged(params, tokens, keys, state, table, caches):
+        def _step_paged(params, tokens, prev, keys, state, table, caches):
+            # a stream's input is the device's own last output, ``prev``,
+            # as the step before left it: no token crosses to the host and
+            # back on the way to the next step. The host names a token
+            # (>= 0) only for a slot joined since that step was dispatched
+            with jax.named_scope("merge"):
+                tokens = jnp.where(tokens >= 0, tokens,
+                                   prev[:tokens.shape[0]])
             if self._chunked:
                 # a recurrent layer's state moves for active slots only: a
                 # prompt between two chunks keeps what its last chunk left.
@@ -1745,33 +1812,37 @@ class GenerativeServing:
             self._prefill_chunk_fn = jax.jit(_prefill_chunk,
                                              donate_argnames=pools)
         self._join_fn = jax.jit(slot_join)    # T==1 prompts: no prefill
+        # the first step's ``prev``: no token yet, but placed as a program
+        # places its results (made from the lengths, which a join has
+        # returned by then), so that the second step meets the program
+        # the first one compiled
+        n_prev = self.slots + (0 if self._chunked else 1)
+        self._no_tokens_fn = jax.jit(
+            lambda length: jnp.zeros((n_prev,), jnp.int32) * length[0])
         self._evict_fn = jax.jit(slot_evict)
         self._split = lambda seed, n: np.asarray(
             jax.random.split(jax.random.PRNGKey(seed), n))
         # -- host-side per-slot bookkeeping (scheduler-thread private) ----
         s = self.slots
-        self._uri: List[Optional[str]] = [None] * s
-        self._tokens: List[Optional[List[int]]] = [None] * s
-        self._budget = [0] * s
-        self._expires: List[Optional[float]] = [None] * s
-        self._enqueue_t = [0.0] * s
-        self._first_t: List[Optional[float]] = [None] * s
-        self._claim_pc = [0.0] * s  # perf_counter at the stream's claim
-        self._streamed = [0] * s
-        self._keys: List[Optional[np.ndarray]] = [None] * s
-        self._next_tokens = np.zeros(s, np.int32)
+        self._streams: List[Optional[_Stream]] = [None] * s
+        self._claim_pc = [0.0] * s  # perf_counter at the slot's claim
+        # a slot's next input where the host has it: the last token of a
+        # prompt joined since the last dispatch (a speculative stream's
+        # last accepted token, always); -1 where the device's own last
+        # output is the input
+        self._next_tokens = np.full(s, 0 if self._spec else -1, np.int32)
         self._active_host = np.zeros(s, bool)
+        # the decode step dispatched and not yet folded, with the streams
+        # it stepped, and the streams that have left their slot (their
+        # last step dispatched) with a token still to come
+        self._in_flight_step: Optional[Dict[str, Any]] = None
+        self._leaving: List[_Stream] = []
+        self._last_landed = 0.0  # perf_counter when a step's tokens came
         # chunked prefill: prompts claimed and not yet resident, oldest
         # first, each holding its slot
         self._prefilling: List[Dict[str, Any]] = []
         self._steps_since_chunk = DECODE_STEPS_PER_CHUNK
         self._reserved = np.zeros(s, bool)
-        # continuation-on-failover bookkeeping: the original prompt, seed
-        # and deadline ride along so a drain handoff can re-enqueue the
-        # stream with its accumulated prefix (docs/fleet.md)
-        self._prompt: List[Optional[List[int]]] = [None] * s
-        self._seed: List[Optional[int]] = [None] * s
-        self._deadline_ms: List[Optional[float]] = [None] * s
         # -- SLO bookkeeping (same registry families as ClusterServing) ---
         self.metrics_label = f"srv{next(_instance_ids)}"
         self._m = {key: fam.labels(server=self.metrics_label)
@@ -1889,44 +1960,46 @@ class GenerativeServing:
             self._m_latency.observe(max(wall_clock() - t0, 0.0))
             _trace.flow_point(flow_id, "serving.result", "f")
 
-    def _retire(self, slot: int, value: Dict[str, Any],
-                counter: Optional[str] = None,
-                folded: Optional[float] = None,
-                first: Optional[float] = None) -> None:
-        """Terminal-result a slot's stream and free its host bookkeeping
-        (the DEVICE evict is the caller's one vectorized ``_evict_slots``)."""
-        self._post_terminal(self._uri[slot], value, folded, first)
+    def _end(self, stream: _Stream, value: Dict[str, Any], why: str,
+             counter: Optional[str] = None, folded: Optional[float] = None,
+             first: Optional[float] = None) -> Optional[int]:
+        """A stream's ONE terminal, and its slot's host bookkeeping freed
+        where it still has one; returns that slot (the DEVICE evict is the
+        caller's one vectorized ``_evict_slots``). ``why`` is what
+        ``_Stream.ended`` says from here on."""
+        self._post_terminal(stream.uri, value, folded, first)
         if counter is not None:
             self._count(counter)
         elif "value" in value:
             self._m_records.inc()
-        self._release_pages(slot)
-        self._clear_slot(slot)
+        stream.ended = why
+        slot = stream.slot
+        if slot is None:
+            self._leaving.remove(stream)
+        else:
+            self._vacate(slot)
+        return slot
 
-    def _clear_slot(self, slot: int) -> None:
-        self._uri[slot] = None
-        self._tokens[slot] = None
-        self._keys[slot] = None
-        self._expires[slot] = None
-        self._first_t[slot] = None
-        self._streamed[slot] = 0
-        self._prompt[slot] = None
-        self._seed[slot] = None
-        self._deadline_ms[slot] = None
+    def _vacate(self, slot: int) -> None:
+        """The slot's stream lets go of it and of its pages."""
+        self._streams[slot].slot = None
+        self._streams[slot] = None
         self._active_host[slot] = False
+        self._release_pages(slot)
 
     def _abandon(self, slot: int) -> None:
         """Release a slot WITHOUT posting a terminal — the stream's one
         terminal will be posted by whichever instance adopts its re-routed
         continuation. Only :meth:`handoff` may do this: every other exit
-        path funnels through :meth:`_retire`."""
+        path funnels through :meth:`_end`."""
+        stream = self._streams[slot]
         with self._counter_lock:
             self._in_flight = max(0, self._in_flight - 1)
             in_flight = self._in_flight
-            self._meta.pop(self._uri[slot], None)
+            self._meta.pop(stream.uri, None)
         self._m_in_flight.set(in_flight)
-        self._release_pages(slot)
-        self._clear_slot(slot)
+        stream.ended = "gone"
+        self._vacate(slot)
 
     def _fresh_device_state(self) -> None:
         """KV caches, slot occupancy, page table and page allocator as a
@@ -1957,6 +2030,9 @@ class GenerativeServing:
             self.page_len, self._window_len) if self._window_len else None)
         # the occupancy (length, active) is the slot state of ops/decode.py
         self._state = init_slot_state(self.slots)
+        # what the last decode step returned for the host to fetch: its
+        # tokens are the next step's inputs, read where they lie
+        self._prev_tokens = None
         if self._spec:
             # the draft model still decodes off slot rectangles
             self._dcaches = self.draft_lm.init_slot_caches(self.slots)
@@ -2035,7 +2111,8 @@ class GenerativeServing:
     def _dispatch_step(self, tokens, keys):
         """Dispatch one fused step and rebind the device state to its
         results before anything else runs: the caches passed in were given
-        to the program. Returns what the host fetches."""
+        to the program. Returns what the host fetches, its copy to the
+        host already under way."""
         t0 = time.perf_counter()
         if self._spec:
             (emitted, n_acc, self._state, self._caches,
@@ -2047,9 +2124,18 @@ class GenerativeServing:
             table = self._table
             if self._window is not None:
                 table = (table, self._window.table(self._active_host))
+            prev = self._prev_tokens
+            if prev is None:  # the first step of this device state
+                prev = self._no_tokens_fn(self._state["length"])
             out, self._state, self._caches = self._step_fn(
-                self._params, tokens, keys, self._state, table,
+                self._params, tokens, prev, keys, self._state, table,
                 self._caches)
+            if self._chunked:  # (tokens, the step's counts)
+                self._prev_tokens = out[0]
+                out[1].copy_to_host_async()
+            else:
+                self._prev_tokens = out
+            self._prev_tokens.copy_to_host_async()
         _profiler.record_phase("serving", "dispatch",
                                time.perf_counter() - t0, start=t0)
         return out
@@ -2369,20 +2455,14 @@ class GenerativeServing:
         """The host's bookkeeping of a stream that is now resident in
         ``slot``: its prompt is in the caches and the next step decodes
         its first token."""
-        full = prompt + prefix
-        self._uri[slot] = uri
-        self._tokens[slot] = list(prefix)
-        self._budget[slot] = budget
-        self._expires[slot] = exp
-        self._enqueue_t[slot] = float(rec.get("enqueue_t") or now)
+        stream = _Stream(uri, slot, prompt, prefix, budget)
+        stream.expires = exp
+        stream.enqueue_t = float(rec.get("enqueue_t") or now)
         # TTFT was already observed on the original server for an adopted
         # stream — don't observe it twice
-        self._first_t[slot] = now if prefix else None
-        self._streamed[slot] = len(prefix)
-        self._next_tokens[slot] = int(full[-1])
-        self._prompt[slot] = prompt
-        self._deadline_ms[slot] = rec.get("deadline_ms")
-        self._seed[slot] = None
+        stream.first_t = now if prefix else None
+        stream.claim_pc = self._claim_pc[slot]
+        stream.deadline_ms = rec.get("deadline_ms")
         if self._sampling:
             seed = rec.get("seed")
             if seed is None:  # fresh entropy: repeated requests differ
@@ -2390,10 +2470,13 @@ class GenerativeServing:
             # the FULL per-request key schedule, precomputed once: step i
             # uses key [i] — identical to serial sample_generate's
             # split(PRNGKey(seed), budget) schedule. The step index is
-            # len(self._tokens[slot]), so an adopted prefix resumes the
-            # schedule exactly where the dead server left off.
-            self._seed[slot] = int(seed)
-            self._keys[slot] = self._split(int(seed), budget)
+            # the stream's ``dispatched``, which starts at an adopted
+            # prefix's length, so the schedule resumes exactly where the
+            # dead server left off.
+            stream.seed = int(seed)
+            stream.keys = self._split(int(seed), budget)
+        self._streams[slot] = stream
+        self._next_tokens[slot] = int((prefix or prompt)[-1])
         self._active_host[slot] = True
 
     # -- chunked prefill (layered decoders) ----------------------------------
@@ -2497,7 +2580,8 @@ class GenerativeServing:
         step writes, if it starts one."""
         released, held = 0, self._window.in_use()
         for i in np.flatnonzero(self._active_host):
-            t = len(self._prompt[i]) - 1 + len(self._tokens[i])
+            stream = self._streams[i]
+            t = len(stream.prompt) - 1 + stream.dispatched
             released += self._window.behind(i, t)
             self._window.cover(i, t, t + 1)
         if released:
@@ -2568,67 +2652,100 @@ class GenerativeServing:
     def _expire_slots(self) -> None:
         """Per-token deadline check: an expired stream is evicted
         MID-FLIGHT — its one terminal result is the deadline error (the
-        partials it already streamed are not terminals)."""
+        partials it already streamed are not terminals; a token of it
+        still in flight is dropped at its fold)."""
         now = wall_clock()
         mask = np.zeros(self.slots, bool)
-        for i in range(self.slots):
-            if (self._active_host[i] and self._expires[i] is not None
-                    and now >= self._expires[i]):
+        for i, stream in enumerate(self._streams):
+            if (stream is not None and stream.expires is not None
+                    and now >= stream.expires):
                 mask[i] = True
-                self._retire(i, {"error": DEADLINE_ERROR}, counter="expired")
+                self._end(stream, {"error": DEADLINE_ERROR}, "gone",
+                          counter="expired")
         if mask.any():
             self._evict_slots(mask)
 
     def _fail_active(self, message: str, rebuild: bool = False) -> None:
-        """Error every active stream (its one terminal). ``rebuild``: the
+        """Error every resident stream (its one terminal). ``rebuild``: the
         failure came at or after a dispatch that was given the caches, so
-        the device state starts over instead of being evicted from."""
+        the device state starts over instead of being evicted from, and
+        the step in flight goes with it: a stream that had left its slot
+        with a token still to come gets the error too."""
         mask = np.zeros(self.slots, bool)
-        for i in range(self.slots):
-            if self._active_host[i]:
+        for i, stream in enumerate(self._streams):
+            if stream is not None:
                 mask[i] = True
-                self._retire(i, {"error": message}, counter="errors")
+                self._end(stream, {"error": message}, "gone",
+                          counter="errors")
         jobs, self._prefilling = self._prefilling, []
         for job in jobs:  # joining prompts lose what their chunks wrote
             self._drop_prefill(job, {"error": message}, "errors")
         if rebuild:
+            for stream in list(self._leaving):
+                self._end(stream, {"error": message}, "gone",
+                          counter="errors")
+            self._in_flight_step = None
             self._rebuild_pools()
         elif mask.any():
             self._evict_slots(mask)
 
-    def _post_tokens(self, nxt: np.ndarray) -> None:
-        """Fold one step's tokens, one an active stream."""
-        self._fold((i, (int(nxt[i]),)) for i in range(self.slots)
-                   if self._active_host[i])
+    def _count_dispatched(self, stepped) -> None:
+        """After a dispatch: one more position a stream it stepped. A
+        stream whose budget that reaches is not stepped again: it leaves
+        its slot now, inactive on the device before the next dispatch and
+        free for the next request, and its last token finds it through the
+        step in flight."""
+        mask = np.zeros(self.slots, bool)
+        for slot, stream in stepped:
+            stream.dispatched += 1
+            if stream.dispatched >= stream.budget:
+                mask[slot] = True
+                self._vacate(slot)
+                self._leaving.append(stream)
+        if mask.any():
+            self._evict_slots(mask)
+
+    def _post_tokens(self, nxt: np.ndarray, stepped) -> None:
+        """Fold one step's tokens, one a stream it stepped. A stream that
+        has ended since the dispatch (expired, failed, on ``eos_id``) does
+        not get its token; nothing after an eos reaches a record."""
+        overrun = sum(stream.ended == "eos" for _, stream in stepped)
+        self._fold((stream, (int(nxt[slot]),)) for slot, stream in stepped
+                   if stream.ended is None)
+        if overrun:
+            self._count("overrun_slot_steps", overrun)
 
     def _post_tokens_spec(self, emitted: np.ndarray,
                           n_acc: np.ndarray) -> None:
         """Fold one speculative round's ACCEPTED tokens — the rules of
         :meth:`_fold`, but up to ``spec_k + 1`` tokens land per stream per
         round. The budget clamp and eos truncation are host-side; a stream
-        they cut short is retired in the same pass, so the device's
-        over-advanced length never feeds another step."""
+        they cut short ends in the same pass, so the device's over-advanced
+        length never feeds another step. That is why a round is folded
+        before the next is dispatched: its lengths wait on the host."""
         eos = self.config.eos_id
 
         def accepted():
-            for i in range(self.slots):
-                if not self._active_host[i]:
+            for i, stream in enumerate(self._streams):
+                if stream is None:
                     continue
                 take = min(int(n_acc[i]),
-                           self._budget[i] - len(self._tokens[i]))
+                           stream.budget - len(stream.tokens))
                 toks = [int(x) for x in emitted[i, :take]]
                 if eos is not None and eos in toks:
                     toks = toks[:toks.index(eos) + 1]
                 if toks:
-                    yield i, toks
+                    self._next_tokens[i] = toks[-1]
+                    yield stream, toks
         self._fold(accepted())
 
     def _fold(self, taken) -> None:
-        """Fold a step's new tokens, ``(slot, tokens)`` a stream, into the
+        """Fold a step's new tokens, ``(stream, tokens)`` each, into the
         streams: TTFT on the first token, a partial result due every
-        ``stream_interval`` tokens, terminal value + evict on eos / budget
-        exhaustion. The records go to the publisher; no write is waited
-        for here. A stream's claim time rides with the first record that
+        ``stream_interval`` tokens, terminal value on eos / budget
+        exhaustion (with the slot's eviction where the stream still holds
+        one). The records go to the publisher; no write is waited for
+        here. A stream's claim time rides with the first record that
         carries a token of it, so that ``serve.first_token`` ends when a
         client could see one."""
         now, folded = wall_clock(), time.perf_counter()
@@ -2638,28 +2755,30 @@ class GenerativeServing:
         stride = self._brownout.stream_stride(cfg.stream_interval)
         finished = np.zeros(self.slots, bool)
         due, n_tok = [], 0
-        for i, toks in taken:
-            self._tokens[i].extend(toks)
-            self._next_tokens[i] = toks[-1]
+        for stream, toks in taken:
+            stream.tokens.extend(toks)
             n_tok += len(toks)
             claimed = None
-            if self._first_t[i] is None:
-                self._first_token_seen(i, now)
+            if stream.first_t is None:
+                self._first_token_seen(stream, now)
                 if _utils.span_hooks:
-                    claimed = self._claim_pc[i]
-            have = len(self._tokens[i])
-            if (have >= self._budget[i]
-                    or (cfg.eos_id is not None and toks[-1] == cfg.eos_id)):
-                finished[i] = True
-                # the list is the record's from here: the slot lets go of it
-                self._retire(i, {"value": self._tokens[i], "done": True},
-                             folded=folded, first=claimed)
-            elif stride > 0 and have - self._streamed[i] >= stride:
+                    claimed = stream.claim_pc
+            have = len(stream.tokens)
+            eos = cfg.eos_id is not None and toks[-1] == cfg.eos_id
+            if eos or have >= stream.budget:
+                # the list is the record's from here
+                slot = self._end(stream,
+                                 {"value": stream.tokens, "done": True},
+                                 "eos" if eos else "budget", folded=folded,
+                                 first=claimed)
+                if slot is not None:
+                    finished[slot] = True
+            elif stride > 0 and have - stream.streamed >= stride:
                 # the list and the length that counts: the copy and the
                 # serialisation are the publisher's
-                due.append((self._uri[i], self._tokens[i], have,
-                            self._seed[i], claimed))
-                self._streamed[i] = have
+                due.append((stream.uri, stream.tokens, have, stream.seed,
+                            claimed))
+                stream.streamed = have
             elif claimed is not None:  # no record carries this token
                 _utils.offer_span("serve.first_token", claimed,
                                   time.perf_counter() - claimed)
@@ -2670,25 +2789,31 @@ class GenerativeServing:
         if finished.any():
             self._evict_slots(finished)
 
-    def _first_token_seen(self, slot: int, now: float) -> None:
+    def _first_token_seen(self, stream: _Stream, now: float) -> None:
         """A stream's first decoded token: TTFT, and the flow chain's
         ``serving.first_token`` point."""
-        self._first_t[slot] = now
-        self._m_ttft.observe(max(now - self._enqueue_t[slot], 0.0))
+        stream.first_t = now
+        self._m_ttft.observe(max(now - stream.enqueue_t, 0.0))
         if _trace.tracing():
-            meta = self._meta.get(self._uri[slot])
+            meta = self._meta.get(stream.uri)
             if meta is not None:
                 _trace.flow_point(meta[1], "serving.first_token", "t")
 
     def serve_step(self) -> int:
         """One scheduler step: evict expired streams, admit new requests
-        into free slots (shed + bucketed prefill), run ONE fused decode
-        step over every occupied slot, stream/terminate per token. Returns
-        the number of streams stepped — the single-step form tests and
-        the bench drive directly; :meth:`run` loops it. Stepped by hand,
-        with no loop running, it returns when the step's records have
-        landed, so the caller reads the result store as the step left
-        it."""
+        into free slots (shed + bucketed prefill), dispatch ONE fused
+        decode step over every occupied slot, stream/terminate per token.
+        Returns the number of streams stepped (or, where nothing was left
+        to step, of those whose last tokens it folded) — the single-step
+        form tests and the bench drive directly; :meth:`run` loops it.
+
+        Inside :meth:`run` the loop keeps one step in flight: the step is
+        dispatched from the device's own last tokens, and only then are
+        the tokens of the step before it fetched and folded, so the device
+        computes beside the host's iteration. Stepped by hand, with no loop
+        running, the step's own tokens are folded and its records have
+        landed when this returns, so the caller reads the streams and the
+        result store as the step left them."""
         try:
             if not _utils.span_hooks:
                 return self._serve_step()
@@ -2720,64 +2845,117 @@ class GenerativeServing:
         self._m_slots.set(n_active)
         if self._recurrent:
             self._m_state_slots.set(n_active + len(self._prefilling))
-        if self._window is not None and n_active:
+        if n_active == 0:
+            # nothing to dispatch: what is in flight is folded before the
+            # loop may sleep; a prompt still joining keeps it awake
+            return (self._fold_in_flight()
+                    or int(chunked or bool(self._prefilling)))
+        if self._window is not None:
             self._window_advance()
-        if n_active == 0:  # a prompt still joining keeps the loop awake
-            return int(chunked or bool(self._prefilling))
-        tokens = np.ascontiguousarray(self._next_tokens)
+        stepped = [(i, stream) for i, stream in enumerate(self._streams)
+                   if stream is not None]
+        # fresh arrays a step: the dispatch may read them after it returns
+        tokens = self._next_tokens.copy()
         keys = np.zeros((self.slots, 2), np.uint32)
         if self._sampling:
-            for i in range(self.slots):
-                if self._active_host[i]:
-                    keys[i] = self._keys[i][len(self._tokens[i])]
-        t_step = time.perf_counter()
+            for i, stream in stepped:
+                keys[i] = stream.keys[stream.dispatched]
+        step = {"streams": stepped, "t_step": time.perf_counter(),
+                "ahead_from": None}
         given = False
         try:
             # chaos site, raised BEFORE the dispatch: the step errors every
             # active stream (their one terminal) and the caches stay whole
             faults.inject("serving.decode_step")
             given = True
-            if self._spec:
-                emitted, n_acc = self._dispatch_step(tokens, keys)
-                em_host = self._fetch_tokens(emitted)
-                n_host = self._fetch_tokens(n_acc)
-            else:
-                out = self._dispatch_step(tokens, keys)
-                if self._chunked:
-                    out, read = out
-                nxt_host = self._fetch_tokens(out)
-                if not self._chunked:
-                    nxt_host, read = nxt_host[:-1], nxt_host[-1]
+            step["out"] = self._dispatch_step(tokens, keys)
         except Exception as e:
             logger.exception("decode step failed for %d streams", n_active)
             self._fail_active(repr(e), rebuild=given)
             return 0
-        if self._spec:
-            n_emitted = int(np.sum(n_host[self._active_host]))
-            per = (time.perf_counter() - t_step) / max(n_emitted, 1)
-            self._ewma_token_s = (per if self._ewma_token_s == 0.0
-                                  else 0.8 * self._ewma_token_s + 0.2 * per)
-            self._m_spec_accept.set(float(np.mean(np.maximum(
-                n_host[self._active_host] - 1, 0))) / self._spec_k)
-            with time_it("serve.post"):
-                self._post_tokens_spec(em_host, n_host)
-            return n_active
-        per = (time.perf_counter() - t_step) / n_active
-        self._ewma_token_s = (per if self._ewma_token_s == 0.0
-                              else 0.8 * self._ewma_token_s + 0.2 * per)
+        self._count("decode_steps")
         if self._chunked:
             self._steps_since_chunk += 1
             if self._prefilling:
                 self._count("steps_between_chunks")
+        if self._spec:
+            # the round's accepted counts are clamped by the host: its
+            # lengths wait on the fold, so it is folded at once
+            return n_active if self._land_step(step) else 0
+        self._next_tokens[:] = -1
+        self._count_dispatched(stepped)
+        before, self._in_flight_step = self._in_flight_step, step
+        if before is not None:
+            # this step went to the device ahead of the fold of the one
+            # before it; the host work from here to its own fetch runs
+            # beside it
+            self._count("steps_ahead")
+            step["ahead_from"] = time.perf_counter()
+            if not self._land_step(before):
+                return 0
+        if not self._loop_running and not self._fold_in_flight():
+            return 0  # by hand: the step's own tokens, before it returns
+        return n_active
+
+    def _fold_in_flight(self) -> int:
+        """Fetch and fold the step in flight, if there is one: before the
+        loop sleeps, drains, stops or hands its streams off. Returns how
+        many streams' tokens were folded."""
+        step, self._in_flight_step = self._in_flight_step, None
+        if step is None or not self._land_step(step):
+            return 0
+        return len(step["streams"])
+
+    def _land_step(self, step: Dict[str, Any]) -> bool:
+        """The one blocking call an iteration: fetch a dispatched step's
+        tokens, then fold them. A fetch that fails has lost the caches the
+        step was given: every stream errors and the pools start over."""
+        if step["ahead_from"] is not None and _utils.span_hooks:
+            _utils.offer_span("serve.step_ahead", step["ahead_from"],
+                              time.perf_counter() - step["ahead_from"])
+        n_active = len(step["streams"])
+        try:
+            if self._spec:
+                emitted, n_acc = step["out"]
+                em_host = self._fetch_tokens(emitted)
+                n_host = self._fetch_tokens(n_acc)
+            elif self._chunked:
+                out, read = step["out"]
+                nxt_host = self._fetch_tokens(out)
+            else:
+                nxt_host = self._fetch_tokens(step["out"])
+                nxt_host, read = nxt_host[:-1], nxt_host[-1]
+        except Exception as e:
+            logger.exception("decode step failed for %d streams", n_active)
+            self._fail_active(repr(e), rebuild=True)
+            return False
+        # the cadence of the steps: from this one's dispatch, or from the
+        # landing of the one before it where that came later
+        landed = time.perf_counter()
+        took = landed - max(step["t_step"], self._last_landed)
+        self._last_landed = landed
+        if self._spec:
+            n_emitted = int(np.sum(n_host[self._active_host]))
+            per = took / max(n_emitted, 1)
+            self._m_spec_accept.set(float(np.mean(np.maximum(
+                n_host[self._active_host] - 1, 0))) / self._spec_k)
+        else:
+            per = took / n_active
+        self._ewma_token_s = (per if self._ewma_token_s == 0.0
+                              else 0.8 * self._ewma_token_s + 0.2 * per)
+        if self._chunked:
             # the step's own counts, computed with the tokens just fetched
             for name, value in zip(self.lm.step_stats,
                                    np.atleast_1d(np.asarray(read))):
                 self._step_observers[name](float(value))
-        else:
+        elif not self._spec:
             self._m_pages_read.observe(float(read))
         with time_it("serve.post"):
-            self._post_tokens(nxt_host)
-        return n_active
+            if self._spec:
+                self._post_tokens_spec(em_host, n_host)
+            else:
+                self._post_tokens(nxt_host, step["streams"])
+        return True
 
     # -- lifecycle (mirrors ClusterServing) ----------------------------------
 
@@ -2804,6 +2982,10 @@ class GenerativeServing:
             # drain()'s terminal health, stop()'s return and handoff()'s
             # re-enqueue all come after every write of this instance
             try:
+                # the step in flight first: a stopped stream's error, a
+                # drained stream's value and a handed-off prefix all come
+                # after the last token the device was asked for
+                self._fold_in_flight()
                 if self._stop.is_set():
                     self._fail_active(SHUTDOWN_ERROR)
                 if self._handoff_evt.is_set():
@@ -2887,34 +3069,37 @@ class GenerativeServing:
                         f"handoff: serve loop did not pause within "
                         f"{timeout_s}s")
                 time.sleep(0.002)
-        # no partial of a stream that is about to be another instance's may
-        # land after its re-enqueue: forget them, wait for the write in
-        # flight (the loop did both as it paused; with no loop, here)
+        # the prefix is whole (the step in flight folded) and no partial of
+        # a stream that is about to be another instance's may land after
+        # its re-enqueue: forget them, wait for the write in flight (the
+        # loop did all three as it paused; with no loop, here)
+        self._fold_in_flight()
         self._publisher.drop_partials()
         self._publisher.close()
         moved = 0
         mask = np.zeros(self.slots, bool)
-        for i in range(self.slots):
-            if not self._active_host[i]:
+        for i, stream in enumerate(self._streams):
+            if stream is None:
                 continue
-            uri = self._uri[i]
+            # the original prompt, seed and deadline ride along with the
+            # accumulated prefix (docs/fleet.md)
             rec: Dict[str, Any] = {
-                "prompt": list(self._prompt[i]),
-                "prefix": list(self._tokens[i]),
-                "max_new_tokens": self._budget[i],
-                "enqueue_t": self._enqueue_t[i],
+                "prompt": list(stream.prompt),
+                "prefix": list(stream.tokens),
+                "max_new_tokens": stream.budget,
+                "enqueue_t": stream.enqueue_t,
             }
-            if self._deadline_ms[i] is not None:
-                rec["deadline_ms"] = self._deadline_ms[i]
-            if self._seed[i] is not None:
-                rec["seed"] = self._seed[i]
+            if stream.deadline_ms is not None:
+                rec["deadline_ms"] = stream.deadline_ms
+            if stream.seed is not None:
+                rec["seed"] = stream.seed
             mask[i] = True
             try:
-                to_queue.enqueue(uri, rec)
+                to_queue.enqueue(stream.uri, rec)
             except Exception:
-                logger.exception("handoff enqueue for %s failed", uri)
-                self._retire(i, {"error": SHUTDOWN_ERROR},
-                             counter="errors")
+                logger.exception("handoff enqueue for %s failed", stream.uri)
+                self._end(stream, {"error": SHUTDOWN_ERROR}, "gone",
+                          counter="errors")
                 continue
             self._abandon(i)
             moved += 1
@@ -2956,6 +3141,7 @@ class GenerativeServing:
             self._thread = None
         else:
             try:
+                self._fold_in_flight()
                 self._fail_active(SHUTDOWN_ERROR)
             finally:
                 self._publisher.close()
@@ -3035,6 +3221,10 @@ class GenerativeServing:
             "prompt_tokens_total": int(self._m["prompt_tokens"].value()),
             "steps_between_chunks_total": int(
                 self._m["steps_between_chunks"].value()),
+            "decode_steps_total": int(self._m["decode_steps"].value()),
+            "steps_ahead_total": int(self._m["steps_ahead"].value()),
+            "overrun_slot_steps_total": int(
+                self._m["overrun_slot_steps"].value()),
             "state_slots_in_use": (
                 int(np.sum(self._active_host)) + len(self._prefilling)
                 if self._recurrent else None),

@@ -529,7 +529,7 @@ def test_resident_streams_decode_between_the_chunks_of_a_joining_prompt(
     for _ in range(3 * k + 3):
         srv.serve_step()
         chunks.append(srv.counters["prefill_chunks"] - before)
-        tokens.append(len(srv._tokens[0]))
+        tokens.append(len(srv._streams[0].tokens))
     assert len(srv.lm.chunk_plan(59 * 4 - 1)) == 4
     # a chunk, then k decode steps of the resident stream before the next
     # chunk; the stream decodes in every iteration

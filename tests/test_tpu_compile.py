@@ -376,7 +376,9 @@ def test_serving_programs_keep_the_page_pools_in_place(
     assert pools[0]["k"].shape == _POOL
     table, row, scalar = v5e((48, 64), I32), v5e((64,), I32), v5e((), I32)
     if program == "decode_step":
+        # the host's tokens, the step before's (with its pages-read count)
         lowered = srv._step_fn.lower(params, v5e((48,), I32),
+                                     v5e((49,), I32),
                                      v5e((48, 2), jnp.uint32), state, table,
                                      pools)
     elif program == "prefill":
@@ -450,6 +452,7 @@ def test_sala_programs_keep_pools_and_states_in_place(v5e, tmp_path,
     row, scalar = v5e((_SALA["width"],), I32), v5e((), I32)
     if program == "decode_step":
         lowered = srv._step_fn.lower(params, v5e((slots,), I32),
+                                     v5e((slots,), I32),
                                      v5e((slots, 2), jnp.uint32), state,
                                      table, pools)
     else:
@@ -526,6 +529,7 @@ def test_smallthinker_programs_keep_pools_and_expert_tables_in_place(
     row, scalar = v5e((_MIXED["width"],), I32), v5e((), I32)
     if program == "decode_step":
         lowered = srv._step_fn.lower(params, v5e((slots,), I32),
+                                     v5e((slots,), I32),
                                      v5e((slots, 2), jnp.uint32), state,
                                      (table, table), pools)
     else:
