@@ -5,12 +5,15 @@ by layer, and :class:`LayeredDecoder` runs it behind
 the server: ``params``, ``max_len``, cache construction, a paged decode
 step, a prefill; here ``paged_state_step`` and ``prefill_chunk``).
 
-What it covers today is what MiniCPM-SALA and SmallThinker need
+What it covers today is what MiniCPM-SALA, SmallThinker and GLM-5 need
 (docs/models.md): RMS norms, an untied head, MiniCPM's embedding, residual
-and logit scalings, bfloat16 parameters, a feed-forward chosen by the spec
-(``"silu"``: gated SiLU; ``"moe"``: a softmax router on the layer's input,
-before attention, and dropless top-k ReGLU experts, ``ops/moe.py``), and a
-mixer chosen by layer:
+and logit scalings, bfloat16 parameters, a feed-forward chosen by the spec,
+for the whole model or layer by layer (``"silu"``: gated SiLU; ``"moe"``:
+dropless top-k experts, ``ops/moe.py``, under a router that is data too: a
+softmax on the layer's input before attention with ReGLU experts, or
+sigmoid scores with a correction bias and a scaling on the normed output of
+attention, with gated-SiLU experts and a shared expert), and a mixer chosen
+by layer:
 
 - ``"lightning-attn"``: linear attention with a decay a head
   (``ops/linear_attention.py``), ``qk_norm``, rotary positions, an output
@@ -41,7 +44,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import grouped_attention, moe
+from ..ops import grouped_attention, latent_attention, moe
 from ..ops.decode import _page_positions, _paged_write, init_paged_pool
 from ..ops.linear_attention import (lightning_slopes, linear_attention_chunk,
                                     linear_attention_step)
@@ -52,6 +55,7 @@ from ..ops.sparse_attention import (SparseSpec, attend_chunk, attend_step,
 
 LINEAR, SPARSE = "lightning-attn", "minicpm4"
 FULL, WINDOW = "full", "window"
+LATENT = "mla-dsa"
 SILU, MOE = "silu", "moe"
 
 
@@ -87,19 +91,46 @@ class DecoderSpec:
     held_experts: Optional[Tuple[int, ...]] = None
     window: int = 0                # positions a ``"window"`` layer sees
     page_len: int = 0              # 0: one page is one selection block
+    #: the feed-forward of each layer in order (``None``: ``ffn`` in all)
+    ffns: Optional[Tuple[str, ...]] = None
+    #: the experts' width where it is not ``intermediate`` (a model whose
+    #: dense layers are wider than its experts)
+    expert_width: int = 0
+    #: the router: ``"softmax"`` reads the layer's input as it is, before
+    #: attention; ``"sigmoid"`` reads the normed output of attention, adds
+    #: a correction bias for the choice alone, divides the chosen scores by
+    #: their sum and scales them by ``routed_scale``
+    router: str = "softmax"
+    routed_scale: float = 1.0
+    expert_act: str = "relu"       # ``"relu"`` (ReGLU) or ``"silu"``
+    shared_width: int = 0          # a shared expert every token reads
+    #: the sizes of the ``"mla-dsa"`` layers
+    latent: Optional[latent_attention.LatentSpec] = None
 
     def __post_init__(self):
-        known = (LINEAR, SPARSE, FULL, WINDOW)
+        known = (LINEAR, SPARSE, FULL, WINDOW, LATENT)
         unknown = set(self.mixers) - set(known)
         if unknown:
             raise ValueError(f"no mixer named {sorted(unknown)}; have "
                              f"{', '.join(map(repr, known))}")
-        if self.ffn not in (SILU, MOE):
-            raise ValueError(f"no feed-forward named {self.ffn!r}; have "
-                             f"{SILU!r} and {MOE!r}")
-        if self.ffn == MOE and not 0 < self.experts_per_token <= self.experts:
+        unknown = set(self.layer_ffns) - {SILU, MOE}
+        if unknown:
+            raise ValueError(f"no feed-forward named {sorted(unknown)}; "
+                             f"have {SILU!r} and {MOE!r}")
+        if len(self.layer_ffns) != len(self.mixers):
+            raise ValueError(f"{len(self.layer_ffns)} feed-forwards for "
+                             f"{len(self.mixers)} mixers")
+        if self.has_experts \
+                and not 0 < self.experts_per_token <= self.experts:
             raise ValueError(f"{self.experts_per_token} experts a token of "
                              f"{self.experts}")
+        if self.router not in ("softmax", "sigmoid") \
+                or self.expert_act not in ("relu", "silu"):
+            raise ValueError(f"router {self.router!r} (softmax, sigmoid) "
+                             f"or expert_act {self.expert_act!r} (relu, "
+                             f"silu) is not known")
+        if LATENT in self.mixers and self.latent is None:
+            raise ValueError("an mla-dsa layer needs its sizes (latent)")
         if WINDOW in self.mixers and (self.window < 1
                                       or self.window % self.page):
             raise ValueError(f"a window layer's window must be whole pages "
@@ -115,14 +146,29 @@ class DecoderSpec:
     def page(self) -> int:
         return self.page_len or self.sparse.block_size
 
+    @property
+    def layer_ffns(self) -> Tuple[str, ...]:
+        """The feed-forward of each layer in order."""
+        return self.ffns if self.ffns is not None \
+            else (self.ffn,) * len(self.mixers)
+
+    @property
+    def has_experts(self) -> bool:
+        return MOE in self.layer_ffns
+
     @classmethod
     def from_config(cls, cfg: Dict[str, Any], max_len: int,
                     page_len: int = 64) -> "DecoderSpec":
         """From a ``config.json`` with its keys as published: a
         ``minicpm_sala`` one (``mixer_types``; ``sparse_attention`` holds
-        InfLLM-V2's sizes, which the published file leaves to the code) or
-        a SmallThinker one (``sliding_window_layout`` with ``rope_layout``
-        and the ``moe_*`` keys; ``page_len`` is the K/V page)."""
+        InfLLM-V2's sizes, which the published file leaves to the code), a
+        SmallThinker one (``sliding_window_layout`` with ``rope_layout``
+        and the ``moe_*`` keys; ``page_len`` is the K/V page) or a
+        ``glm_moe_dsa`` one (``kv_lora_rank``, ``index_*``,
+        ``first_k_dense_replace``, ``n_routed_experts`` with
+        ``held_experts`` where the chip holds a share)."""
+        if "kv_lora_rank" in cfg:
+            return cls._from_latent(cfg, max_len, page_len)
         if "sliding_window_layout" in cfg:
             return cls._from_window_layout(cfg, max_len, page_len)
         return cls(
@@ -165,29 +211,86 @@ class DecoderSpec:
             experts_per_token=cfg["moe_num_active_primary_experts"],
             window=cfg["sliding_window_size"], page_len=page_len)
 
-    def layer_shapes(self, kind: str):
+    @classmethod
+    def _from_latent(cls, cfg, max_len, page_len):
+        n, dense = cfg["num_hidden_layers"], cfg["first_k_dense_replace"]
+        held = cfg.get("held_experts")
+        return cls(
+            vocab_size=cfg["vocab_size"], hidden=cfg["hidden_size"],
+            intermediate=cfg["intermediate_size"],
+            mixers=(LATENT,) * n, heads=cfg["num_attention_heads"],
+            kv_heads=cfg["num_key_value_heads"],
+            head_dim=cfg["qk_head_dim"], linear_heads=0, linear_head_dim=0,
+            max_len=max_len, rms_eps=cfg["rms_norm_eps"],
+            rope_theta=cfg["rope_parameters"]["rope_theta"],
+            param_dtype=cfg.get("param_dtype", "bfloat16"),
+            init_std=cfg.get("initializer_range", 0.02),
+            ffns=(SILU,) * dense + (MOE,) * (n - dense),
+            experts=cfg.get("n_routed_experts_published",
+                            cfg["n_routed_experts"]),
+            experts_per_token=cfg["num_experts_per_tok"],
+            held_experts=None if held is None else tuple(held),
+            expert_width=cfg["moe_intermediate_size"],
+            router=("sigmoid" if cfg["scoring_func"] == "sigmoid"
+                    else "softmax"),
+            routed_scale=cfg["routed_scaling_factor"], expert_act="silu",
+            shared_width=cfg["n_shared_experts"]
+            * cfg["moe_intermediate_size"], page_len=page_len,
+            latent=latent_attention.LatentSpec(
+                heads=cfg["num_attention_heads"],
+                q_rank=cfg["q_lora_rank"], kv_rank=cfg["kv_lora_rank"],
+                nope_dim=cfg["qk_nope_head_dim"],
+                rope_dim=cfg["qk_rope_head_dim"], v_dim=cfg["v_head_dim"],
+                index_heads=cfg["index_n_heads"],
+                index_dim=cfg["index_head_dim"],
+                index_topk=cfg["index_topk"],
+                index_rope_dim=cfg["qk_rope_head_dim"]))
+
+    def layer_shapes(self, kind: str, ffn: Optional[str] = None):
+        """``(matrices, vectors that start at 1)`` of a layer of mixer
+        ``kind`` and feed-forward ``ffn`` (``None``: the model's one);
+        :meth:`layer_zeros` has the vectors that start at 0."""
         d, f = self.hidden, self.intermediate
-        if kind == LINEAR:
-            h = kv = self.linear_heads * self.linear_head_dim
-            hd = self.linear_head_dim
-        else:
-            h, kv, hd = (self.heads * self.head_dim,
-                         self.kv_heads * self.head_dim, self.head_dim)
-        mats = {"q": (d, h), "k": (d, kv), "v": (d, kv), "o": (h, d)}
+        ffn = ffn or self.ffn
         ones = {"norm1": (d,), "norm2": (d,)}
+        if kind == LATENT:
+            mats, more, _ = self.latent.shapes(d)
+            mats, ones = dict(mats), dict(ones, **more)
+        else:
+            if kind == LINEAR:
+                h = kv = self.linear_heads * self.linear_head_dim
+                hd = self.linear_head_dim
+            else:
+                h, kv, hd = (self.heads * self.head_dim,
+                             self.kv_heads * self.head_dim, self.head_dim)
+            mats = {"q": (d, h), "k": (d, kv), "v": (d, kv), "o": (h, d)}
         if kind in (LINEAR, SPARSE):    # qk_norm and an output gate
             mats["g"] = (d, h)
             ones.update(q_norm=(hd,), k_norm=(hd,))
         if kind == LINEAR:
             ones["o_norm"] = (h,)
-        if self.ffn == MOE:
+        if ffn == MOE:
             e = self.experts if self.held_experts is None \
                 else len(self.held_experts)
-            mats.update(router=(d, self.experts), w_gate=(e, d, f),
-                        w_up=(e, d, f), w_down=(e, f, d))
+            w = self.expert_width or f
+            mats.update(router=(d, self.experts), w_gate=(e, d, w),
+                        w_up=(e, d, w), w_down=(e, w, d))
+            if self.shared_width:
+                mats.update(shared_gate=(d, self.shared_width),
+                            shared_up=(d, self.shared_width),
+                            shared_down=(self.shared_width, d))
         else:
             mats.update(gate_proj=(d, f), up_proj=(d, f), down_proj=(f, d))
         return mats, ones
+
+    def layer_zeros(self, kind: str, ffn: Optional[str] = None):
+        """The vectors of such a layer that start at 0: the indexer's key
+        norm's bias, a sigmoid router's correction bias."""
+        zeros = dict(self.latent.shapes(self.hidden)[2]) \
+            if kind == LATENT else {}
+        if (ffn or self.ffn) == MOE and self.router == "sigmoid":
+            zeros["router_bias"] = (self.experts,)
+        return zeros
 
 
 def _rms_norm(weight, x, eps):
@@ -231,10 +334,15 @@ class LayeredDecoder:
         #: positions a window layer sees, 0 where the model has none: the
         #: server then keeps a second page budget (:meth:`window_pages`)
         self.window_len = spec.window if WINDOW in spec.mixers else 0
-        #: what ``paged_state_step`` returns third, by name, in order
-        self.step_stats = (("moe_experts_touched", "moe_expert_load",
-                            "moe_assignments") if spec.ffn == MOE
-                           else ("sparse_positions_read",))
+        #: what ``paged_state_step`` returns third, by name, in order: what
+        #: the spec has of routed experts, sparse reads and an indexer
+        reads = SPARSE in spec.mixers or LATENT in spec.mixers
+        self.step_stats = (
+            (("moe_experts_touched", "moe_expert_load", "moe_assignments")
+             if spec.has_experts else ())
+            + (("sparse_positions_read",)
+               if reads or not spec.has_experts else ())
+            + (("dsa_positions_scored",) if LATENT in spec.mixers else ()))
         #: a prompt is fed in chunks of ``prefill_chunk`` positions, the
         #: last padded to one of these: a closed set of compiled programs
         self.chunk_buckets = (prefill_chunk // 4, prefill_chunk // 2,
@@ -262,13 +370,15 @@ class LayeredDecoder:
             return (jax.random.normal(key, shape) * spec.init_std).astype(
                 dtype)
         layers = []
-        for i, kind in enumerate(spec.mixers):
-            mats, ones = spec.layer_shapes(kind)
+        for i, (kind, ffn) in enumerate(zip(spec.mixers, spec.layer_ffns)):
+            mats, ones = spec.layer_shapes(kind, ffn)
             keys = jax.random.split(jax.random.fold_in(root, i), len(mats))
             layer = {n: draw(k, s) for k, (n, s) in
                      zip(keys, sorted(mats.items()))}
             layer.update({n: jnp.ones(s, jnp.float32)
                           for n, s in ones.items()})
+            layer.update({n: jnp.zeros(s, jnp.float32)
+                          for n, s in spec.layer_zeros(kind, ffn).items()})
             layers.append(layer)
         table = (spec.vocab_size, spec.hidden)
         return {"embed": draw(jax.random.fold_in(root, 1000), table),
@@ -301,7 +411,8 @@ class LayeredDecoder:
         """One cache a layer, in layer order: a sparse layer's K/V page
         pool with its compressed-key pool, a full layer's K/V page pool
         (``num_pages`` each), a window layer's (:meth:`window_pages`), a
-        lightning layer's state ``[slots, H, D, D]`` in float32."""
+        lightning layer's state ``[slots, H, D, D]`` in float32, a latent
+        layer's latent pool with its index-key pool (``num_pages`` each)."""
         spec = self.spec
         if int8:
             raise NotImplementedError(
@@ -321,6 +432,9 @@ class LayeredDecoder:
                 caches.append(init_paged_pool(
                     num_pages if kind == FULL else self.window_pages(slots),
                     spec.kv_heads, page_len, spec.head_dim, dtype))
+            elif kind == LATENT:
+                caches.append(latent_attention.init_latent_pool(
+                    num_pages, page_len, spec.latent, dtype))
             else:
                 caches.append({"state": jnp.zeros(
                     (slots, spec.linear_heads, spec.linear_head_dim,
@@ -393,15 +507,47 @@ class LayeredDecoder:
                 o.reshape(o.shape[0], -1), p["o"])
         return x, cache
 
-    def _moe(self, p, x, choice, gates, valid):
-        """``x + experts(N(x))`` under the routing made from the layer's
-        input; also each expert's assignments."""
+    def _moe(self, p, x, valid, routed=None):
+        """``x + experts(h)`` (and the shared expert's ``shared(h)`` where
+        the model has one), ``h = N(x)``, under the routing ``routed``
+        (``(choice, gates)``, made from the layer's input before
+        attention) or, where there is none, a routing made from ``h``
+        itself; also each held expert's assignments."""
         spec = self.spec
         with jax.named_scope("layer_norm"):
             h = _rms_norm(p["norm2"], x, spec.rms_eps)
-        y, sizes = moe.experts(h, choice, gates, p["w_gate"], p["w_up"],
-                               p["w_down"], spec.held_experts, valid)
+        choice, gates = routed or moe.route(
+            h, p["router"], spec.experts_per_token, spec.router,
+            p.get("router_bias"), spec.routed_scale)
+        y, sizes = moe.experts(
+            h, choice, gates, p["w_gate"], p["w_up"], p["w_down"],
+            spec.held_experts, valid,
+            jax.nn.silu if spec.expert_act == "silu" else jax.nn.relu)
+        if spec.shared_width:
+            y = y + moe.shared(h, p["shared_gate"], p["shared_up"],
+                               p["shared_down"])
         return x + spec.residual_scale * y, sizes
+
+    def _attend_latent(self, p, x, u, cache, table, positions, attend):
+        """An ``mla-dsa`` layer over ``u [N, d]`` at ``positions`` (``[S,
+        1]`` of a step, ``[1, T]`` of a chunk): the latent products, the
+        row and the index key written through ``table``, the indexer, the
+        selection and the read (``attend(q_nope, q_rope, q_index, w_index,
+        cache)`` gives ``(o, stats)``), the output product."""
+        spec = self.spec
+        lat, at = spec.latent, positions.reshape(-1)
+        q_nope, q_rope, row, c_q = latent_attention.project(
+            lat, p, u, at, spec.rope_theta, spec.rms_eps)
+        q_i, k_i, w_i = latent_attention.index_project(
+            lat, p, u, c_q, at, spec.rope_theta)
+        pages, offs = _page_positions(table, positions, self.page_len)
+        cache = latent_attention.write(
+            cache, pages, offs, row.reshape(positions.shape + (-1,)),
+            k_i.reshape(positions.shape + (-1,)))
+        o, stats = attend(q_nope, q_rope, q_i, w_i, cache)
+        with jax.named_scope("mla_project"):
+            x = x + spec.residual_scale * _product(o, p["o"])
+        return x, cache, stats
 
     def _embed(self, params, tokens):
         with jax.named_scope("embed"):
@@ -420,7 +566,11 @@ class LayeredDecoder:
         mean over the sparse layers, as the device counted them), or, for
         routed experts, the distinct experts a layer used, its busiest
         expert's assignments over the mean (both means over the layers) and
-        the assignments of all layers, the active slots' alone. Only an
+        the assignments of all layers, the active slots' alone (of the
+        experts held here, where the chip holds a share); a model with
+        latent layers gives the experts' three, the positions its sparse
+        read gathered and the positions its indexer scored for a live
+        stream (means over the active slots and the layers). Only an
         ``active`` slot's state moves, and only an active slot's token
         reads an expert."""
         spec = self.spec
@@ -430,14 +580,22 @@ class LayeredDecoder:
         table, window_table = table if isinstance(table, (tuple, list)) \
             else (table, table)
         x = self._embed(params, tokens)                         # [S, d]
-        new_caches, reads, loads = [], [], []
-        for kind, p, cache in zip(spec.mixers, params["layers"], caches):
-            if spec.ffn == MOE:  # the router sees the layer's input as it is
-                choice, gates = moe.route(x, p["router"],
-                                          spec.experts_per_token)
+        new_caches, reads, loads, scored = [], [], [], []
+        for kind, ffn, p, cache in zip(spec.mixers, spec.layer_ffns,
+                                       params["layers"], caches):
+            # a softmax router sees the layer's input as it is
+            routed = moe.route(x, p["router"], spec.experts_per_token) \
+                if ffn == MOE and spec.router == "softmax" else None
             with jax.named_scope("layer_norm"):
                 u = _rms_norm(p["norm1"], x, spec.rms_eps)
-            if kind in (FULL, WINDOW):
+            if kind == LATENT:
+                x, cache, (read, seen) = self._attend_latent(
+                    p, x, u, cache, table, lengths[:, None],
+                    lambda *q: self._latent_step(p, *q, table, lengths,
+                                                 active))
+                reads.append(read)
+                scored.append(seen)
+            elif kind in (FULL, WINDOW):
                 window = spec.window if kind == WINDOW else None
                 tab = window_table if kind == WINDOW else table
                 x, cache = self._attend_paged(
@@ -478,20 +636,42 @@ class LayeredDecoder:
                 reads.append(read)
                 with jax.named_scope("attention"):
                     x = self._mix_out(p, x, u, o.reshape(s, -1))
-            if spec.ffn == MOE:
-                x, sizes = self._moe(p, x, choice, gates, active)
+            if ffn == MOE:
+                x, sizes = self._moe(p, x, active, routed)
                 loads.append(moe.load_stats(sizes))
             else:
                 x = self._ffn(p, x)
             new_caches.append(cache)
+        parts = []  # what :attr:`step_stats` names, in its order
         if loads:
-            loads = jnp.stack(loads)                            # [layers, 3]
-            read = jnp.concatenate([jnp.mean(loads[:, :2], axis=0),
-                                    jnp.sum(loads[:, 2:], axis=0)])
-        else:
-            read = jnp.mean(jnp.stack(reads).astype(jnp.float32)) if reads \
-                else jnp.float32(0)
+            by_layer = jnp.stack(loads)                         # [layers, 3]
+            parts.append(jnp.concatenate([jnp.mean(by_layer[:, :2], axis=0),
+                                          jnp.sum(by_layer[:, 2:], axis=0)]))
+        if reads or not loads:
+            parts.append(
+                jnp.mean(jnp.stack(reads).astype(jnp.float32)) if reads
+                else jnp.float32(0))
+        if scored:
+            parts.append(jnp.mean(jnp.stack(scored)))
+        read = parts[0] if len(parts) == 1 else jnp.concatenate(
+            [jnp.atleast_1d(part) for part in parts])
         return self._head(params, x), new_caches, read
+
+    def _latent_step(self, p, q_nope, q_rope, q_i, w_i, cache, table,
+                     lengths, active):
+        """The indexer, the selection and the absorbed read of a decode
+        step; also the positions read and scored for a live stream (the
+        means over the active slots)."""
+        lat = self.spec.latent
+        scores = latent_attention.index_step(
+            lat, q_i, w_i, cache["index"], table, lengths, active)
+        at, real = latent_attention.select_step(lat, scores)
+        o = latent_attention.attend_step(lat, p, q_nope, q_rope,
+                                         cache["latent"], table, at, real)
+        live = jnp.maximum(jnp.sum(active), 1).astype(jnp.float32)
+        read = jnp.sum(jnp.where(active[:, None], real, False)) / live
+        seen = jnp.sum(jnp.where(active, lengths + 1, 0)) / live
+        return o, (read, seen.astype(jnp.float32))
 
     def _head(self, params, x):
         spec = self.spec
@@ -537,13 +717,17 @@ class LayeredDecoder:
             else (row, row)
         real = jnp.arange(t) < n_valid
         new_caches = []
-        for kind, p, cache in zip(spec.mixers, params["layers"], caches):
-            if spec.ffn == MOE:
-                choice, gates = moe.route(x, p["router"],
-                                          spec.experts_per_token)
+        for kind, ffn, p, cache in zip(spec.mixers, spec.layer_ffns,
+                                       params["layers"], caches):
+            routed = moe.route(x, p["router"], spec.experts_per_token) \
+                if ffn == MOE and spec.router == "softmax" else None
             with jax.named_scope("layer_norm"):
                 u = _rms_norm(p["norm1"], x, spec.rms_eps)
-            if kind in (FULL, WINDOW):
+            if kind == LATENT:
+                x, cache, _ = self._attend_latent(
+                    p, x, u, cache, row[None], positions[None],
+                    lambda *q: (self._latent_chunk(p, *q, row, start), None))
+            elif kind in (FULL, WINDOW):
                 window = spec.window if kind == WINDOW else None
                 pages = window_row if kind == WINDOW else row
                 x, cache = self._attend_paged(
@@ -586,9 +770,20 @@ class LayeredDecoder:
                 o = attend_chunk(spec.sparse, q, cache, row, start, allowed)
                 with jax.named_scope("attention"):
                     x = self._mix_out(p, x, u, o.reshape(t, -1))
-            if spec.ffn == MOE:
-                x, _ = self._moe(p, x, choice, gates, real)
+            if ffn == MOE:
+                x, _ = self._moe(p, x, real, routed)
             else:
                 x = self._ffn(p, x)
             new_caches.append(cache)
         return new_caches
+
+    def _latent_chunk(self, p, q_nope, q_rope, q_i, w_i, cache, row, start):
+        """The indexer, the selection and the plain masked read of a chunk's
+        queries, each over its own selection."""
+        lat = self.spec.latent
+        bits = latent_attention.index_chunk(lat, q_i, w_i, cache["index"],
+                                            row, start)
+        threshold, last = latent_attention.select_chunk(lat, bits, start)
+        return latent_attention.attend_chunk(
+            lat, p, q_nope, q_rope, cache["latent"], row, start, bits,
+            threshold, last)

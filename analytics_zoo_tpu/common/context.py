@@ -63,6 +63,7 @@ class ZooTpuContext:
 _context_lock = threading.Lock()
 _context: Optional[ZooTpuContext] = None
 _cache_wired: bool = False
+_compiles_counted: bool = False
 
 #: what JAX reports of its own compiles (``jax.monitoring``), counted from
 #: :func:`wire_compilation_cache` on. A program that XLA builds and one that
@@ -111,6 +112,18 @@ DEFAULT_COMPILE_CACHE_DIR = os.path.join(
         os.path.abspath(__file__)))), ".jax_cache")
 
 
+def _count_compiles() -> None:
+    """Register the listeners behind the ``compile.*`` metrics, once a
+    process whatever happens to the cache's wiring: a second registration
+    would count every compile twice."""
+    global _compiles_counted
+    if not _compiles_counted:
+        jax.monitoring.register_event_listener(_on_compile_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_duration)
+        _compiles_counted = True
+
+
 def wire_compilation_cache() -> str:
     """Turn on JAX's persistent compilation cache and return its directory.
 
@@ -149,9 +162,7 @@ def wire_compilation_cache() -> str:
         jax.config.update("jax_compilation_cache_include_metadata_in_key",
                           True)
         jax.config.update("jax_traceback_in_locations_limit", 0)
-        jax.monitoring.register_event_listener(_on_compile_event)
-        jax.monitoring.register_event_duration_secs_listener(
-            _on_compile_duration)
+        _count_compiles()
         _cache_wired = True
         logger.info("persistent compilation cache: %s (%s)",
                     placed or DEFAULT_COMPILE_CACHE_DIR,

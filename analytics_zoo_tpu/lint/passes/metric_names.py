@@ -58,7 +58,8 @@ _GAUGE_UNITLESS_OK = {"serving.in_flight", "serving.slots_occupied",
 _HISTOGRAM_UNITLESS_OK = {"serving.sparse_positions_read",
                            "serving.paged_pages_read",
                            "serving.moe_experts_touched",
-                           "serving.moe_expert_load"}
+                           "serving.moe_expert_load",
+                           "serving.dsa_positions_scored"}
 
 
 def _is_registration(node: ast.Call) -> bool:

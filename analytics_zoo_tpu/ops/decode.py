@@ -319,7 +319,8 @@ def shard_paged_pool(caches, n_shard: int,
     if n_shard < 1 or len(devs) % n_shard:
         raise ValueError(f"kv shard count {n_shard} must divide the local "
                          f"device count {len(devs)}")
-    num_pages = caches[0]["k"].shape[0]
+    # every pool leaf has the pages first (K/V, a latent or an index pool)
+    num_pages = jax.tree_util.tree_leaves(caches[0])[0].shape[0]
     if num_pages % n_shard:
         raise ValueError(f"num_pages {num_pages} must be divisible by the "
                          f"kv shard count {n_shard}")

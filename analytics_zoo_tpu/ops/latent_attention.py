@@ -1,0 +1,612 @@
+"""Multi-head latent attention (MLA) over a paged **latent** pool, with a
+learned sparse selection of single positions (DSA: an indexer that scores
+every position so far and keeps the ``index_topk`` best), as the
+``mla-dsa`` layers of ``capture/decoder.py`` use it.
+
+What a position leaves in the cache is one row ``[c_kv ; k_rope]``
+(``kv_rank + rope_dim`` numbers, shared by all heads) in the pool
+``latent [P, page_len, kv_rank + rope_dim]`` and one index key
+(``index_dim`` numbers) in the pool ``index [P, page_len, index_dim]``,
+both read through the server's one page table. Keys and values of the
+heads are products of the latent row with ``kv_b`` and are never stored.
+
+Two attention paths that compute the same function:
+
+- **a decode step** (:func:`attend_step`) is *absorbed*: the query is
+  carried through ``kv_b``'s key half (``q' = W_k^T q_nope``), scores are
+  taken against the latent rows themselves, and the weighted sum of latent
+  rows goes through ``kv_b``'s value half once a head. It reads the
+  selected rows of every slot from the pool, ``index_topk`` at most, by a
+  gather through the table: the step's cost follows the selection, not the
+  context.
+- **a chunk** of one stream's prompt (:func:`attend_chunk`) is *plain*:
+  each tile of pages is expanded to the heads' keys and values and every
+  query row is masked to its own selection (``s in S_t``). With ``T``
+  queries a tile the expansion costs a fifth of the scores, where the
+  absorbed form would cost 1.7 times the plain one. On one TPU chip a
+  tile's scores, mask and softmax run in a pallas kernel
+  (:func:`_tile_attend_kernel`: the scores stay in VMEM; as XLA writes it
+  they cross the HBM four times, which was four fifths of a chunk);
+  elsewhere the XLA form runs, and on the TPU that is noted once with the
+  rule. The tiles' results are merged by their logs of sums.
+
+The indexer (:func:`index_step`, :func:`index_chunk`): ``I[t, s] = sum_j
+w[t, j] relu(q_I[t, j] . k_I[s])`` over ``s <= t``, float32 accumulation,
+a tile of pages at a time. The selection is exact: a decode step takes
+``lax.top_k`` of each slot's scores (ties to the lower position); a chunk,
+whose scores are ``[T, context]``, finds each row's ``index_topk``-th
+largest score by a 16-way search on the scores' bits and, among the
+positions that tie with it, the lowest ones by the same search on the
+position, so a row's mask is ``score > threshold or (score == threshold
+and position <= last)``: the set ``lax.top_k`` would return, never an
+approximation of it.
+
+Scopes on the device (docs/observability.md): ``mla_project`` (the latent
+products with their norms and rotary), ``dsa_index`` (the indexer's
+products and scores), ``dsa_select`` (the top-k), ``mla_attend`` (the
+gather of the selected rows and the softmax over them, or the chunk's
+masked tiles), ``kv_write`` (both pools' scatter)."""
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from . import dispatch
+
+_NEG = -1e30
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """The sizes of an ``mla-dsa`` layer; widths are the model's own."""
+    heads: int = 64
+    q_rank: int = 2048
+    kv_rank: int = 512
+    nope_dim: int = 192
+    rope_dim: int = 64
+    v_dim: int = 256
+    index_heads: int = 32
+    index_dim: int = 128
+    index_topk: int = 2048
+    index_rope_dim: int = 64
+    index_norm_eps: float = 1e-6
+    #: positions of a tile of the decode step's scoring loop, of a chunk's
+    #: scoring and selection loops, and of a chunk's attention loop
+    step_tile: int = 2560
+    chunk_tile: int = 512
+    attend_tile: int = 2048
+
+    def __post_init__(self):
+        if self.rope_dim % 2 or self.index_rope_dim % 2 \
+                or self.index_rope_dim > self.index_dim:
+            raise ValueError("rotary widths must be even and lie inside "
+                             "the head")
+
+    @property
+    def row(self) -> int:
+        """Numbers a position leaves in the latent pool."""
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def pool_row(self) -> int:
+        """The latent pool's row: ``row`` in whole tiles of 128 lanes. The
+        chip's row-major layout pads a row to that anyway, and for a row
+        that is no whole tiles its compiler would rather keep the pool
+        pages-minor-most and lay it out anew around every gather of rows
+        (two pool-sized copies a layer a step)."""
+        return -(-self.row // 128) * 128
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    def shapes(self, hidden: int) -> Tuple[Dict, Dict, Dict]:
+        """``(matrices, vectors that start at 1, vectors that start at
+        0)`` of one layer's attention and indexer."""
+        h = self.heads
+        mats = {"q_a": (hidden, self.q_rank),
+                "q_b": (self.q_rank, h * self.qk_dim),
+                "kv_a": (hidden, self.row),
+                "kv_b": (self.kv_rank, h * (self.nope_dim + self.v_dim)),
+                "o": (h * self.v_dim, hidden),
+                "index_q": (self.q_rank, self.index_heads * self.index_dim),
+                "index_k": (hidden, self.index_dim),
+                "index_w": (hidden, self.index_heads)}
+        ones = {"q_a_norm": (self.q_rank,), "kv_a_norm": (self.kv_rank,),
+                "index_k_norm": (self.index_dim,)}
+        zeros = {"index_k_bias": (self.index_dim,)}
+        return mats, ones, zeros
+
+
+def init_latent_pool(num_pages: int, page_len: int, spec: LatentSpec,
+                     dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
+    """The latent pool ``[P, page_len, pool_row]`` (``kv_rank + rope_dim``
+    numbers a position, in whole tiles of 128 lanes) and the index key
+    pool ``[P, page_len, index_dim]``; page 0 is the null page."""
+    if num_pages < 2:
+        raise ValueError(f"num_pages must be >= 2 (page 0 is the reserved "
+                         f"null page), got {num_pages}")
+    return {"latent": jnp.zeros((num_pages, page_len, spec.pool_row), dtype),
+            "index": jnp.zeros((num_pages, page_len, spec.index_dim), dtype)}
+
+
+# -- the pieces -----------------------------------------------------------------
+
+def _rms_norm(weight, x, eps):
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                         + eps) * weight
+
+
+def _product(x, w):
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+
+
+def rotary_interleaved(x: jax.Array, positions: jax.Array, theta: float
+                       ) -> jax.Array:
+    """Rotary positions on the pairs ``(2i, 2i+1)`` of the last axis of
+    ``x [..., D]`` (float32); ``positions`` has ``x``'s leading axes or
+    broadcasts against them from the left (``[T]`` for ``[T, H, D]``). The
+    partner of each number comes from a product with a fixed ``D x D``
+    matrix of 0 and +-1 at ``highest``, which is exact and keeps the
+    head's numbers in their lanes (a reshape to pairs would not)."""
+    d = x.shape[-1]
+    freq = theta ** (-jnp.arange(d // 2, dtype=jnp.float32) / (d // 2))
+    angle = positions.astype(jnp.float32)[..., None] * jnp.repeat(freq, 2)
+    angle = angle.reshape(angle.shape[:-1] + (1,) * (x.ndim - angle.ndim)
+                          + (d,))
+    at = jnp.arange(d)
+    # partner[.., 2i] = -x[.., 2i+1], partner[.., 2i+1] = x[.., 2i]
+    swap = jnp.where(at[:, None] == (at ^ 1)[None],
+                     jnp.where(at[:, None] % 2 == 1, -1.0, 1.0), 0.0)
+    partner = jnp.dot(x, swap.astype(jnp.float32),
+                      precision=lax.Precision.HIGHEST)
+    return x * jnp.cos(angle) + partner * jnp.sin(angle)
+
+
+@jax.named_scope("mla_project")
+def project(spec: LatentSpec, p, u: jax.Array, positions: jax.Array,
+            theta: float, eps: float):
+    """The latent products of ``u [N, d]`` at ``positions [N]``: the scaled
+    query halves ``q_nope [N, H, nope]`` and ``q_rope [N, H, rope]``, the
+    row ``[c_kv ; k_rope] [N, kv_rank + rope]`` that the position leaves in
+    the cache, and the query latent ``c_q [N, q_rank]`` (the indexer reads
+    it too)."""
+    n = u.shape[0]
+    c_q = _rms_norm(p["q_a_norm"], _product(u, p["q_a"]), eps)
+    q = _product(c_q, p["q_b"]).reshape(n, spec.heads, spec.qk_dim)
+    q = q / math.sqrt(spec.qk_dim)
+    q_rope = rotary_interleaved(q[..., spec.nope_dim:], positions, theta)
+    kv = _product(u, p["kv_a"])
+    c_kv = _rms_norm(p["kv_a_norm"], kv[:, :spec.kv_rank], eps)
+    k_rope = rotary_interleaved(kv[:, spec.kv_rank:], positions, theta)
+    return (q[..., :spec.nope_dim], q_rope,
+            jnp.concatenate([c_kv, k_rope], axis=-1), c_q)
+
+
+@jax.named_scope("dsa_index")
+def index_project(spec: LatentSpec, p, u: jax.Array, c_q: jax.Array,
+                  positions: jax.Array, theta: float):
+    """The indexer's query heads ``[N, IH, ID]`` (from the query latent),
+    its one key a position ``[N, ID]`` (a layer norm with weight and bias
+    over ``W_kI u``) and the heads' weights ``[N, IH]``; rotary on the
+    first ``index_rope_dim`` numbers of queries and keys."""
+    n, r = u.shape[0], spec.index_rope_dim
+    q = _product(c_q, p["index_q"]).reshape(n, spec.index_heads,
+                                            spec.index_dim)
+    k = _product(u, p["index_k"])
+    mean = jnp.mean(k, axis=-1, keepdims=True)
+    k = (k - mean) * lax.rsqrt(
+        jnp.mean(jnp.square(k - mean), axis=-1, keepdims=True)
+        + spec.index_norm_eps) * p["index_k_norm"] + p["index_k_bias"]
+    q = jnp.concatenate([rotary_interleaved(q[..., :r], positions, theta),
+                         q[..., r:]], axis=-1)
+    k = jnp.concatenate([rotary_interleaved(k[..., :r], positions, theta),
+                         k[..., r:]], axis=-1)
+    w = _product(u, p["index_w"]) / math.sqrt(
+        spec.index_heads * spec.index_dim)
+    return q, k, w
+
+
+@jax.named_scope("kv_write")
+def write(cache: Dict[str, jax.Array], pages: jax.Array, offs: jax.Array,
+          rows: jax.Array, keys: jax.Array) -> Dict[str, jax.Array]:
+    """Scatter the positions' latent ``rows`` and index ``keys`` (leading
+    axes as ``pages`` / ``offs``) into their pools, rounded to the pools'
+    dtype."""
+    pad = cache["latent"].shape[-1] - rows.shape[-1]
+    rows = jnp.pad(rows, ((0, 0),) * (rows.ndim - 1) + ((0, pad),))
+    return {"latent": cache["latent"].at[pages, offs].set(
+                rows.astype(cache["latent"].dtype)),
+            "index": cache["index"].at[pages, offs].set(
+                keys.astype(cache["index"].dtype))}
+
+
+def _tile_pages(tile: int, page_len: int, width: int) -> int:
+    """Pages of a tile of ``tile`` positions over a table ``width`` wide."""
+    return max(min(tile // page_len, width), 1)
+
+
+def _bits_width(spec: LatentSpec, page_len: int, width: int) -> int:
+    """Positions of a chunk's buffer of scores: the table's ``width`` pages
+    in whole tiles of the scoring loop and of the attention loop alike."""
+    unit = math.lcm(
+        _tile_pages(spec.chunk_tile, page_len, width) * page_len,
+        _tile_pages(spec.attend_tile, page_len, width) * page_len)
+    return -(-width * page_len // unit) * unit
+
+
+def _pages_of(rows: jax.Array, i, tile_pages: int) -> jax.Array:
+    """The page ids of tile ``i`` of each table row ``rows [B, W]`` (past
+    the row's width: its last page, whose positions the caller masks)."""
+    at = i * tile_pages + jnp.arange(tile_pages)
+    return jnp.take_along_axis(
+        rows, jnp.minimum(at, rows.shape[1] - 1)[None], axis=1)
+
+
+# -- decode: one position of every slot ---------------------------------------------
+
+@jax.named_scope("dsa_index")
+def index_step(spec: LatentSpec, q: jax.Array, w: jax.Array,
+               index_pool: jax.Array, table: jax.Array, lengths: jax.Array,
+               active: jax.Array) -> jax.Array:
+    """Index scores of every slot's query (at position ``lengths[s]``, its
+    own key written) against the positions ``0 .. lengths[s]``: ``[S, L]``
+    float32, ``-inf`` past the slot's length. Tiles of pages from the first
+    to the longest live stream's last; ``L`` is whole tiles over the
+    table's width."""
+    s = q.shape[0]
+    page_len = index_pool.shape[1]
+    tp = _tile_pages(spec.step_tile, page_len, table.shape[1])
+    tile = tp * page_len
+    n_max = -(-table.shape[1] // tp)
+    qc = q.astype(index_pool.dtype)
+    longest = jnp.max(jnp.where(active, lengths, 0))
+
+    def body(i, scores):
+        keys = jnp.take(index_pool, _pages_of(table, i, tp), axis=0,
+                        mode="clip").reshape(s, tile, -1)
+        dots = jnp.einsum("shd,snd->shn", qc, keys,
+                          preferred_element_type=jnp.float32)
+        got = jnp.einsum("shn,sh->sn", jax.nn.relu(dots), w)
+        pos = i * tile + jnp.arange(tile)
+        got = jnp.where(pos[None] <= lengths[:, None], got, -jnp.inf)
+        return lax.dynamic_update_slice(scores, got, (0, i * tile))
+    return lax.fori_loop(0, longest // tile + 1, body,
+                         jnp.full((s, n_max * tile), -jnp.inf, jnp.float32))
+
+
+@jax.named_scope("dsa_select")
+def select_step(spec: LatentSpec, scores: jax.Array
+                ) -> Tuple[jax.Array, jax.Array]:
+    """The ``index_topk`` positions of largest score a slot (ties to the
+    lower position; every position while there are fewer): ``(positions
+    [S, K], which of them are real [S, K])``."""
+    k = min(spec.index_topk, scores.shape[1])
+    top, at = lax.top_k(scores, k)
+    return at.astype(jnp.int32), top > -jnp.inf
+
+
+@jax.named_scope("mla_attend")
+def attend_step(spec: LatentSpec, p, q_nope: jax.Array, q_rope: jax.Array,
+                latent_pool: jax.Array, table: jax.Array, at: jax.Array,
+                real: jax.Array) -> jax.Array:
+    """The absorbed form over each slot's selected positions ``at [S, K]``:
+    the rows come from the pool through the table, scores are taken
+    against the latent rows themselves, and the weighted latent goes
+    through ``kv_b``'s value half. Returns ``[S, H * v_dim]`` float32."""
+    s = q_nope.shape[0]
+    page_len = latent_pool.shape[1]
+    dtype = latent_pool.dtype
+    # each position's page by comparison with the table's columns: a gather
+    # of single numbers from the table costs the chip 8 ns a number, a
+    # fifth of the row gather itself
+    width = table.shape[1]
+    pages = jnp.sum(jnp.where(
+        jnp.minimum(at // page_len, width - 1)[..., None]
+        == jnp.arange(width), table[:, None], 0), axis=-1)
+    # the pool seen as rows: a gather by page and offset would have the
+    # compiler lay the whole pool out anew
+    rows = jnp.take(latent_pool.reshape(-1, latent_pool.shape[2]),
+                    pages * page_len + at % page_len, axis=0,
+                    mode="clip")                          # [S, K, row]
+    c, r = rows[..., :spec.kv_rank], rows[..., spec.kv_rank:spec.row]
+    w_kv = p["kv_b"].reshape(spec.kv_rank, spec.heads, -1)
+    q_lat = jnp.einsum("shn,chn->shc", q_nope.astype(w_kv.dtype),
+                       w_kv[..., :spec.nope_dim],
+                       preferred_element_type=jnp.float32)
+    scores = jnp.einsum("shc,skc->shk", q_lat.astype(dtype), c,
+                        preferred_element_type=jnp.float32) \
+        + jnp.einsum("shr,skr->shk", q_rope.astype(dtype), r,
+                     preferred_element_type=jnp.float32)
+    scores = jnp.where(real[:, None], scores, _NEG)
+    top = jnp.max(scores, axis=-1, keepdims=True)
+    probs = jnp.where(real[:, None], jnp.exp(scores - top), 0.0)
+    probs = probs / jnp.maximum(jnp.sum(probs, axis=-1, keepdims=True),
+                                1e-30)
+    o_lat = jnp.einsum("shk,skc->shc", probs.astype(dtype), c,
+                       preferred_element_type=jnp.float32)
+    o = jnp.einsum("shc,chv->shv", o_lat.astype(w_kv.dtype),
+                   w_kv[..., spec.nope_dim:],
+                   preferred_element_type=jnp.float32)
+    return o.reshape(s, -1)
+
+
+# -- prefill: a chunk of one stream's prompt ------------------------------------------
+
+def _sortable(x: jax.Array) -> jax.Array:
+    """Float32 scores as uint32 in the same order; every real score maps
+    above 0, which stands for a position that may not be seen."""
+    bits = lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+@jax.named_scope("dsa_index")
+def index_chunk(spec: LatentSpec, q: jax.Array, w: jax.Array,
+                index_pool: jax.Array, row: jax.Array, start) -> jax.Array:
+    """Index scores of the chunk's queries (positions ``start ..
+    start+T-1``, their keys written) against the stream's positions so
+    far, as sortable bits ``[T, L]`` uint32 (:func:`_sortable`; 0 where
+    ``s > t``). Tiles of pages up to the chunk's end."""
+    t = q.shape[0]
+    page_len = index_pool.shape[1]
+    tp = _tile_pages(spec.chunk_tile, page_len, row.shape[0])
+    tile = tp * page_len
+    width = _bits_width(spec, page_len, row.shape[0])
+    qc = q.astype(index_pool.dtype)
+    at_t = start + jnp.arange(t)
+
+    def body(i, bits):
+        keys = jnp.take(index_pool, _pages_of(row[None], i, tp)[0], axis=0,
+                        mode="clip").reshape(tile, -1)
+        dots = jnp.einsum("thd,nd->thn", qc, keys,
+                          preferred_element_type=jnp.float32)
+        got = jnp.einsum("thn,th->tn", jax.nn.relu(dots), w)
+        pos = i * tile + jnp.arange(tile)
+        got = jnp.where(pos[None] <= at_t[:, None], _sortable(got),
+                        jnp.uint32(0))
+        return lax.dynamic_update_slice(bits, got, (0, i * tile))
+    return lax.fori_loop(0, (start + t - 1) // tile + 1, body,
+                         jnp.zeros((t, width), jnp.uint32))
+
+
+@jax.named_scope("dsa_select")
+def select_chunk(spec: LatentSpec, bits: jax.Array, start
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Each query row's selection as ``(threshold [T] uint32, last [T]
+    int32)``: the row selects ``s`` where ``bits[t, s] > threshold``, or
+    ``bits[t, s] == threshold and s <= last``. The threshold is the row's
+    ``index_topk``-th largest score and ``last`` the position up to which
+    the scores that tie with it are taken, lowest first: the set
+    ``lax.top_k`` returns. Both come from 16-way searches, four bits a
+    pass over the tiles of scores up to the chunk's end; a row that sees
+    fewer than ``index_topk`` positions gets threshold 0, which selects
+    all it sees (the caller masks ``s > t``)."""
+    t, width = bits.shape
+    # whole tiles of the buffer, the widest of at most 8 scoring tiles
+    tile = next(width // m for m in range(1, width + 1)
+                if width % m == 0 and width // m <= 8 * spec.chunk_tile)
+    n = (start + t - 1) // tile + 1
+    k = spec.index_topk
+    if width >= 1 << 16:
+        raise ValueError(f"a context of {width} positions does not fit the "
+                         f"selection's 16-bit search on the position")
+    steps = jnp.arange(1, 16, dtype=jnp.uint32)[:, None]        # [15, 1]
+
+    def counted(holds):
+        """``[C, T]``: how many positions of each row ``holds(tile of bits
+        [T, tile], their positions [tile])`` ``[C, T, tile]`` is true of."""
+        def body(i, acc):
+            part = lax.dynamic_slice(bits, (0, i * tile), (t, tile))
+            pos = i * tile + jnp.arange(tile, dtype=jnp.int32)
+            return acc + jnp.sum(holds(part, pos), axis=-1, dtype=jnp.int32)
+        return body
+
+    def search(passes, keeps):
+        """The largest value, four bits a pass from the top, of which
+        ``keeps(candidates [15, T]) [15, T]`` still holds; it holds of a
+        value and of every smaller one."""
+        def one(j, found):
+            shift = (4 * (passes - 1 - j)).astype(jnp.uint32)
+            cands = found[None] | (steps << shift)
+            return found | (jnp.sum(keeps(cands), axis=0,
+                                    dtype=jnp.uint32) << shift)
+        return lax.fori_loop(0, passes, one, jnp.zeros((t,), jnp.uint32))
+
+    def at_least_k(cands):
+        body = counted(lambda part, _: part[None] >= cands[:, :, None])
+        return lax.fori_loop(0, n, body,
+                             jnp.zeros(cands.shape, jnp.int32)) >= k
+    threshold = search(8, at_least_k)
+    above = lax.fori_loop(
+        0, n, counted(lambda part, _: (part > threshold[:, None])[None]),
+        jnp.zeros((1, t), jnp.int32))[0]
+    need = k - above        # >= 1: fewer than k lie above the k-th largest
+
+    def too_few(cands):
+        body = counted(lambda part, pos: (part == threshold[:, None])[None]
+                       & (pos[None, None].astype(jnp.uint32)
+                          < cands[:, :, None]))
+        return lax.fori_loop(0, n, body,
+                             jnp.zeros(cands.shape, jnp.int32)) < need
+    return threshold, search(4, too_few).astype(jnp.int32)
+
+
+def _tile_attend_xla(q, k, v, ok):
+    """Softmax attention of ``q [H, T, D]`` over one tile of keys ``k [H,
+    n, D]`` and values ``v [H, n, V]`` under the mask ``ok [T, n]``: ``(o
+    [H, T, V] float32, normalised over the tile; lse [H, T]``, the log of
+    the tile's sum of exponentials, ``_NEG`` for a row that sees nothing
+    here)``. The XLA form: the scores of the whole tile at once."""
+    scores = jnp.einsum("htd,hnd->htn", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = jnp.where(ok[None], scores, _NEG)
+    top = jnp.max(scores, axis=-1)
+    probs = jnp.where(ok[None], jnp.exp(scores - top[..., None]), 0.0)
+    total = jnp.sum(probs, axis=-1)
+    o = jnp.einsum("htn,hnv->htv", probs.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return o / jnp.maximum(total, 1e-30)[..., None], \
+        jnp.where(total > 0, top + jnp.log(jnp.maximum(total, 1e-30)), _NEG)
+
+
+def _tile_kernel(q_ref, k_ref, v_ref, ok_ref, o_ref, lse_ref, acc_ref,
+                 m_ref, l_ref):
+    """One head's block of query rows against the tile's blocks of keys in
+    turn (the grid's last axis): scores on the MXU, the mask, a running
+    softmax in float32 scratch. Scores, probabilities and the mask's
+    blocks never leave VMEM."""
+    from jax.experimental import pallas as pl
+
+    ki = pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    ok = ok_ref[...] != 0
+    s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)     # [bq, bk]
+    s = jnp.where(ok, s, _NEG)
+    m_prev = m_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[:, :1] = l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[:, :1] = m_new
+    acc_ref[:] = acc_ref[:] * corr + lax.dot_general(
+        p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finish():
+        total = l_ref[:, :1]
+        o_ref[0] = acc_ref[:] / jnp.maximum(total, 1e-30)
+        lse_ref[0] = jnp.where(
+            total > 0, m_ref[:, :1] + jnp.log(jnp.maximum(total, 1e-30)),
+            _NEG)
+
+
+#: the kernel's blocks: query rows a program, keys a step of its last axis
+TILE_KERNEL_BLOCKS = (512, 1024)
+
+
+def _tile_kernel_rule(q, k, v) -> Optional[str]:
+    """Why the chunk's tiles cannot run :func:`_tile_attend_kernel` (None:
+    they can): the XLA form then runs, and on the TPU that is noted once."""
+    if dispatch.partitioned():
+        return ("the chunk program spans several devices, and a Mosaic "
+                "kernel cannot be partitioned automatically")
+    (_, t, d), (_, n, dv) = q.shape, v.shape
+    bq, bk = TILE_KERNEL_BLOCKS
+    if t % min(bq, t) or n % min(bk, n) or min(bq, t) % 32 \
+            or min(bk, n) % 128 or d % 128 or dv % 128:
+        return (f"queries [{t}, {d}] over a tile of [{n}, {dv}] are no "
+                f"whole blocks of ({bq}, {bk}) rows and 128 lanes")
+    return None
+
+
+def _tile_attend_kernel(q, k, v, ok):
+    """:func:`_tile_attend_xla`'s result from a pallas kernel: a grid of
+    (head, block of query rows, block of keys), the last axis carrying the
+    running softmax. ``ok`` rides as int8, one block for all heads."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (h, t, d), (_, n, dv) = q.shape, v.shape
+    bq, bk = min(TILE_KERNEL_BLOCKS[0], t), min(TILE_KERNEL_BLOCKS[1], n)
+    o, lse = pl.pallas_call(
+        _tile_kernel,
+        out_shape=(jax.ShapeDtypeStruct((h, t, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((h, t, 1), jnp.float32)),
+        grid=(h, t // bq, n // bk),
+        in_specs=[
+            pl.BlockSpec((1, bq, d), lambda a, i, j: (a, i, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, bk, d), lambda a, i, j: (a, j, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, bk, dv), lambda a, i, j: (a, j, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((bq, bk), lambda a, i, j: (i, j),
+                         memory_space=pltpu.VMEM)],
+        out_specs=(
+            pl.BlockSpec((1, bq, dv), lambda a, i, j: (a, i, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, bq, 1), lambda a, i, j: (a, i, 0),
+                         memory_space=pltpu.VMEM)),
+        scratch_shapes=[pltpu.VMEM((bq, dv), jnp.float32),
+                        pltpu.VMEM((bq, 128), jnp.float32),
+                        pltpu.VMEM((bq, 128), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+    )(q, k, v, ok.astype(jnp.int8))
+    return o, lse[..., 0]
+
+
+def _tile_attend(q, k, v, ok):
+    """One tile's attention: the kernel on one TPU chip where its rule
+    holds, otherwise the XLA form (off the TPU it is the implementation
+    and not a fallback: nothing is noted)."""
+    if not dispatch.on_tpu():
+        return _tile_attend_xla(q, k, v, ok)
+    rule = _tile_kernel_rule(q, k, v)
+    if rule is not None:
+        dispatch.note_fallback("latent_chunk_attend", rule)
+        return _tile_attend_xla(q, k, v, ok)
+    return _tile_attend_kernel(q, k, v, ok)
+
+
+@jax.named_scope("mla_attend")
+def attend_chunk(spec: LatentSpec, p, q_nope: jax.Array, q_rope: jax.Array,
+                 latent_pool: jax.Array, row: jax.Array, start,
+                 bits: jax.Array, threshold: jax.Array, last: jax.Array
+                 ) -> jax.Array:
+    """The plain form for the chunk's queries, each over its own selection:
+    a tile of the stream's pages at a time is expanded through ``kv_b`` to
+    the heads' keys (``[k_nope_h ; k_rope]``) and values, attended under
+    the mask ``s in S_t`` (``bits``, ``threshold``, ``last`` of
+    :func:`select_chunk`; ``s <= t``) by :func:`_tile_attend`, and the
+    tiles' results are merged by their logs of sums. Returns ``[T, H *
+    v_dim]`` float32."""
+    t = q_nope.shape[0]
+    page_len = latent_pool.shape[1]
+    dtype = latent_pool.dtype
+    tp = _tile_pages(spec.attend_tile, page_len, row.shape[0])
+    tile = tp * page_len
+    q = jnp.concatenate([q_nope, q_rope], axis=-1).astype(dtype)
+    q = jnp.moveaxis(q, 0, 1)                                # [H, T, qk]
+    w_kv = p["kv_b"].reshape(spec.kv_rank, spec.heads, -1)
+    at_t = start + jnp.arange(t)
+
+    def body(i, carry):
+        lse, acc = carry
+        rows = jnp.take(latent_pool, _pages_of(row[None], i, tp)[0], axis=0,
+                        mode="clip").reshape(tile, -1)
+        c, r = rows[:, :spec.kv_rank], rows[:, spec.kv_rank:spec.row]
+        kv = jnp.einsum("nc,chd->hnd", c, w_kv,
+                        preferred_element_type=jnp.float32).astype(dtype)
+        k = jnp.concatenate(
+            [kv[..., :spec.nope_dim],
+             jnp.broadcast_to(r[None], (spec.heads,) + r.shape)], axis=-1)
+        pos = i * tile + jnp.arange(tile)
+        part = lax.dynamic_slice(bits, (0, i * tile), (t, tile))
+        ok = (part > threshold[:, None]) | (
+            (part == threshold[:, None]) & (pos[None] <= last[:, None]))
+        ok &= pos[None] <= at_t[:, None]
+        o, new = _tile_attend(q, k, kv[..., spec.nope_dim:], ok)
+        both = jnp.logaddexp(lse, new)
+        acc = acc * jnp.exp(lse - both)[..., None] \
+            + o * jnp.exp(new - both)[..., None]
+        return both, acc
+
+    init = (jnp.full((spec.heads, t), _NEG, jnp.float32),
+            jnp.zeros((spec.heads, t, spec.v_dim), jnp.float32))
+    _, acc = lax.fori_loop(0, (start + t - 1) // tile + 1, body, init)
+    return jnp.moveaxis(acc, 0, 1).reshape(t, -1)

@@ -187,6 +187,12 @@ _M_SPARSE_READ = _metrics.histogram(
     "Positions that a sparse-attention layer's gather read for one stream "
     "in one decode step, as the step program counted them: one "
     "observation a step.", labels=("server",))
+_M_DSA_SCORED = _metrics.histogram(
+    "serving.dsa_positions_scored",
+    "Positions that a latent layer's indexer scored for one live stream in "
+    "one decode step (the stream's context), the mean over the active "
+    "slots and the layers, as the step program counted them: one "
+    "observation a step.", labels=("server",))
 _M_PAGES_READ = _metrics.histogram(
     "serving.paged_pages_read",
     "KV pages that the decode step's attention read for all slots in one "
@@ -1859,6 +1865,7 @@ class GenerativeServing:
             for kind in ("full", "window")}
         self._m_window_released = _M_WINDOW_RELEASED.labels(
             server=self.metrics_label)
+        self._m_dsa_scored = _M_DSA_SCORED.labels(server=self.metrics_label)
         self._m_moe_touched = _M_MOE_TOUCHED.labels(
             server=self.metrics_label)
         self._m_moe_load = _M_MOE_LOAD.labels(server=self.metrics_label)
@@ -1868,6 +1875,7 @@ class GenerativeServing:
         # names it gives (``LayeredDecoder.step_stats``)
         self._step_observers = {
             "sparse_positions_read": self._m_sparse_read.observe,
+            "dsa_positions_scored": self._m_dsa_scored.observe,
             "moe_experts_touched": self._m_moe_touched.observe,
             "moe_expert_load": self._m_moe_load.observe,
             "moe_assignments": self._m_moe_assignments.inc}
@@ -3229,13 +3237,16 @@ class GenerativeServing:
                 int(np.sum(self._active_host)) + len(self._prefilling)
                 if self._recurrent else None),
             "sparse_positions_read": _mean_of(self._m_sparse_read),
+            "dsa_positions_scored": _mean_of(self._m_dsa_scored),
             "kv_pages_in_use": {
                 "full": self.num_pages - 1 - len(self._free_pages),
                 "window": (self._window.in_use()
                            if self._window is not None else None)},
             "window_pages_released_total": int(
                 self._m_window_released.value()),
-            "moe_experts_touched": _mean_of(self._m_moe_touched),
+            # three digits: a reader takes the mean of a stretch of the
+            # run from two snapshots' means times their counts
+            "moe_experts_touched": _mean_of(self._m_moe_touched, 3),
             "moe_expert_load": _mean_of(self._m_moe_load, 3),
             "moe_assignments_total": int(self._m_moe_assignments.value()),
             "paged_pages_read": _mean_of(self._m_pages_read),

@@ -566,6 +566,188 @@ def test_smallthinker_programs_keep_pools_and_expert_tables_in_place(
         assert not gathered, gathered[:4]
 
 
+_LONGCTX = dict(slots=12, pages=6241, page_len=64, width=520)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "chunk_2048",
+                                     "chunk_512"])
+def test_glm5_programs_keep_pools_and_expert_tables_in_place(
+        v5e, tmp_path, monkeypatch, program):
+    """GLM-5's decode step and prefill chunk as the server declares them,
+    compiled for the chip at the benchmark's sizes (1 dense + 4 expert
+    layers at the published widths, 16 of 256 experts held, 6,241 pages of
+    64 in a latent pool ``[P, 64, 640]`` (rows of 576 in whole tiles of 128
+    lanes) and an index-key pool ``[P, 64, 128]`` a layer, 12 slots): every pool leaf is aliased to an output, no
+    copy of a whole pool or of an expert table is left in the program, the
+    grouped products are the compiler's own kernel, and the program with
+    its temporaries fits the chip beside the weights. A chunk's attention
+    tiles are the pallas kernel (one Mosaic call a layer whose attention
+    feeds a later layer) and none of the XLA form's scores of a whole
+    tile."""
+    import json
+    import pathlib
+    import re
+    from analytics_zoo_tpu.ops import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    monkeypatch.setattr(dispatch, "_seen", set())
+    from analytics_zoo_tpu.capture.decoder import DecoderSpec, LayeredDecoder
+    from analytics_zoo_tpu.serving import GenerativeServing, ServingConfig
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cfg = json.loads((root / "perfbench" / "configs"
+                      / "glm_5.json").read_text())
+    lm = LayeredDecoder(DecoderSpec.from_config(cfg, cfg["n_positions"]))
+    lm.set_params(jax.eval_shape(lm.init_params))
+    slots = _LONGCTX["slots"]
+    srv = GenerativeServing(ServingConfig(
+        data_src=f"dir://{tmp_path}/q", slots=2, kv_pages=2,
+        kv_page_len=_LONGCTX["page_len"]), lm)
+
+    def described(tree):
+        return jax.tree_util.tree_map(lambda a: v5e(a.shape, a.dtype), tree)
+
+    params = described(srv._params)
+    state = described(jax.eval_shape(
+        lambda: {"length": jnp.zeros((slots,), I32),
+                 "active": jnp.zeros((slots,), bool)}))
+    pools = described(jax.eval_shape(lambda: lm.init_paged_caches(
+        _LONGCTX["pages"], _LONGCTX["page_len"], slots=slots)))
+    assert [(p["latent"].shape, p["index"].shape) for p in pools] \
+        == [((6241, 64, 640), (6241, 64, 128))] * 5
+    table = v5e((slots, _LONGCTX["width"]), I32)
+    row, scalar = v5e((_LONGCTX["width"],), I32), v5e((), I32)
+    if program == "decode_step":
+        lowered = srv._step_fn.lower(params, v5e((slots,), I32),
+                                     v5e((slots,), I32),
+                                     v5e((slots, 2), jnp.uint32), state,
+                                     table, pools)
+    else:
+        width = int(program.split("_")[1])
+        lowered = srv._prefill_chunk_fn.lower(
+            params, v5e((1, width), I32), pools, state, table, row, scalar,
+            scalar, scalar, scalar, v5e((), jnp.bool_))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    leaves = {int(n) for n in re.findall(
+        r"%caches_\S+ = \S+ parameter\((\d+)\)", entry)}
+    assert len(leaves) == len(jax.tree_util.tree_leaves(pools)) == 10
+    aliased = {int(n) for n in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])}
+    assert leaves <= aliased, sorted(leaves - aliased)
+    for dims in ("6241,64,640", "6241,64,128", "16,6144,2048",
+                 "16,2048,6144"):
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(r"= \w+\[%s\]\S* copy\(" % dims, line)]
+        assert not copies, copies
+    assert "ragged-dot" in text
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    assert held < 14.5e9, held   # of the chip's 16 GB
+    assert dispatch.fallbacks_seen() == []
+    calls = [name for name, op_name in _mosaic_calls(text)
+             if "ragged" not in name]
+    if program == "decode_step":
+        assert not calls, calls
+    else:
+        # the last layer's attention feeds nothing in a chunk (no logits
+        # come of it) and the compiler drops it: four layers' kernels
+        assert len(calls) == 4, calls
+        whole = [line.strip()[:160] for line in text.splitlines()
+                 if re.search(r"f32\[64,%s,2048\]" % width, line)]
+        assert not whole, whole[:4]
+
+
+# the lowered text of the two older layered decoders' programs at a tiny size,
+# hashed on PR 32's tree (abe2836); see the test below
+_LOWERED = {
+    "smallthinker.step": "e316ae3972f7144a",
+    "smallthinker.chunk_8": "4b95ab126390b796",
+    "smallthinker.chunk_32": "cc3bb262807dcd68",
+    "sala.step": "97f003074592f864",
+    "sala.chunk_8": "6fcd59c24b75e7b5",
+    "sala.chunk_32": "ac903f110dd545a1"}
+
+
+@pytest.mark.parametrize("model", ["smallthinker", "sala"])
+def test_older_decoders_lower_to_the_programs_they_were(tmp_path, model):
+    """MiniCPM-SALA's and SmallThinker's decode step and chunk programs, at a
+    tiny size on the CPU, lower to the text they lowered to before GLM-5's
+    layers came into ``capture/decoder.py`` and ``ops/moe.py`` (PR 33): the
+    text with its scope names is the compile cache's key
+    (``common/context.py wire_compilation_cache`` keeps source lines out of
+    it), so equal text means that neither model's cell compiles anything
+    anew and both keep their scopes. A PR that changes these programs on
+    purpose says so and pins the new hashes; one that moved an operation
+    without meaning to finds out here and not in ``setup_s``."""
+    import hashlib
+    import json
+    import pathlib
+    from analytics_zoo_tpu.common import context
+    from analytics_zoo_tpu.capture.decoder import DecoderSpec, LayeredDecoder
+    from analytics_zoo_tpu.serving import GenerativeServing, ServingConfig
+    context.wire_compilation_cache()
+    root = pathlib.Path(__file__).resolve().parents[1]
+    if model == "smallthinker":
+        cfg = json.loads((root / "perfbench" / "configs"
+                          / "smallthinker_21b.json").read_text())
+        cfg.update(
+            vocab_size=97, hidden_size=64, moe_ffn_hidden_size=32,
+            head_dim=16, num_attention_heads=4, num_key_value_heads=2,
+            moe_num_primary_experts=8, moe_num_active_primary_experts=2,
+            sliding_window_size=32, num_hidden_layers=4,
+            sliding_window_layout=[0, 1, 1, 1], rope_layout=[0, 1, 1, 1],
+            n_positions=128, param_dtype="float32")
+        spec = DecoderSpec.from_config(cfg, 128, page_len=8)
+    else:
+        cfg = json.loads((root / "perfbench" / "configs"
+                          / "minicpm_sala.json").read_text())
+        cfg.update(
+            vocab_size=97, hidden_size=64, intermediate_size=96, head_dim=16,
+            num_attention_heads=4, num_key_value_heads=2, lightning_nh=4,
+            lightning_head_dim=16, num_hidden_layers=4,
+            mixer_types=["minicpm4", "lightning-attn", "lightning-attn",
+                         "minicpm4"],
+            n_positions=256, param_dtype="float32", dim_model_base=32,
+            sparse_attention={"kernel_size": 4, "kernel_stride": 2,
+                              "block_size": 8, "init_blocks": 1,
+                              "window_size": 16, "topk": 2, "dense_len": 64})
+        spec = DecoderSpec.from_config(cfg, 256)
+    lm = LayeredDecoder(spec, prefill_chunk=32)
+    lm.set_params(jax.eval_shape(lm.init_params))
+    slots = 3
+    srv = GenerativeServing(ServingConfig(
+        data_src=f"dir://{tmp_path}/q", slots=slots, kv_pages=None,
+        kv_page_len=lm.page_len), lm)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    params, state = described(srv._params), described(srv._state)
+    pools = described(srv._caches)
+    table, row = sds((slots, srv._table_w), I32), sds((srv._table_w,), I32)
+    scalar = sds((), I32)
+    if model == "smallthinker":
+        table, row = (table, table), (row, row)
+
+    def hashed(lowered):
+        return hashlib.sha256(
+            lowered.as_text(debug_info=True).encode()).hexdigest()[:16]
+    got = {f"{model}.step": hashed(srv._step_fn.lower(
+        params, sds((slots,), I32), sds((slots,), I32),
+        sds((slots, 2), jnp.uint32), state, table, pools))}
+    for width in (8, 32):
+        got[f"{model}.chunk_{width}"] = hashed(srv._prefill_chunk_fn.lower(
+            params, sds((1, width), I32), pools, state,
+            table[0] if model == "smallthinker" else table, row, scalar,
+            scalar, scalar, scalar, sds((), jnp.bool_)))
+    assert got == {k: v for k, v in _LOWERED.items() if k in got}
+
+
 # -- which branch ran: the reason strings, on the CPU -------------------------
 
 def test_fallback_reasons_are_logged_once(monkeypatch, caplog):
