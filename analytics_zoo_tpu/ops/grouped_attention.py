@@ -10,13 +10,20 @@ query, so a window layer never touches what lies behind its window and a
 full layer stops at the longest live stream. Scores are float32; the
 products take the pool's dtype with float32 accumulation.
 
-On one TPU chip the decode step reads each stream's pages from the pool in
-place (:func:`_attend_step_kernel`, the kernel of ``ops/decode.py
-paged_decode_context`` for grouped-query heads): from the first page its
-window touches to the page of its length and no other, so the step's cost
-follows the live keys. Elsewhere (off the TPU, a program over several
-devices, pages that are no whole tiles) the XLA form above runs, and on the
-TPU that is noted once with the rule.
+On one TPU chip both read the stream's pages from the pool in place. The
+decode step (:func:`_attend_step_kernel`, the kernel of ``ops/decode.py
+paged_decode_context`` for grouped-query heads) reads from the first page a
+slot's window touches to the page of its length and no other, so the step's
+cost follows the live keys. The chunk (:func:`_attend_chunk_kernel`) takes
+a block of query rows of one key/value head at a time against the key
+blocks those rows may see, from the block of the first key the block's
+first row sees to the block of its last row: scores, probabilities and the
+rescaled accumulator stay in VMEM, and a key block that the causal or the
+window mask empties for the whole block of rows is neither fetched nor
+computed. Elsewhere (off the TPU, a program over several devices, pages,
+heads or chunks that are no whole tiles and blocks) the XLA form above
+runs, and on the TPU that is noted once with the rule
+(``grouped_paged_decode``, ``grouped_chunk_attend``).
 
 The caller has written the queries' own K and V before it reads. Scopes:
 ``attn_full`` and ``attn_window`` (docs/observability.md)."""
@@ -261,12 +268,198 @@ def attend_step(q: jax.Array, cache, table: jax.Array, lengths: jax.Array,
                        n_tiles, window, tile_pages)[:, 0]
 
 
+#: the chunk kernel's blocks: query rows a program, keys a step of its loop
+#: (whole pages; positions that are multiples of it bound the blocks)
+CHUNK_KERNEL_BLOCKS = (512, 512)
+
+
+def _chunk_kernel_rule(q, cache, row) -> Optional[str]:
+    """Why a chunk cannot run :func:`_attend_chunk_kernel` (None: it can):
+    ``ops/decode.py``'s rule for reading a pool in place, and queries that
+    are whole blocks of rows and of 128 lanes."""
+    rule = _paged_decode_rule(cache, row)
+    if rule is not None:
+        return rule
+    t, _, _, d = q.shape
+    page_len = cache["k"].shape[1]
+    rows, keys = min(CHUNK_KERNEL_BLOCKS[0], t), CHUNK_KERNEL_BLOCKS[1]
+    if d % 128 or t % rows or rows % (32 // cache["k"].dtype.itemsize) \
+            or keys % page_len:
+        return (f"queries [{t}, {d}] over pages of {page_len} are no whole "
+                f"blocks of {CHUNK_KERNEL_BLOCKS} rows and keys and 128 "
+                f"lanes")
+    return None
+
+
+def _chunk_kernel(start_ref, row_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                  v_buf, sem, qt_ref, acc_ref, m_ref, l_ref, *,
+                  window: Optional[int]):
+    """One key/value head's block of query rows (positions ``low ..
+    high``) against the key blocks it may see, in turn: from the block of
+    the first key the block's first row sees (0 in a full layer) to the
+    block of ``high``, and no other. A key block's pages come from the pool
+    in HBM through ``row`` (one DMA a page for K and one for V, this
+    head's ``D`` lanes of the stored row; the next block's are started
+    before this one's are waited for) and serve the head's query heads
+    (each ``D`` lanes of ``q_ref``) in turn.
+
+    Scores are taken keys by rows (``K q^T``, the queries transposed once a
+    program), so that the running softmax's maxima and sums over the keys
+    are sums of whole registers and its statistics lie along the lanes:
+    the other way round, a reduction along the lanes for every row of
+    every block took two fifths of the kernel's time (PERF.md, PR 34).
+    The accumulator is ``V^T p`` and is transposed back at the end. A block
+    that every row sees whole skips the mask; elsewhere the mask comes
+    from the positions. Scores and probabilities never leave VMEM."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    head, block = pl.program_id(0), pl.program_id(1)
+    rows = q_ref.shape[0]
+    _, pages, page_len, d = k_buf.shape
+    groups, span, width = m_ref.shape[0], pages * page_len, row_ref.shape[0]
+    lanes = pl.ds(pl.multiple_of(head * d, d), d)
+    heads = [slice(g * d, (g + 1) * d) for g in range(groups)]
+    low = start_ref[0] + block * rows
+    high = low + rows - 1
+    first = 0 if window is None \
+        else jnp.maximum(low - window + 1, 0) // span
+    count = high // span + 1 - first
+
+    def each_page(b, buf, act):
+        """``act`` on the copies of key block ``first + b`` into ``buf``
+        (past the table's width: its last page, as the XLA form reads)."""
+        def one(j, carry):
+            page = row_ref[jnp.minimum((first + b) * pages + j, width - 1)]
+            act(pltpu.make_async_copy(k_hbm.at[page, :, lanes],
+                                      k_buf.at[buf, j], sem.at[0, buf]))
+            act(pltpu.make_async_copy(v_hbm.at[page, :, lanes],
+                                      v_buf.at[buf, j], sem.at[1, buf]))
+            return carry
+        lax.fori_loop(0, pages, one, 0)
+
+    each_page(0, 0, lambda dma: dma.start())
+    for own in heads:
+        qt_ref[own, :] = q_ref[:, own].T.astype(qt_ref.dtype)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def attend(buf, seen):
+        k = k_buf[buf].reshape(span, d)
+        v = v_buf[buf].reshape(span, d)
+
+        def head(g, own):
+            sc = jnp.dot(k, qt_ref[own, :],
+                         preferred_element_type=jnp.float32)  # [span, rows]
+            if seen is not None:
+                sc = jnp.where(seen, sc, _NEG)
+            m = m_ref[g]
+            m_new = jnp.maximum(m, jnp.max(sc, axis=0, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            if seen is not None:
+                p = jnp.where(seen, p, 0.0)
+            corr = jnp.exp(m - m_new)
+            l_ref[g] = l_ref[g] * corr + jnp.sum(p, axis=0, keepdims=True)
+            m_ref[g] = m_new
+            acc_ref[own, :] = acc_ref[own, :] * corr + lax.dot_general(
+                v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)           # [d, rows]
+
+        if seen is None:
+            for g, own in enumerate(heads):
+                head(g, own)
+        else:
+            # a masked block comes once or twice a program: a loop over
+            # the heads is a tenth slower there and a third of the
+            # kernel's code (the chunk programs hold 21 such kernels)
+            def one(g, carry):
+                head(g, pl.ds(pl.multiple_of(g * d, d), d))
+                return carry
+            lax.fori_loop(0, groups, one, 0)
+
+    def step(b, buf):
+        @pl.when(b + 1 < count)
+        def _next_block():
+            each_page(b + 1, 1 - buf, lambda dma: dma.start())
+
+        each_page(b, buf, lambda dma: dma.wait())
+        base = (first + b) * span
+        whole = base + span - 1 <= low
+        if window is not None:
+            whole &= base > high - window
+
+        @pl.when(whole)
+        def _every_row_sees_every_key():
+            attend(buf, None)
+
+        @pl.when(jnp.logical_not(whole))
+        def _masked():
+            pos = base + lax.broadcasted_iota(jnp.int32, (span, rows), 0)
+            at = low + lax.broadcasted_iota(jnp.int32, (span, rows), 1)
+            seen = pos <= at
+            if window is not None:
+                seen &= pos > at - window
+            attend(buf, seen)
+        return 1 - buf
+
+    lax.fori_loop(0, count, step, 0)
+    for g, own in enumerate(heads):
+        o_ref[:, own] = (acc_ref[own, :] / jnp.maximum(l_ref[g], 1e-30)).T
+
+
+@functools.partial(jax.jit, static_argnames=("window", "blocks"))
+def _attend_chunk_kernel(q, k_pool, v_pool, row, start, window, blocks):
+    """:func:`attend_chunk` with the pool read in place and the scores kept
+    in VMEM: a grid of (key/value head, block of ``blocks[0]`` query rows),
+    each program walking its own key blocks of ``blocks[1]`` positions.
+    Jitted on its own with the scope innermost, as
+    :func:`_attend_step_kernel` is."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    with _scope(window):
+        t, kv, g, d = q.shape
+        _, page_len, _ = k_pool.shape
+        rows, pages = min(blocks[0], t), blocks[1] // page_len
+        heads = pl.BlockSpec((rows, g * d), lambda h, i, *_: (i, h),
+                             memory_space=pltpu.VMEM)
+        buf = pltpu.VMEM((2, pages, page_len, d), k_pool.dtype)
+        stat = pltpu.VMEM((g, 1, rows), jnp.float32)
+        out = pl.pallas_call(
+            functools.partial(_chunk_kernel, window=window),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(kv, t // rows),
+                in_specs=[heads, pl.BlockSpec(memory_space=pl.ANY),
+                          pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=heads,
+                scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+                                pltpu.VMEM((g * d, rows), k_pool.dtype),
+                                pltpu.VMEM((g * d, rows), jnp.float32),
+                                stat, stat]),
+            out_shape=jax.ShapeDtypeStruct((t, kv * g * d), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel"),
+                vmem_limit_bytes=64 * 1024 * 1024),
+        )(jnp.asarray(start, jnp.int32).reshape(1), row.astype(jnp.int32),
+          q.reshape(t, kv * g * d), k_pool, v_pool)
+        return out.reshape(t, kv, g, d)
+
+
 def attend_chunk(q: jax.Array, cache, row: jax.Array, start,
                  window: Optional[int] = None,
                  tile_pages: int = 8) -> jax.Array:
     """Prefill: a chunk's queries (``q [T, KV, G, D]`` scaled, at positions
     ``start ..``) over the stream's pages ``row [W]``, the chunk's own
-    among them. Returns ``[T, KV, G, D]`` float32."""
+    among them; on one TPU chip the kernel, which visits for each block of
+    rows the key blocks it may see. Returns ``[T, KV, G, D]`` float32."""
+    if dispatch.on_tpu():
+        rule = _chunk_kernel_rule(q, cache, row)
+        if rule is None:
+            return _attend_chunk_kernel(q, cache["k"], cache["v"], row,
+                                        start, window, CHUNK_KERNEL_BLOCKS)
+        dispatch.note_fallback("grouped_chunk_attend", rule)
     with _scope(window):
         page_len = cache["k"].shape[1]
         t = start + jnp.arange(q.shape[0], dtype=jnp.int32)
