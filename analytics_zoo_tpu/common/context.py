@@ -93,14 +93,17 @@ def _on_compile_event(event: str, **_) -> None:
         counter.inc()
 
 
-def _on_compile_duration(event: str, seconds: float, **_) -> None:
+def _on_compile_duration(event: str, seconds: float, **more) -> None:
     if event != _BACKEND_COMPILE:
         return
     _M_BACKEND_COMPILE.observe(seconds)
     if _utils.span_hooks:
-        # JAX reports a duration once it is over: the span ends now
+        # JAX reports a duration once it is over: the span ends now, on
+        # the compiling thread, so the block that is open there (a join,
+        # a decode dispatch) is its parent; what JAX says beside the
+        # duration (``fun_name``, the program's name) rides as its args
         _utils.offer_span("compile.backend", time.perf_counter() - seconds,
-                          seconds)
+                          seconds, args=tuple(more.items()))
 
 
 #: where the persistent compilation cache lives when nothing outside the

@@ -110,6 +110,11 @@ _M_QUEUE_WAIT = _metrics.histogram(
     "Enqueue-to-claim wait of generative requests (client-stamped "
     "enqueue_t): the part of time to first token spent in the queue.",
     labels=("server",))
+_M_PREFILL_WAIT = _metrics.histogram(
+    "serving.prefill_wait_seconds",
+    "Claim-to-first-chunk wait of a prompt that is prefilled in chunks: "
+    "its turn comes behind the chunks of the prompts claimed before it "
+    "and the decode steps between them.", labels=("server",))
 _M_TOKENS = _metrics.counter(
     "serving.tokens_total",
     "Tokens decoded across all generative streams.", labels=("server",))
@@ -1378,8 +1383,9 @@ class _ResultPublisher:
     def _land(self, uri: str, value: Dict[str, Any], terminal: bool,
               folded: float, first: Optional[float]) -> None:
         srv = self._srv
+        request = srv._request_of(uri)
         try:
-            with time_it("serve.put_result"):
+            with time_it("serve.put_result", request=request):
                 srv.queue.put_result(uri, value)
         except Exception:
             logger.exception("posting result for %s failed" if terminal
@@ -1387,10 +1393,12 @@ class _ResultPublisher:
         else:
             if _utils.span_hooks:
                 _utils.offer_span("serve.publish_lag", folded,
-                                  time.perf_counter() - folded)
+                                  time.perf_counter() - folded,
+                                  request=request, life=True)
         if first is not None and _utils.span_hooks:
             _utils.offer_span("serve.first_token", first,
-                              time.perf_counter() - first)
+                              time.perf_counter() - first,
+                              request=request, life=True)
         if terminal:
             srv._settle(uri)
 
@@ -1886,6 +1894,8 @@ class GenerativeServing:
         self._m_claim_age = _M_CLAIM_AGE.labels(server=self.metrics_label)
         self._m_ttft = _M_TTFT.labels(server=self.metrics_label)
         self._m_queue_wait = _M_QUEUE_WAIT.labels(server=self.metrics_label)
+        self._m_prefill_wait = _M_PREFILL_WAIT.labels(
+            server=self.metrics_label)
         self._m_tokens = _M_TOKENS.labels(server=self.metrics_label)
         self._m_slots = _M_SLOTS.labels(server=self.metrics_label)
         self._m_pages_free = _M_PAGES_FREE.labels(server=self.metrics_label)
@@ -1967,6 +1977,12 @@ class GenerativeServing:
             t0, flow_id = meta
             self._m_latency.observe(max(wall_clock() - t0, 0.0))
             _trace.flow_point(flow_id, "serving.result", "f")
+
+    def _request_of(self, uri: str):
+        """What the spans of one claimed request share as their
+        ``request``: the ``trace_id`` its client stamped, else its uri."""
+        meta = self._meta.get(uri)
+        return uri if meta is None or meta[1] is None else meta[1]
 
     def _end(self, stream: _Stream, value: Dict[str, Any], why: str,
              counter: Optional[str] = None, folded: Optional[float] = None,
@@ -2329,6 +2345,7 @@ class GenerativeServing:
         fed = t - 1             # positions prefilled before decode starts
         tb = (prefill_bucket(fed - plen, self.lm.max_len)
               if fed > plen else 0)
+        self._join_bucket = tb
         # highest position the stream may WRITE within its allocation:
         # bucket padding past the suffix, the decode budget, and the
         # transient spec_k overshoot all need real (owned) pages
@@ -2404,6 +2421,7 @@ class GenerativeServing:
         so step ``i`` uses the same key an uninterrupted stream would —
         the continuation is token-identical (docs/fleet.md)."""
         cfg = self.config
+        self._join_bucket = 0  # the prefill program's width, once known
         prompt = rec.get("prompt")
         if not prompt:
             self._post_terminal(uri, {"error": "empty prompt"})
@@ -2560,9 +2578,21 @@ class GenerativeServing:
                              count, job["uri"])
             self._fail_active(repr(e), rebuild=True)
             return False
+        if index == 0:
+            # the prompt's wait for its turn: behind the chunks of the
+            # prompts claimed before it and the decode steps between them
+            claimed = self._claim_pc[slot]
+            self._m_prefill_wait.observe(t0 - claimed)
         if _utils.span_hooks:
-            _utils.offer_span("serve.prefill_chunk", t0,
-                              time.perf_counter() - t0)
+            request = self._request_of(job["uri"])
+            if index == 0:
+                _utils.offer_span("serve.prefill_wait", claimed,
+                                  t0 - claimed, request=request, life=True)
+            _utils.offer_span(
+                "serve.prefill_chunk", t0, time.perf_counter() - t0,
+                request=request,
+                args=(("start", start), ("rows", n), ("width", width),
+                      ("index", index), ("count", count)))
         self._count("prefill_chunks")
         self._count("prompt_tokens", n)
         job["next"] += 1
@@ -2640,18 +2670,15 @@ class GenerativeServing:
             self._m_queue_wait.observe(waited)
             if _utils.span_hooks:
                 _utils.offer_span("serve.queue_wait", claim_pc - waited,
-                                  waited)
-        tracing = _trace.tracing()
-        if tracing:
-            for uri, rec in got:
-                _trace.flow_point(rec.get("trace_id"), "serving.claim", "t")
+                                  waited, request=self._request_of(uri),
+                                  life=True)
         for uri, rec in got:
             slot = free.pop(0)
             self._claim_pc[slot] = claim_pc
-            if tracing:
-                _trace.flow_point(rec.get("trace_id"), "serving.join", "t")
-            with time_it("serve.join"):
+            with time_it("serve.join",
+                         request=self._request_of(uri)) as span:
                 joined = self._join(slot, uri, rec, now)
+                span.note("bucket", self._join_bucket)
             if not joined:
                 free.insert(0, slot)
 
@@ -2703,28 +2730,36 @@ class GenerativeServing:
         its slot now, inactive on the device before the next dispatch and
         free for the next request, and its last token finds it through the
         step in flight."""
-        mask = np.zeros(self.slots, bool)
+        ending = []
         for slot, stream in stepped:
             stream.dispatched += 1
             if stream.dispatched >= stream.budget:
+                ending.append((slot, stream))
+        if not ending:
+            return
+        with time_it("serve.evict"):
+            mask = np.zeros(self.slots, bool)
+            for slot, stream in ending:
                 mask[slot] = True
                 self._vacate(slot)
                 self._leaving.append(stream)
-        if mask.any():
             self._evict_slots(mask)
 
-    def _post_tokens(self, nxt: np.ndarray, stepped) -> None:
+    def _post_tokens(self, nxt: np.ndarray, stepped) -> np.ndarray:
         """Fold one step's tokens, one a stream it stepped. A stream that
         has ended since the dispatch (expired, failed, on ``eos_id``) does
-        not get its token; nothing after an eos reaches a record."""
+        not get its token; nothing after an eos reaches a record. Returns
+        :meth:`_fold`'s mask."""
         overrun = sum(stream.ended == "eos" for _, stream in stepped)
-        self._fold((stream, (int(nxt[slot]),)) for slot, stream in stepped
-                   if stream.ended is None)
+        finished = self._fold(
+            (stream, (int(nxt[slot]),)) for slot, stream in stepped
+            if stream.ended is None)
         if overrun:
             self._count("overrun_slot_steps", overrun)
+        return finished
 
     def _post_tokens_spec(self, emitted: np.ndarray,
-                          n_acc: np.ndarray) -> None:
+                          n_acc: np.ndarray) -> np.ndarray:
         """Fold one speculative round's ACCEPTED tokens — the rules of
         :meth:`_fold`, but up to ``spec_k + 1`` tokens land per stream per
         round. The budget clamp and eos truncation are host-side; a stream
@@ -2745,17 +2780,18 @@ class GenerativeServing:
                 if toks:
                     self._next_tokens[i] = toks[-1]
                     yield stream, toks
-        self._fold(accepted())
+        return self._fold(accepted())
 
-    def _fold(self, taken) -> None:
+    def _fold(self, taken) -> np.ndarray:
         """Fold a step's new tokens, ``(stream, tokens)`` each, into the
         streams: TTFT on the first token, a partial result due every
         ``stream_interval`` tokens, terminal value on eos / budget
-        exhaustion (with the slot's eviction where the stream still holds
-        one). The records go to the publisher; no write is waited for
-        here. A stream's claim time rides with the first record that
+        exhaustion. The records go to the publisher; no write is waited
+        for here. A stream's claim time rides with the first record that
         carries a token of it, so that ``serve.first_token`` ends when a
-        client could see one."""
+        client could see one. Returns the mask of the slots whose streams
+        ended here while they still held one: the device's eviction of
+        them is the caller's."""
         now, folded = wall_clock(), time.perf_counter()
         cfg = self.config
         # brownout L1+: coarser partials — every queue write the streamers
@@ -2768,7 +2804,8 @@ class GenerativeServing:
             n_tok += len(toks)
             claimed = None
             if stream.first_t is None:
-                self._first_token_seen(stream, now)
+                stream.first_t = now
+                self._m_ttft.observe(max(now - stream.enqueue_t, 0.0))
                 if _utils.span_hooks:
                     claimed = stream.claim_pc
             have = len(stream.tokens)
@@ -2789,23 +2826,14 @@ class GenerativeServing:
                 stream.streamed = have
             elif claimed is not None:  # no record carries this token
                 _utils.offer_span("serve.first_token", claimed,
-                                  time.perf_counter() - claimed)
+                                  time.perf_counter() - claimed,
+                                  request=self._request_of(stream.uri),
+                                  life=True)
         if due:
             self._publisher.partials(due, folded)
         if n_tok:
             self._m_tokens.inc(n_tok)
-        if finished.any():
-            self._evict_slots(finished)
-
-    def _first_token_seen(self, stream: _Stream, now: float) -> None:
-        """A stream's first decoded token: TTFT, and the flow chain's
-        ``serving.first_token`` point."""
-        stream.first_t = now
-        self._m_ttft.observe(max(now - stream.enqueue_t, 0.0))
-        if _trace.tracing():
-            meta = self._meta.get(stream.uri)
-            if meta is not None:
-                _trace.flow_point(meta[1], "serving.first_token", "t")
+        return finished
 
     def serve_step(self) -> int:
         """One scheduler step: evict expired streams, admit new requests
@@ -2823,12 +2851,13 @@ class GenerativeServing:
         landed when this returns, so the caller reads the streams and the
         result store as the step left them."""
         try:
-            if not _utils.span_hooks:
-                return self._serve_step()
-            t0 = time.perf_counter()
-            stepped = self._serve_step()
-            if stepped:  # an iteration that dispatched a step: parent of the rest
-                _utils.offer_span("serve.step", t0, time.perf_counter() - t0)
+            # the parent of everything the iteration emits; an iteration
+            # that stepped nothing leaves none, and what it emitted stands
+            # at the top
+            with time_it("serve.step", tentative=True) as span:
+                stepped = self._serve_step()
+                if not stepped:
+                    span.drop()
             return stepped
         finally:
             if not self._loop_running:
@@ -2858,18 +2887,20 @@ class GenerativeServing:
             # loop may sleep; a prompt still joining keeps it awake
             return (self._fold_in_flight()
                     or int(chunked or bool(self._prefilling)))
-        if self._window is not None:
-            self._window_advance()
-        stepped = [(i, stream) for i, stream in enumerate(self._streams)
-                   if stream is not None]
-        # fresh arrays a step: the dispatch may read them after it returns
-        tokens = self._next_tokens.copy()
-        keys = np.zeros((self.slots, 2), np.uint32)
-        if self._sampling:
-            for i, stream in stepped:
-                keys[i] = stream.keys[stream.dispatched]
-        step = {"streams": stepped, "t_step": time.perf_counter(),
-                "ahead_from": None}
+        with time_it("serve.prepare"):
+            if self._window is not None:
+                self._window_advance()
+            stepped = [(i, stream) for i, stream in enumerate(self._streams)
+                       if stream is not None]
+            # fresh arrays a step: the dispatch may read them after it
+            # returns
+            tokens = self._next_tokens.copy()
+            keys = np.zeros((self.slots, 2), np.uint32)
+            if self._sampling:
+                for i, stream in stepped:
+                    keys[i] = stream.keys[stream.dispatched]
+            step = {"streams": stepped, "t_step": time.perf_counter(),
+                    "ahead_from": None}
         given = False
         try:
             # chaos site, raised BEFORE the dispatch: the step errors every
@@ -2919,8 +2950,10 @@ class GenerativeServing:
         tokens, then fold them. A fetch that fails has lost the caches the
         step was given: every stream errors and the pools start over."""
         if step["ahead_from"] is not None and _utils.span_hooks:
+            # a stretch of the step's life: it lies over two iterations
             _utils.offer_span("serve.step_ahead", step["ahead_from"],
-                              time.perf_counter() - step["ahead_from"])
+                              time.perf_counter() - step["ahead_from"],
+                              life=True)
         n_active = len(step["streams"])
         try:
             if self._spec:
@@ -2960,9 +2993,12 @@ class GenerativeServing:
             self._m_pages_read.observe(float(read))
         with time_it("serve.post"):
             if self._spec:
-                self._post_tokens_spec(em_host, n_host)
+                finished = self._post_tokens_spec(em_host, n_host)
             else:
-                self._post_tokens(nxt_host, step["streams"])
+                finished = self._post_tokens(nxt_host, step["streams"])
+        if finished.any():  # on eos_id, or a budget the host clamped
+            with time_it("serve.evict"):
+                self._evict_slots(finished)
         return True
 
     # -- lifecycle (mirrors ClusterServing) ----------------------------------
@@ -2974,6 +3010,9 @@ class GenerativeServing:
         self._terminal_state = None
         self._loop_running = True
         self._last_shed_m = -1e18
+        # the loop's lane in every span record, whoever's thread this is
+        thread = threading.current_thread()
+        was, thread.name = thread.name, f"{self.metrics_label}-loop"
         try:
             while (not self._stop.is_set()
                    and not self._handoff_evt.is_set()):
@@ -3001,7 +3040,10 @@ class GenerativeServing:
                 self._publisher.close()
             finally:
                 self._loop_running = False
-            self._maybe_write_health()
+            try:
+                self._maybe_write_health()
+            finally:
+                thread.name = was
 
     def start(self) -> "GenerativeServing":
         ops_alerts.ensure_default()  # no-op unless ops.enabled
@@ -3257,6 +3299,9 @@ class GenerativeServing:
             "queue_wait_ms": {"p50": _pct(self._m_queue_wait, 0.50),
                               "p99": _pct(self._m_queue_wait, 0.99),
                               "window": self._m_queue_wait.count()},
+            "prefill_wait_ms": {"p50": _pct(self._m_prefill_wait, 0.50),
+                                "p99": _pct(self._m_prefill_wait, 0.99),
+                                "window": self._m_prefill_wait.count()},
             "latency_ms": {"p50": _pct(self._m_latency, 0.50),
                            "p99": _pct(self._m_latency, 0.99),
                            "window": self._m_latency.count()},

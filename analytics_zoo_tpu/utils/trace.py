@@ -25,11 +25,17 @@ Three capabilities beyond the original recorder:
   decode → dispatch → result, or for a generated stream enqueue → claim →
   join → first token → result — draws as a single arrowed chain across
   threads and processes in Perfetto. The serving stack calls it with the
-  ``trace_id`` the client stamps at enqueue.
+  ``trace_id`` the client stamps at enqueue; a generated stream's claim,
+  join and first token are drawn from the span records that carry the
+  same id as their ``request`` (``serve.queue_wait``, ``serve.join``,
+  ``serve.first_token``), so they are stamped by no call of their own.
 
-Thread rows are named by ROLE: the recorder uses each thread's live name
-(``device-feed``, ``zoo-serving-claim``, ...); :func:`set_thread_label`
-renames the current thread for code that runs on an anonymous thread.
+Thread rows are named by ROLE: a row is a lane of the program's span
+records, the emitting thread's live name (``device-feed``,
+``zoo-serving-claim``, ``srv1-loop``, ...); :func:`set_thread_label`
+renames the current thread for code that runs on an anonymous thread. The
+stretches of a request's life (its wait in the queue, its time to a first
+token) belong to no thread and lie on the process's ``requests`` row.
 
 Usage::
 
@@ -38,9 +44,11 @@ Usage::
         estimator.train(fs, batch_size=..., epochs=1)
     # open https://ui.perfetto.dev and load the file
 
-Recording costs one list-append per span. The recorder is on
-``common.utils.span_hooks`` only while a session is open: outside one,
-nothing listens and a ``time_it`` span takes no clock.
+A session keeps no second copy of a span: ``common.utils`` makes one record
+a span while anybody listens on ``span_hooks`` (one append a span, the
+newest ``RECORDS_KEPT`` kept), the session's hook is on that list only while
+a session is open, and the dump draws the records that ended inside it.
+Outside a session nothing listens and a ``time_it`` span takes no clock.
 """
 from __future__ import annotations
 
@@ -68,12 +76,30 @@ def set_thread_label(label: str) -> None:
     threading.current_thread().name = label
 
 
+#: the row of a process's timeline for what belongs to no thread: the
+#: stretches of a request's life (``SpanRecord.lane`` ``None``)
+REQUESTS_ROW = "requests"
+
+#: the records that are a stage of a generated stream's flow chain, with
+#: the stage's name and whether it is stamped at the record's end
+_FLOW_STAGES = {"serve.queue_wait": ("serving.claim", True),
+                "serve.join": ("serving.join", False),
+                "serve.first_token": ("serving.first_token", True)}
+
+
 class _TraceSession:
+    """One open ``trace()``. The spans of this process are not recorded
+    here: they are drawn at :meth:`dump` from the program's own records
+    (``common.utils.span_records``), lanes, requests and all. What the
+    session keeps itself are the flow points stamped through
+    :func:`flow_point` and, in a forked child, a spool of its spans."""
+
     def __init__(self) -> None:
         self._lock = threading.Lock()
-        self._events: List[dict] = []
-        self._names: Dict[Tuple[int, int], str] = {}  # (pid, tid) -> label
+        self._events: List[dict] = []  # flow points, "lane" for "tid"
+        self._names: Dict[Tuple[int, int], str] = {}  # child: (pid, tid)
         self.t0 = time.perf_counter()
+        self.t1: Optional[float] = None  # set when the session closes
         self.pid = os.getpid()
         # spool for forked children: each foreign pid appends JSONL lines
         # (crash-tolerant — a SIGKILLed worker loses at most a partial
@@ -84,18 +110,11 @@ class _TraceSession:
 
     # -- recording ------------------------------------------------------------
 
-    def _emit(self, ev: dict) -> None:
-        pid = os.getpid()
+    def _spool(self, pid: int, ev: dict) -> None:
+        """A forked child's event, to its per-pid part file. The file
+        handle is re-resolved after any further fork (pid changed)."""
         ev["pid"] = pid
-        tid = ev["tid"]
-        if pid == self.pid:
-            with self._lock:
-                if (pid, tid) not in self._names:
-                    self._names[(pid, tid)] = threading.current_thread().name
-                self._events.append(ev)
-            return
-        # forked child: spool to the per-pid part file. The file handle is
-        # re-resolved after any further fork (pid changed under us).
+        tid = ev["tid"] = threading.get_ident()
         if self._part is None or self._part_pid != pid:
             try:
                 self._part = open(
@@ -120,31 +139,84 @@ class _TraceSession:
             pass
 
     def add(self, name: str, start: float, elapsed: float) -> None:
-        self._emit({
-            "name": name,
-            "ph": "X",  # complete event
-            "ts": (start - self.t0) * 1e6,  # microseconds
-            "dur": elapsed * 1e6,
-            "tid": threading.get_ident(),
-            "cat": "analytics_zoo_tpu",
-        })
+        """The hook's side of a span: nothing in the session's own
+        process, whose records the dump reads; a forked child's records
+        die with it, so its spans are spooled as they end."""
+        pid = os.getpid()
+        if pid != self.pid:
+            self._spool(pid, self._slice(name, start, elapsed))
+
+    def _slice(self, name: str, start: float, elapsed: float,
+               **args) -> dict:
+        ev = {"name": name, "ph": "X",  # complete event
+              "ts": (start - self.t0) * 1e6,  # microseconds
+              "dur": elapsed * 1e6, "cat": "analytics_zoo_tpu"}
+        if args:
+            ev["args"] = args
+        return ev
+
+    def _flow(self, flow_id: int, stage: str, phase: str,
+              t: float) -> List[dict]:
+        """One flow-chain point: a 2µs anchor slice named ``stage`` plus
+        the flow event Perfetto binds to it (same ts, same track)."""
+        anchor = self._slice(stage, t, 2e-6, trace_id=flow_id)
+        ev = {"name": FLOW_CAT, "cat": FLOW_CAT, "ph": phase,
+              "id": flow_id, "ts": anchor["ts"] + 1.0}
+        if phase == "f":
+            ev["bp"] = "e"  # bind the terminus to the enclosing slice
+        return [anchor, ev]
 
     def add_flow(self, flow_id: int, stage: str, phase: str,
                  t: float) -> None:
-        """One flow-chain point: a 2µs anchor slice named ``stage`` plus
-        the flow event Perfetto binds to it (same ts, same track)."""
-        ts = (t - self.t0) * 1e6
-        tid = threading.get_ident()
-        self._emit({"name": stage, "ph": "X", "ts": ts, "dur": 2.0,
-                    "tid": tid, "cat": "analytics_zoo_tpu",
-                    "args": {"trace_id": flow_id}})
-        ev = {"name": FLOW_CAT, "cat": FLOW_CAT, "ph": phase,
-              "id": flow_id, "ts": ts + 1.0, "tid": tid}
-        if phase == "f":
-            ev["bp"] = "e"  # bind the terminus to the enclosing slice
-        self._emit(ev)
+        pid = os.getpid()
+        events = self._flow(flow_id, stage, phase, t)
+        if pid != self.pid:
+            for ev in events:
+                self._spool(pid, ev)
+            return
+        lane = threading.current_thread().name
+        with self._lock:
+            for ev in events:
+                ev["lane"] = lane
+                self._events.append(ev)
 
     # -- output ---------------------------------------------------------------
+
+    def _drawn(self) -> List[dict]:
+        """The program's records that ended while this session was open,
+        as timeline events that name their lane: a block on a thread is a
+        slice on the thread's row; a stretch of a request's life is an
+        asynchronous slice of the process, so that the waits of many
+        requests lie side by side; a stage of a generated stream's flow
+        chain is stamped where its record says."""
+        t1 = time.perf_counter() if self.t1 is None else self.t1
+        events = []
+        for r in _utils.span_records():
+            end = r.start + r.seconds
+            if not self.t0 <= end <= t1:
+                continue
+            lane = REQUESTS_ROW if r.lane is None else r.lane
+            if r.lane is None:
+                life = {"name": r.name, "cat": "request_life", "id": r.id,
+                        "lane": lane, "args": {"request": r.request}}
+                events.append(dict(life, ph="b",
+                                   ts=(r.start - self.t0) * 1e6))
+                events.append(dict(life, ph="e", ts=(end - self.t0) * 1e6))
+            else:
+                args = dict(r.args, span=r.id)
+                if r.parent is not None:
+                    args["parent"] = r.parent
+                if r.request is not None:
+                    args["request"] = r.request
+                events.append(dict(
+                    self._slice(r.name, r.start, r.seconds, **args),
+                    lane=lane))
+            stage = _FLOW_STAGES.get(r.name)
+            if stage is not None and isinstance(r.request, int):
+                for ev in self._flow(r.request, stage[0], "t",
+                                     end if stage[1] else r.start):
+                    events.append(dict(ev, lane=lane))
+        return events
 
     def _merge_parts(self) -> List[dict]:
         merged: List[dict] = []
@@ -166,18 +238,27 @@ class _TraceSession:
     def dump(self, path: str) -> int:
         with self._lock:
             events = list(self._events)
-            names = dict(self._names)
-        events.extend(self._merge_parts())
+        events.extend(self._drawn())
+        # a row a lane; the requests' row, where there is one, is row 0
+        lanes = {ev["lane"] for ev in events}
+        rows = {REQUESTS_ROW: 0}
+        for lane in sorted(lanes - set(rows)):
+            rows[lane] = len(rows)
+        for ev in events:
+            ev["pid"], ev["tid"] = self.pid, rows[ev.pop("lane")]
         meta = [{"name": "process_name", "ph": "M", "pid": self.pid,
                  "args": {"name": _process_label()}}]
-        meta += [{"name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
-                  "args": {"name": label}}
-                 for (pid, tid), label in sorted(names.items())
-                 if pid == self.pid]
+        meta += [{"name": "thread_name", "ph": "M", "pid": self.pid,
+                  "tid": tid, "args": {"name": lane}}
+                 for lane, tid in rows.items() if lane in lanes]
+        parts = self._merge_parts()
+        meta += [ev for ev in parts if ev.get("ph") == "M"]
+        events += [ev for ev in parts if ev.get("ph") != "M"]
+        events.sort(key=lambda ev: ev["ts"])
         with open(path, "w") as f:
             json.dump(meta + events, f)
         shutil.rmtree(self.spool, ignore_errors=True)
-        return len([e for e in events if e.get("ph") != "M"])
+        return len(events)
 
 
 def _process_label() -> str:
@@ -218,6 +299,7 @@ def _close(session: _TraceSession) -> None:
             _sessions.remove(session)
         except ValueError:  # pragma: no cover - double-exit safety
             return
+        session.t1 = time.perf_counter()
         if not _sessions:
             _utils.span_hooks.remove(_record)
 
