@@ -20,15 +20,17 @@ Two attention paths that compute the same function:
   gather through the table: the step's cost follows the selection, not the
   context.
 - **a chunk** of one stream's prompt (:func:`attend_chunk`) is *plain*:
-  each tile of pages is expanded to the heads' keys and values and every
-  query row is masked to its own selection (``s in S_t``). With ``T``
-  queries a tile the expansion costs a fifth of the scores, where the
-  absorbed form would cost 1.7 times the plain one. On one TPU chip a
-  tile's scores, mask and softmax run in a pallas kernel
-  (:func:`_tile_attend_kernel`: the scores stay in VMEM; as XLA writes it
-  they cross the HBM four times, which was four fifths of a chunk);
-  elsewhere the XLA form runs, and on the TPU that is noted once with the
-  rule. The tiles' results are merged by their logs of sums.
+  the stream's latent rows are expanded to the heads' keys and values and
+  every query row is masked to its own selection (``s in S_t``). With
+  ``T`` queries to share it the expansion costs a fifth of the scores,
+  where the absorbed form would cost 1.7 times the plain one. On one TPU
+  chip it is one pallas call a layer (:func:`_attend_chunk_kernel`): the
+  loop over the key blocks, their expansion, the mask's blocks and the
+  running softmax of all the chunk's rows stay in VMEM, the latent pages
+  are read from the pool in place, and key blocks behind every row of a
+  sub-block of rows are skipped. Elsewhere XLA's loop over tiles of pages
+  runs, whose results are merged by their logs of sums, and on the TPU
+  that is noted once with the rule.
 
 The indexer (:func:`index_step`, :func:`index_chunk`): ``I[t, s] = sum_j
 w[t, j] relu(q_I[t, j] . k_I[s])`` over ``s <= t``, float32 accumulation,
@@ -49,6 +51,7 @@ masked tiles), ``kv_write`` (both pools' scatter)."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -57,6 +60,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import dispatch
+from .decode import PAGED_DECODE_TABLE_BYTES
 
 _NEG = -1e30
 
@@ -455,126 +459,281 @@ def _tile_attend_xla(q, k, v, ok):
         jnp.where(total > 0, top + jnp.log(jnp.maximum(total, 1e-30)), _NEG)
 
 
-def _tile_kernel(q_ref, k_ref, v_ref, ok_ref, o_ref, lse_ref, acc_ref,
-                 m_ref, l_ref):
-    """One head's block of query rows against the tile's blocks of keys in
-    turn (the grid's last axis): scores on the MXU, the mask, a running
-    softmax in float32 scratch. Scores, probabilities and the mask's
-    blocks never leave VMEM."""
-    from jax.experimental import pallas as pl
+#: the chunk kernel's blocks: query rows a sub-block of a program's rows, keys
+#: a step of its outer loop (whole pages)
+CHUNK_KERNEL_BLOCKS = (512, 1024)
 
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    ok = ok_ref[...] != 0
-    s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32)     # [bq, bk]
-    s = jnp.where(ok, s, _NEG)
-    m_prev = m_ref[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[:, :1] = l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    m_ref[:, :1] = m_new
-    acc_ref[:] = acc_ref[:] * corr + lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(ki == pl.num_programs(2) - 1)
-    def _finish():
-        total = l_ref[:, :1]
-        o_ref[0] = acc_ref[:] / jnp.maximum(total, 1e-30)
-        lse_ref[0] = jnp.where(
-            total > 0, m_ref[:, :1] + jnp.log(jnp.maximum(total, 1e-30)),
-            _NEG)
+#: what the kernel may hold in VMEM
+CHUNK_KERNEL_VMEM_BYTES = 32 * 1024 * 1024
 
 
-#: the kernel's blocks: query rows a program, keys a step of its last axis
-TILE_KERNEL_BLOCKS = (512, 1024)
+def seen_from(start, block, sub: int, keys: int):
+    """The first sub-block of ``sub`` rows, of a chunk whose first row is at
+    position ``start``, of which some row may see key block ``block``
+    (positions ``block * keys ..``): sub-block ``i`` ends at the position
+    ``start + sub * (i + 1) - 1``. The causal mask empties the block for
+    every sub-block before it."""
+    return jnp.maximum(block * keys - start, 0) // sub
 
 
-def _tile_kernel_rule(q, k, v) -> Optional[str]:
-    """Why the chunk's tiles cannot run :func:`_tile_attend_kernel` (None:
-    they can): the XLA form then runs, and on the TPU that is noted once."""
+def blocks_visited(start: int, rows: int, sub: int, keys: int
+                   ) -> Tuple[int, int]:
+    """``(visited, dense)``: the (sub-block of rows, key block) pairs that
+    the chunk kernel computes for a chunk of ``rows`` rows from ``start``,
+    and all pairs up to the chunk's last row, which it would compute if it
+    skipped nothing."""
+    blocks = (start + rows - 1) // keys + 1
+    visited = sum(rows // sub - int(seen_from(start, j, sub, keys))
+                  for j in range(blocks))
+    return visited, blocks * (rows // sub)
+
+
+def _chunk_kernel_rule(spec: LatentSpec, rows: int, page_len: int,
+                       width: int, bits_width: int) -> Optional[str]:
+    """Why a chunk of ``rows`` queries cannot run
+    :func:`_attend_chunk_kernel` (None: it can): the XLA form then runs,
+    and on the TPU that is noted once."""
     if dispatch.partitioned():
         return ("the chunk program spans several devices, and a Mosaic "
                 "kernel cannot be partitioned automatically")
-    (_, t, d), (_, n, dv) = q.shape, v.shape
-    bq, bk = TILE_KERNEL_BLOCKS
-    if t % min(bq, t) or n % min(bk, n) or min(bq, t) % 32 \
-            or min(bk, n) % 128 or d % 128 or dv % 128:
-        return (f"queries [{t}, {d}] over a tile of [{n}, {dv}] are no "
-                f"whole blocks of ({bq}, {bk}) rows and 128 lanes")
+    sub, keys = min(CHUNK_KERNEL_BLOCKS[0], rows), CHUNK_KERNEL_BLOCKS[1]
+    if rows % sub or sub % 128 or keys % page_len or keys % 128 \
+            or bits_width % keys or spec.qk_dim % 128 or spec.v_dim % 128 \
+            or spec.kv_rank % 128:
+        return (f"{rows} queries of [{spec.qk_dim}, {spec.v_dim}] over "
+                f"latent rows of {spec.kv_rank} in pages of {page_len} "
+                f"({bits_width} scored) are no whole blocks of "
+                f"{CHUNK_KERNEL_BLOCKS} rows and keys and 128 lanes")
+    if width * 4 > PAGED_DECODE_TABLE_BYTES:
+        return (f"a table row of {width} pages does not fit the scalar "
+                f"memory ({PAGED_DECODE_TABLE_BYTES} bytes)")
     return None
 
 
-def _tile_attend_kernel(q, k, v, ok):
-    """:func:`_tile_attend_xla`'s result from a pallas kernel: a grid of
-    (head, block of query rows, block of keys), the last axis carrying the
-    running softmax. ``ok`` rides as int8, one block for all heads."""
+def _chunk_kernel(start_ref, row_ref, qt_ref, wk_ref, wv_ref, lat_hbm, ok_hbm,
+                  o_ref, lat_buf, ok_buf, sem, k_ref, vt_ref, acc_ref, m_ref,
+                  l_ref):
+    """All of a chunk's query rows of one head against the stream's key
+    blocks in turn, from the first to the block of the chunk's last row. A
+    key block's pages come from the latent pool in HBM through ``row`` (one
+    DMA a page; the next block's are started before this one's are waited
+    for) and are expanded once, in VMEM, through this head's slices of
+    ``kv_b``: keys ``[c ; r] W_k`` (``wk_ref``, which carries the shared
+    rotary key through an identity, so a key is ``[k_nope ; r]`` with no
+    broadcast over the heads) and values transposed, ``W_v^T c^T``, both
+    rounded to the pool's dtype as the XLA form rounds them. Then the
+    sub-blocks of rows that may see the block attend to it in turn, each
+    under its own block of the selection's mask (int8 from HBM, the next
+    one's copy in flight): sub-blocks whose every row lies before the
+    block are skipped (:func:`seen_from`).
+
+    Scores are taken keys by rows (``k q^T``; the queries arrive
+    transposed, which costs XLA nothing where it makes them and the
+    kernel 2-10 % where it has to), so the running softmax's maxima and
+    sums over the keys are sums of whole registers and its statistics lie
+    along the lanes (PERF.md, PR 34 and PR 36); the accumulator is ``v^T
+    p``, transposed back once at the end. The running maximum, sum and
+    accumulator of all rows stay in VMEM across all key blocks."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    (h, t, d), (_, n, dv) = q.shape, v.shape
-    bq, bk = min(TILE_KERNEL_BLOCKS[0], t), min(TILE_KERNEL_BLOCKS[1], n)
-    o, lse = pl.pallas_call(
-        _tile_kernel,
-        out_shape=(jax.ShapeDtypeStruct((h, t, dv), jnp.float32),
-                   jax.ShapeDtypeStruct((h, t, 1), jnp.float32)),
-        grid=(h, t // bq, n // bk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda a, i, j: (a, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda a, i, j: (a, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, dv), lambda a, i, j: (a, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((bq, bk), lambda a, i, j: (i, j),
-                         memory_space=pltpu.VMEM)],
-        out_specs=(
-            pl.BlockSpec((1, bq, dv), lambda a, i, j: (a, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), lambda a, i, j: (a, i, 0),
-                         memory_space=pltpu.VMEM)),
-        scratch_shapes=[pltpu.VMEM((bq, dv), jnp.float32),
-                        pltpu.VMEM((bq, 128), jnp.float32),
-                        pltpu.VMEM((bq, 128), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(q, k, v, ok.astype(jnp.int8))
-    return o, lse[..., 0]
+    n_sub, _, sub = acc_ref.shape
+    _, pages, page_len, row_len = lat_buf.shape
+    keys, width, rank = pages * page_len, row_ref.shape[0], wv_ref.shape[2]
+    start = start_ref[0]
+    count = (start + n_sub * sub - 1) // keys + 1
+
+    def each_page(j, buf, act):
+        """``act`` on the copies of key block ``j`` into ``buf`` (past the
+        table's width: its last page, as the XLA form reads)."""
+        def one(n, carry):
+            page = row_ref[jnp.minimum(j * pages + n, width - 1)]
+            act(pltpu.make_async_copy(lat_hbm.at[page], lat_buf.at[buf, n],
+                                      sem.at[0, buf]))
+            return carry
+        lax.fori_loop(0, pages, one, 0)
+
+    def mask_copy(j, i, buf):
+        at = pl.ds(pl.multiple_of(j * keys, keys), keys)
+        return pltpu.make_async_copy(ok_hbm.at[i, at], ok_buf.at[buf],
+                                     sem.at[1, buf])
+
+    each_page(0, 0, lambda dma: dma.start())
+    mask_copy(0, 0, 0).start()
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def block(j, bufs):
+        buf, ok_at = bufs
+
+        @pl.when(j + 1 < count)
+        def _next_block():
+            each_page(j + 1, 1 - buf, lambda dma: dma.start())
+
+        each_page(j, buf, lambda dma: dma.wait())
+        lat = lat_buf[buf].reshape(keys, row_len)
+        k_ref[...] = jnp.dot(
+            lat, wk_ref[0],
+            preferred_element_type=jnp.float32).astype(k_ref.dtype)
+        vt_ref[...] = lax.dot_general(
+            wv_ref[0], lat[:, :rank], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(vt_ref.dtype)
+
+        def rows(i, ok_at):
+            @pl.when(i + 1 < n_sub)
+            def _next_rows():
+                mask_copy(j, i + 1, 1 - ok_at).start()
+
+            @pl.when((i + 1 == n_sub) & (j + 1 < count))
+            def _next_blocks_first_rows():
+                mask_copy(j + 1, seen_from(start, j + 1, sub, keys),
+                          1 - ok_at).start()
+
+            mask_copy(j, i, ok_at).wait()
+            sc = jnp.dot(k_ref[...], qt_ref[0, i],
+                         preferred_element_type=jnp.float32)  # [keys, sub]
+            # a row that has seen nothing yet weighs its unseen keys 1; its
+            # first seen key rescales that to nothing, and a row that never
+            # sees one is zeroed at the end
+            sc = jnp.where(ok_buf[ok_at] != 0, sc, _NEG)
+            m = m_ref[i]
+            m_new = jnp.maximum(m, jnp.max(sc, axis=0, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            corr = jnp.exp(m - m_new)
+            l_ref[i] = l_ref[i] * corr + jnp.sum(p, axis=0, keepdims=True)
+            m_ref[i] = m_new
+            acc_ref[i] = acc_ref[i] * corr + jnp.dot(
+                vt_ref[...], p.astype(vt_ref.dtype),
+                preferred_element_type=jnp.float32)           # [v, sub]
+            return 1 - ok_at
+
+        return 1 - buf, lax.fori_loop(seen_from(start, j, sub, keys), n_sub,
+                                      rows, ok_at)
+
+    lax.fori_loop(0, count, block, (0, 0))
+
+    def finish(i, carry):
+        o = jnp.where(m_ref[i] > 0.5 * _NEG,
+                      acc_ref[i] / jnp.maximum(l_ref[i], 1e-30), 0.0)
+        o_ref[pl.ds(pl.multiple_of(i * sub, sub), sub), :] = o.T
+        return carry
+    lax.fori_loop(0, n_sub, finish, 0)
 
 
-def _tile_attend(q, k, v, ok):
-    """One tile's attention: the kernel on one TPU chip where its rule
-    holds, otherwise the XLA form (off the TPU it is the implementation
-    and not a fallback: nothing is noted)."""
-    if not dispatch.on_tpu():
-        return _tile_attend_xla(q, k, v, ok)
-    rule = _tile_kernel_rule(q, k, v)
-    if rule is not None:
-        dispatch.note_fallback("latent_chunk_attend", rule)
-        return _tile_attend_xla(q, k, v, ok)
-    return _tile_attend_kernel(q, k, v, ok)
+def _selected(bits, pos, threshold, last, at_t):
+    """Which of the positions ``pos [n]`` (their scores' ``bits [T, n]``)
+    each query row at ``at_t [T]`` selects: :func:`select_chunk`'s set,
+    and never a later position than its own."""
+    ok = (bits > threshold[:, None]) | (
+        (bits == threshold[:, None]) & (pos[None] <= last[:, None]))
+    return ok & (pos[None] <= at_t[:, None])
 
 
-@jax.named_scope("mla_attend")
+@functools.partial(jax.jit, static_argnames=("spec", "blocks"))
+def _attend_chunk_kernel(spec, kv_b, q_nope, q_rope, latent_pool, row, start,
+                         bits, threshold, last, blocks):
+    """:func:`attend_chunk` as one Mosaic call: a grid of heads, each
+    program walking the stream's key blocks of ``blocks[1]`` positions for
+    all the chunk's rows, ``blocks[0]`` at a time. Around it XLA only lays
+    operands out: the queries transposed a sub-block, ``kv_b`` split a head
+    into its key slice (with the identity that carries the rotary key) and
+    its transposed value slice, and the selection's mask as int8, keys by
+    rows a sub-block, over the whole scored width in one pass (a loop up
+    to the chunk's end was no faster inside the chunk program: PERF.md, PR
+    36). Jitted on its own, so that a chunk program traces and lowers the
+    kernel once for all its layers; the scope is inside the ``jit``,
+    innermost, where XLA takes the Mosaic call's name from."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    with jax.named_scope("mla_attend"):
+        t, h = q_nope.shape[:2]
+        _, page_len, row_len = latent_pool.shape
+        dtype = latent_pool.dtype
+        sub, keys = min(blocks[0], t), blocks[1]
+        n_sub = t // sub
+        qt = jnp.concatenate([q_nope, q_rope], axis=-1).astype(dtype)
+        qt = qt.reshape(n_sub, sub, h, spec.qk_dim).transpose(2, 0, 3, 1)
+        w_kv = kv_b.reshape(spec.kv_rank, h, -1)
+        # [c ; r ; padding] -> [k_nope ; r]: the rotary key passes through
+        # an identity, exactly (a product with 1 and sums with 0)
+        wk = jnp.zeros((h, row_len, spec.qk_dim), w_kv.dtype)
+        wk = wk.at[:, :spec.kv_rank, :spec.nope_dim].set(
+            jnp.moveaxis(w_kv[..., :spec.nope_dim], 1, 0))
+        wk = wk.at[:, spec.kv_rank:spec.row, spec.nope_dim:].set(
+            jnp.eye(spec.rope_dim, dtype=w_kv.dtype))
+        wv = w_kv[..., spec.nope_dim:].transpose(1, 2, 0)   # [H, v, rank]
+        ok = _selected(bits, jnp.arange(bits.shape[1]), threshold, last,
+                       start + jnp.arange(t))
+        ok = ok.reshape(n_sub, sub, -1).transpose(0, 2, 1).astype(jnp.int8)
+
+        def head(*shape):
+            return pl.BlockSpec((1,) + shape,
+                                lambda a, *_: (a,) + (0,) * len(shape),
+                                memory_space=pltpu.VMEM)
+        stat = pltpu.VMEM((n_sub, 1, sub), jnp.float32)
+        return pl.pallas_call(
+            _chunk_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(h,),
+                in_specs=[head(n_sub, spec.qk_dim, sub),
+                          head(row_len, spec.qk_dim),
+                          head(spec.v_dim, spec.kv_rank),
+                          pl.BlockSpec(memory_space=pl.ANY),
+                          pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec((t, spec.v_dim), lambda a, *_: (0, a),
+                                       memory_space=pltpu.VMEM),
+                scratch_shapes=[
+                    pltpu.VMEM((2, keys // page_len, page_len, row_len),
+                               dtype),
+                    pltpu.VMEM((2, keys, sub), jnp.int8),
+                    pltpu.SemaphoreType.DMA((2, 2)),
+                    pltpu.VMEM((keys, spec.qk_dim), dtype),
+                    pltpu.VMEM((spec.v_dim, keys), dtype),
+                    pltpu.VMEM((n_sub, spec.v_dim, sub), jnp.float32),
+                    stat, stat]),
+            out_shape=jax.ShapeDtypeStruct((t, h * spec.v_dim), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",),
+                vmem_limit_bytes=CHUNK_KERNEL_VMEM_BYTES),
+        )(jnp.asarray(start, jnp.int32).reshape(1), row.astype(jnp.int32),
+          qt, wk, wv, latent_pool, ok)
+
+
 def attend_chunk(spec: LatentSpec, p, q_nope: jax.Array, q_rope: jax.Array,
                  latent_pool: jax.Array, row: jax.Array, start,
                  bits: jax.Array, threshold: jax.Array, last: jax.Array
                  ) -> jax.Array:
-    """The plain form for the chunk's queries, each over its own selection:
-    a tile of the stream's pages at a time is expanded through ``kv_b`` to
-    the heads' keys (``[k_nope_h ; k_rope]``) and values, attended under
-    the mask ``s in S_t`` (``bits``, ``threshold``, ``last`` of
-    :func:`select_chunk`; ``s <= t``) by :func:`_tile_attend`, and the
-    tiles' results are merged by their logs of sums. Returns ``[T, H *
-    v_dim]`` float32."""
+    """The plain form for the chunk's queries, each over its own selection
+    (``bits``, ``threshold``, ``last`` of :func:`select_chunk`; ``s <=
+    t``): the stream's latent rows are expanded through ``kv_b`` to the
+    heads' keys (``[k_nope_h ; k_rope]``) and values and attended under
+    the mask ``s in S_t``. On one TPU chip that is one kernel call
+    (:func:`_attend_chunk_kernel`); elsewhere, and where its rule refuses
+    (noted once on the TPU), XLA's loop over tiles of pages, whose results
+    are merged by their logs of sums. Returns ``[T, H * v_dim]``
+    float32."""
+    if dispatch.on_tpu():
+        rule = _chunk_kernel_rule(spec, q_nope.shape[0],
+                                  latent_pool.shape[1], row.shape[0],
+                                  bits.shape[1])
+        if rule is None:
+            return _attend_chunk_kernel(
+                spec, p["kv_b"], q_nope, q_rope, latent_pool, row, start,
+                bits, threshold, last, CHUNK_KERNEL_BLOCKS)
+        dispatch.note_fallback("latent_chunk_attend", rule)
+    with jax.named_scope("mla_attend"):
+        return _attend_chunk_xla(spec, p["kv_b"], q_nope, q_rope,
+                                 latent_pool, row, start, bits, threshold,
+                                 last)
+
+
+def _attend_chunk_xla(spec, kv_b, q_nope, q_rope, latent_pool, row, start,
+                      bits, threshold, last):
+    """:func:`attend_chunk` as XLA writes it: a tile of the stream's pages
+    at a time is expanded and attended (:func:`_tile_attend_xla`)."""
     t = q_nope.shape[0]
     page_len = latent_pool.shape[1]
     dtype = latent_pool.dtype
@@ -582,7 +741,7 @@ def attend_chunk(spec: LatentSpec, p, q_nope: jax.Array, q_rope: jax.Array,
     tile = tp * page_len
     q = jnp.concatenate([q_nope, q_rope], axis=-1).astype(dtype)
     q = jnp.moveaxis(q, 0, 1)                                # [H, T, qk]
-    w_kv = p["kv_b"].reshape(spec.kv_rank, spec.heads, -1)
+    w_kv = kv_b.reshape(spec.kv_rank, spec.heads, -1)
     at_t = start + jnp.arange(t)
 
     def body(i, carry):
@@ -595,12 +754,9 @@ def attend_chunk(spec: LatentSpec, p, q_nope: jax.Array, q_rope: jax.Array,
         k = jnp.concatenate(
             [kv[..., :spec.nope_dim],
              jnp.broadcast_to(r[None], (spec.heads,) + r.shape)], axis=-1)
-        pos = i * tile + jnp.arange(tile)
-        part = lax.dynamic_slice(bits, (0, i * tile), (t, tile))
-        ok = (part > threshold[:, None]) | (
-            (part == threshold[:, None]) & (pos[None] <= last[:, None]))
-        ok &= pos[None] <= at_t[:, None]
-        o, new = _tile_attend(q, k, kv[..., spec.nope_dim:], ok)
+        ok = _selected(lax.dynamic_slice(bits, (0, i * tile), (t, tile)),
+                       i * tile + jnp.arange(tile), threshold, last, at_t)
+        o, new = _tile_attend_xla(q, k, kv[..., spec.nope_dim:], ok)
         both = jnp.logaddexp(lse, new)
         acc = acc * jnp.exp(lse - both)[..., None] \
             + o * jnp.exp(new - both)[..., None]

@@ -372,11 +372,14 @@ class TestServingChaos:
         from analytics_zoo_tpu.serving import InputQueue, OutputQueue
         serving, src = self._serving(tmp_path)
         faults.arm("serving.writeback", at=1, budget=1)
+        inq, outq = InputQueue(src), OutputQueue(src)
+        # in the queue before the loop starts, so that the four are one
+        # batch however slow the host is (enqueued beside a running loop,
+        # a batch that waits 5 ms took three of them on a loaded machine)
+        for i in range(4):
+            inq.enqueue_tensor(f"a{i}", np.full(4, float(i)))
         serving.start()
         try:
-            inq, outq = InputQueue(src), OutputQueue(src)
-            for i in range(4):
-                inq.enqueue_tensor(f"a{i}", np.full(4, float(i)))
             first = [outq.query(f"a{i}", timeout_s=10.0) for i in range(4)]
             # the faulted batch's records got ERROR results (not dropped:
             # a client would otherwise poll to its timeout)

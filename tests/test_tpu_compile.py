@@ -582,7 +582,7 @@ _LONGCTX = dict(slots=12, pages=6241, page_len=64, width=520)
 
 
 @pytest.mark.parametrize("program", ["decode_step", "chunk_2048",
-                                     "chunk_512"])
+                                     "chunk_1024", "chunk_512"])
 def test_glm5_programs_keep_pools_and_expert_tables_in_place(
         v5e, tmp_path, monkeypatch, program):
     """GLM-5's decode step and prefill chunk as the server declares them,
@@ -592,10 +592,14 @@ def test_glm5_programs_keep_pools_and_expert_tables_in_place(
     lanes) and an index-key pool ``[P, 64, 128]`` a layer, 12 slots): every pool leaf is aliased to an output, no
     copy of a whole pool or of an expert table is left in the program, the
     grouped products are the compiler's own kernel, and the program with
-    its temporaries fits the chip beside the weights. A chunk's attention
-    tiles are the pallas kernel (one Mosaic call a layer whose attention
-    feeds a later layer) and none of the XLA form's scores of a whole
-    tile."""
+    its temporaries fits the chip beside the weights. A chunk attends
+    with the chunk kernel: one Mosaic call under ``mla_attend`` a layer
+    whose attention feeds a later layer, which holds the loop over the key
+    blocks, the expansion through ``kv_b`` and the running softmax, so the
+    program has no ``while`` under that scope, none of the XLA loop's
+    scores of a whole tile, and neither a tile's expanded keys and values
+    ``[64, 2048, 448]`` nor a tile's result or the carry it is merged into
+    ``[64, T, 256]``."""
     import json
     import pathlib
     import re
@@ -664,9 +668,16 @@ def test_glm5_programs_keep_pools_and_expert_tables_in_place(
     else:
         # the last layer's attention feeds nothing in a chunk (no logits
         # come of it) and the compiler drops it: four layers' kernels
-        assert len(calls) == 4, calls
+        attend = [op_name for _, op_name in _mosaic_calls(text)
+                  if "mla_attend" in op_name]
+        assert len(calls) == len(attend) == 4, _mosaic_calls(text)
+        loops = [line.strip()[:160] for line in text.splitlines()
+                 if re.search(r" while\(", line) and "mla_attend" in line]
+        assert not loops, loops[:4]
         whole = [line.strip()[:160] for line in text.splitlines()
-                 if re.search(r"f32\[64,%s,2048\]" % width, line)]
+                 if re.search(r"f32\[64,%s,2048\]" % width, line)
+                 or re.search(r"(f32|bf16)\[64,(2048,448|448,2048|%s,256)\]"
+                              % width, line)]
         assert not whole, whole[:4]
 
 
