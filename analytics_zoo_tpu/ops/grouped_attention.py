@@ -20,10 +20,12 @@ blocks those rows may see, from the block of the first key the block's
 first row sees to the block of its last row: scores, probabilities and the
 rescaled accumulator stay in VMEM, and a key block that the causal or the
 window mask empties for the whole block of rows is neither fetched nor
-computed. Elsewhere (off the TPU, a program over several devices, pages,
-heads or chunks that are no whole tiles and blocks) the XLA form above
-runs, and on the TPU that is noted once with the rule
-(``grouped_paged_decode``, ``grouped_chunk_attend``).
+computed. The chunk kernel also takes a page mask, which pages each row
+reads, for the block-sparse layers of ``ops/sparse_attention.py``.
+Elsewhere (off the TPU, a program over several devices, pages, heads or
+chunks that are no whole tiles and blocks) the XLA form above runs, and on
+the TPU that is noted once with the rule (``grouped_paged_decode``,
+``grouped_chunk_attend``).
 
 The caller has written the queries' own K and V before it reads. Scopes:
 ``attn_full`` and ``attn_window`` (docs/observability.md)."""
@@ -37,7 +39,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import dispatch
-from .decode import PAGED_DECODE_CHUNK, _paged_decode_rule
+from .decode import (PAGED_DECODE_CHUNK, PAGED_DECODE_TABLE_BYTES,
+                     _paged_decode_rule)
 
 _NEG = -1e30
 
@@ -273,27 +276,35 @@ def attend_step(q: jax.Array, cache, table: jax.Array, lengths: jax.Array,
 CHUNK_KERNEL_BLOCKS = (512, 512)
 
 
-def _chunk_kernel_rule(q, cache, row) -> Optional[str]:
-    """Why a chunk cannot run :func:`_attend_chunk_kernel` (None: it can):
-    ``ops/decode.py``'s rule for reading a pool in place, and queries that
-    are whole blocks of rows and of 128 lanes."""
+def _chunk_kernel_rule(q, cache, row, blocks,
+                       page_mask: bool = False) -> Optional[str]:
+    """Why a chunk cannot run :func:`_attend_chunk_kernel` with ``blocks``
+    (None: it can): ``ops/decode.py``'s rule for reading a pool in place,
+    and queries that are whole blocks of rows and of 128 lanes. Under a
+    ``page_mask`` a block of rows is also whole lanes of the mask's block,
+    and the table with the key blocks' codes fits the scalar memory."""
     rule = _paged_decode_rule(cache, row)
     if rule is not None:
         return rule
-    t, _, _, d = q.shape
+    t, kv, _, d = q.shape
     page_len = cache["k"].shape[1]
-    rows, keys = min(CHUNK_KERNEL_BLOCKS[0], t), CHUNK_KERNEL_BLOCKS[1]
+    rows, keys = min(blocks[0], t), blocks[1]
     if d % 128 or t % rows or rows % (32 // cache["k"].dtype.itemsize) \
-            or keys % page_len:
+            or keys % page_len or (page_mask and rows % 128 and rows != t):
         return (f"queries [{t}, {d}] over pages of {page_len} are no whole "
-                f"blocks of {CHUNK_KERNEL_BLOCKS} rows and keys and 128 "
-                f"lanes")
+                f"blocks of {blocks} rows and keys and 128 lanes")
+    if page_mask:
+        codes = kv * (t // rows) * -(-row.shape[0] * page_len // keys)
+        if (row.shape[0] + codes) * 4 > PAGED_DECODE_TABLE_BYTES:
+            return (f"a table row of {row.shape[0]} pages and {codes} key "
+                    f"block codes exceed the {PAGED_DECODE_TABLE_BYTES}-byte "
+                    f"scalar prefetch budget")
     return None
 
 
 def _chunk_kernel(start_ref, row_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
                   v_buf, sem, qt_ref, acc_ref, m_ref, l_ref, *,
-                  window: Optional[int]):
+                  window: Optional[int], page_mask=None):
     """One key/value head's block of query rows (positions ``low ..
     high``) against the key blocks it may see, in turn: from the block of
     the first key the block's first row sees (0 in a full layer) to the
@@ -310,7 +321,15 @@ def _chunk_kernel(start_ref, row_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
     every block took two fifths of the kernel's time (PERF.md, PR 34).
     The accumulator is ``V^T p`` and is transposed back at the end. A block
     that every row sees whole skips the mask; elsewhere the mask comes
-    from the positions. Scores and probabilities never leave VMEM."""
+    from the positions. Scores and probabilities never leave VMEM.
+
+    ``page_mask`` (a block-sparse layer, :func:`_page_masked_chunk_kernel`)
+    is ``(whole_ref, ok_ref)``: which pages each row reads, ``ok_ref [1,
+    key blocks, pages, rows]`` (int8), and for each (head, block of rows,
+    key block) whether every row reads every page of it and lies past it
+    (``whole_ref``, flat, in scalar memory): that block skips the mask, and
+    elsewhere the rows' pages are expanded along their positions and join
+    the causal mask."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -366,7 +385,9 @@ def _chunk_kernel(start_ref, row_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
                 v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)           # [d, rows]
 
-        if seen is None:
+        if seen is None or page_mask is not None:
+            # under a page mask most blocks are masked: unrolled, one
+            # head's products run under another's exponentials
             for g, own in enumerate(heads):
                 head(g, own)
         else:
@@ -385,9 +406,14 @@ def _chunk_kernel(start_ref, row_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
 
         each_page(b, buf, lambda dma: dma.wait())
         base = (first + b) * span
-        whole = base + span - 1 <= low
-        if window is not None:
-            whole &= base > high - window
+        if page_mask is None:
+            whole = base + span - 1 <= low
+            if window is not None:
+                whole &= base > high - window
+        else:
+            whole_ref, ok_ref = page_mask
+            whole = whole_ref[(head * pl.num_programs(1) + block)
+                              * ok_ref.shape[1] + first + b] != 0
 
         @pl.when(whole)
         def _every_row_sees_every_key():
@@ -400,6 +426,11 @@ def _chunk_kernel(start_ref, row_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
             seen = pos <= at
             if window is not None:
                 seen &= pos > at - window
+            if page_mask is not None:
+                ok = ok_ref[0, first + b].astype(jnp.int32)  # [pages, rows]
+                seen &= jnp.broadcast_to(
+                    ok[:, None], (pages, page_len, rows)).reshape(
+                        span, rows) != 0
             attend(buf, seen)
         return 1 - buf
 
@@ -408,17 +439,49 @@ def _chunk_kernel(start_ref, row_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
         o_ref[:, own] = (acc_ref[own, :] / jnp.maximum(l_ref[g], 1e-30)).T
 
 
-@functools.partial(jax.jit, static_argnames=("window", "blocks"))
-def _attend_chunk_kernel(q, k_pool, v_pool, row, start, window, blocks):
+def _page_masked_chunk_kernel(start_ref, row_ref, whole_ref, q_ref, ok_ref,
+                              k_hbm, v_hbm, o_ref, *scratch):
+    """:func:`_chunk_kernel` of a full layer whose rows read only some
+    pages (``page_mask``)."""
+    _chunk_kernel(start_ref, row_ref, q_ref, k_hbm, v_hbm, o_ref, *scratch,
+                  window=None, page_mask=(whole_ref, ok_ref))
+
+
+def _page_codes(allowed, start, rows: int, pages: int, page_len: int):
+    """From ``allowed [T, KV, W]`` (which pages each row reads) to the
+    kernel's operands: the mask as int8 ``[KV, key blocks, pages, T]`` (a
+    key block is ``pages`` pages, the last one padded with pages no row
+    reads), and for each (head, block of ``rows`` rows, key block), flat
+    in that order, 1 where every row of the block reads every page of the
+    key block and lies past its last position (the block is whole), else
+    0 (masked)."""
+    t, kv, w = allowed.shape
+    n = -(-w // pages)
+    ok = jnp.pad(allowed, ((0, 0), (0, 0), (0, n * pages - w)))
+    ok = ok.reshape(t, kv, n, pages)
+    every = jnp.all(ok.reshape(t // rows, rows, kv, n, pages), axis=(1, 4))
+    low = start + rows * jnp.arange(t // rows)
+    past = (jnp.arange(n) + 1) * pages * page_len - 1 <= low[:, None]
+    whole = (every & past[:, None]).transpose(1, 0, 2)   # [KV, T/rows, n]
+    return (ok.transpose(1, 2, 3, 0).astype(jnp.int8),
+            whole.astype(jnp.int32).reshape(-1))
+
+
+@functools.partial(jax.jit, static_argnames=("window", "blocks", "scope"))
+def _attend_chunk_kernel(q, k_pool, v_pool, row, start, window, blocks,
+                         allowed=None, scope=None):
     """:func:`attend_chunk` with the pool read in place and the scores kept
     in VMEM: a grid of (key/value head, block of ``blocks[0]`` query rows),
     each program walking its own key blocks of ``blocks[1]`` positions.
     Jitted on its own with the scope innermost, as
-    :func:`_attend_step_kernel` is."""
+    :func:`_attend_step_kernel` is. ``allowed [T, KV, W]``, where given,
+    says which pages each row reads (a block-sparse full layer, scope
+    ``scope``): the kernel takes it as a mask a key block
+    (:func:`_page_masked_chunk_kernel`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    with _scope(window):
+    with jax.named_scope(scope) if scope else _scope(window):
         t, kv, g, d = q.shape
         _, page_len, _ = k_pool.shape
         rows, pages = min(blocks[0], t), blocks[1] // page_len
@@ -426,13 +489,26 @@ def _attend_chunk_kernel(q, k_pool, v_pool, row, start, window, blocks):
                              memory_space=pltpu.VMEM)
         buf = pltpu.VMEM((2, pages, page_len, d), k_pool.dtype)
         stat = pltpu.VMEM((g, 1, rows), jnp.float32)
+        kernel = functools.partial(_chunk_kernel, window=window)
+        scalars = (jnp.asarray(start, jnp.int32).reshape(1),
+                   row.astype(jnp.int32))
+        in_specs = [heads, pl.BlockSpec(memory_space=pl.ANY),
+                    pl.BlockSpec(memory_space=pl.ANY)]
+        operands = (q.reshape(t, kv * g * d), k_pool, v_pool)
+        if allowed is not None:
+            ok, whole = _page_codes(allowed, start, rows, pages, page_len)
+            kernel = _page_masked_chunk_kernel
+            scalars += (whole,)
+            in_specs.insert(1, pl.BlockSpec(
+                (1, ok.shape[1], pages, rows), lambda h, i, *_: (h, 0, 0, i),
+                memory_space=pltpu.VMEM))
+            operands = operands[:1] + (ok,) + operands[1:]
         out = pl.pallas_call(
-            functools.partial(_chunk_kernel, window=window),
+            kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
+                num_scalar_prefetch=len(scalars),
                 grid=(kv, t // rows),
-                in_specs=[heads, pl.BlockSpec(memory_space=pl.ANY),
-                          pl.BlockSpec(memory_space=pl.ANY)],
+                in_specs=in_specs,
                 out_specs=heads,
                 scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
                                 pltpu.VMEM((g * d, rows), k_pool.dtype),
@@ -442,8 +518,7 @@ def _attend_chunk_kernel(q, k_pool, v_pool, row, start, window, blocks):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
                 vmem_limit_bytes=64 * 1024 * 1024),
-        )(jnp.asarray(start, jnp.int32).reshape(1), row.astype(jnp.int32),
-          q.reshape(t, kv * g * d), k_pool, v_pool)
+        )(*scalars, *operands)
         return out.reshape(t, kv, g, d)
 
 
@@ -455,7 +530,7 @@ def attend_chunk(q: jax.Array, cache, row: jax.Array, start,
     among them; on one TPU chip the kernel, which visits for each block of
     rows the key blocks it may see. Returns ``[T, KV, G, D]`` float32."""
     if dispatch.on_tpu():
-        rule = _chunk_kernel_rule(q, cache, row)
+        rule = _chunk_kernel_rule(q, cache, row, CHUNK_KERNEL_BLOCKS)
         if rule is None:
             return _attend_chunk_kernel(q, cache["k"], cache["v"], row,
                                         start, window, CHUNK_KERNEL_BLOCKS)

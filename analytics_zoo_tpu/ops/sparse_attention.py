@@ -22,7 +22,10 @@ Two forms of each, as everywhere in the serving path: ``*_step`` for one
 position of every slot (decode), ``*_chunk`` for a stretch of one stream's
 prompt (chunked prefill). The decode read gathers the selected pages only,
 never the table's whole width; a chunk computes block-masked attention over
-the stream's pages so far, a tile of pages at a time."""
+the stream's pages so far: on one TPU chip with the grouped-query chunk
+kernel of ``ops/grouped_attention.py`` under the selection as its page mask
+(noted once where its rule refuses: ``sparse_chunk_attend``), elsewhere a
+tile of pages at a time."""
 from __future__ import annotations
 
 import dataclasses
@@ -31,6 +34,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from . import dispatch, grouped_attention
 
 _NEG = -1e30
 
@@ -312,16 +317,40 @@ def attend_step(spec: SparseSpec, q: jax.Array, cache: Dict[str, jax.Array],
     return lax.cond(jnp.any(active & dense), mixed, selected, None)
 
 
-@jax.named_scope("sparse_attend")
+#: the chunk kernel's blocks: query rows a program, keys a step of its loop
+CHUNK_KERNEL_BLOCKS = (256, 512)
+
+
 def attend_chunk(spec: SparseSpec, q: jax.Array, cache: Dict[str, jax.Array],
                  row: jax.Array, start, allowed: jax.Array,
                  tile_pages: int = 16) -> jax.Array:
     """Prefill: the chunk's queries (``q [T, KV, G, D]`` scaled, at
     positions ``start ..``) over the stream's pages so far, the chunk's own
-    included (the caller has written them), a tile of ``tile_pages`` pages
-    at a time with a running softmax; ``allowed [T, KV, W]`` says which
-    blocks a query reads. As many tiles as the context so far has, not as
-    the table is wide. Returns ``[T, KV, G, D]`` float32."""
+    included (the caller has written them); ``allowed [T, KV, W]`` says
+    which blocks a query reads, and within a block a query reads up to its
+    own position. On one TPU chip that is the grouped-query chunk kernel
+    (``ops/grouped_attention.py _attend_chunk_kernel``) with ``allowed`` as
+    its page mask: each block of rows walks the key blocks up to its last
+    row's, reads them from the pool in place and keeps its scores in VMEM;
+    a key block that every row reads whole, and lies before every row,
+    skips the mask. Elsewhere, and where the kernel's rule refuses (noted
+    once on the TPU), XLA's tiles of ``tile_pages`` pages with a running
+    softmax, as many as the context so far has, not as the table is wide.
+    Returns ``[T, KV, G, D]`` float32."""
+    if dispatch.on_tpu():
+        rule = grouped_attention._chunk_kernel_rule(
+            q, cache, row, CHUNK_KERNEL_BLOCKS, page_mask=True)
+        if rule is None:
+            return grouped_attention._attend_chunk_kernel(
+                q, cache["k"], cache["v"], row, start, None,
+                CHUNK_KERNEL_BLOCKS, allowed, "sparse_attend")
+        dispatch.note_fallback("sparse_chunk_attend", rule)
+    with jax.named_scope("sparse_attend"):
+        return _attend_chunk_xla(q, cache, row, start, allowed, tile_pages)
+
+
+def _attend_chunk_xla(q, cache, row, start, allowed, tile_pages):
+    """:func:`attend_chunk` as XLA writes it: a tile of pages at a time."""
     t_len, kv, g, d = q.shape
     page_len = cache["k"].shape[1]
     w = row.shape[0]

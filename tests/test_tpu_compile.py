@@ -420,16 +420,22 @@ _SALA = dict(slots=12, pages=4705, page_len=64, width=392)
 @pytest.mark.parametrize("program", ["decode_step", "chunk_2048",
                                      "chunk_512"])
 def test_sala_programs_keep_pools_and_states_in_place(v5e, tmp_path,
-                                                      program):
+                                                      monkeypatch, program):
     """MiniCPM-SALA's decode step and prefill chunk as the server declares
     them, compiled for the chip at the benchmark's sizes (16 layers at the
     published widths, 4,705 pages of 64, 12 slots): every cache leaf (K/V
     pages, compressed keys, lightning states) is aliased to an output, and
     no copy of a whole pool or of the states is left in the program; the
-    program and its temporaries fit the chip beside the weights."""
+    program and its temporaries fit the chip beside the weights. A chunk
+    reads its sparse layers with the chunk kernel: one Mosaic call under
+    ``sparse_attend`` a sparse layer (4), and none of the XLA tiles'
+    float32 fusions ``[T, 2, 16, ...]``."""
     import json
     import pathlib
     import re
+    from analytics_zoo_tpu.ops import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    monkeypatch.setattr(dispatch, "_seen", set())
     from analytics_zoo_tpu.capture.decoder import DecoderSpec, LayeredDecoder
     from analytics_zoo_tpu.serving import GenerativeServing, ServingConfig
     root = pathlib.Path(__file__).resolve().parents[1]
@@ -477,6 +483,19 @@ def test_sala_programs_keep_pools_and_states_in_place(v5e, tmp_path,
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         + memory.output_size_in_bytes - memory.alias_size_in_bytes
     assert held < 14.5e9, held   # of the chip's 16 GB
+    assert dispatch.fallbacks_seen() == []
+    attend = [name for name, op_name in _mosaic_calls(text)
+              if "sparse_attend" in op_name]
+    assert len(attend) == len(_mosaic_calls(text)), _mosaic_calls(text)
+    if program == "decode_step":
+        assert not attend, attend
+    else:
+        assert len(attend) == 4, attend
+        tiles = [line.strip()[:160] for line in text.splitlines()
+                 if "sparse_attend" in line and (
+                     re.search(r"= f32\[%s,2,16\S* fusion\(" % width, line)
+                     or re.search(r" while\(", line))]
+        assert not tiles, tiles[:4]
 
 
 _MIXED = dict(slots=32, pages=7937, window_pages=2113, page_len=64, width=248)
@@ -679,6 +698,36 @@ def test_glm5_programs_keep_pools_and_expert_tables_in_place(
                  or re.search(r"(f32|bf16)\[64,(2048,448|448,2048|%s,256)\]"
                               % width, line)]
         assert not whole, whole[:4]
+
+
+# the lowered text of SmallThinker's chunk kernel at its cell's shapes (2,048
+# rows, a full layer's pool of 7,937 pages and a window layer's of 2,113),
+# hashed on the tree before the kernel took a page mask (6ef3e02); see the
+# test below
+_SMALLTHINKER_CHUNK_KERNEL = {None: "b4e70b3d569e62e6",
+                              4096: "373667d00769d217"}
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+def test_smallthinker_chunk_kernel_lowers_to_the_text_it_was(v5e, window):
+    """The grouped-query chunk kernel without a page mask, as SmallThinker's
+    chunk programs call it, lowers to the text it lowered to before the
+    mask came into the kernel (``ops/sparse_attention.py`` passes one):
+    the Mosaic text is part of the compile cache's key and of what runs,
+    so the unmasked kernel is the same program and compiles nothing
+    anew."""
+    import hashlib
+    from analytics_zoo_tpu.common import context
+    from analytics_zoo_tpu.ops import grouped_attention as GA
+    context.wire_compilation_cache()
+    pages = 7937 if window is None else 2113
+    lowered = GA._attend_chunk_kernel.lower(
+        v5e((2048, 4, 7, 128), F32), v5e((pages, 64, 512), BF16),
+        v5e((pages, 64, 512), BF16), v5e((248,), I32), v5e((), I32),
+        window, (512, 512))
+    got = hashlib.sha256(
+        lowered.as_text(debug_info=True).encode()).hexdigest()[:16]
+    assert got == _SMALLTHINKER_CHUNK_KERNEL[window]
 
 
 # the lowered text of the two older layered decoders' programs at a tiny size,
