@@ -5,9 +5,12 @@ by layer, and :class:`LayeredDecoder` runs it behind
 the server: ``params``, ``max_len``, cache construction, a paged decode
 step, a prefill; here ``paged_state_step`` and ``prefill_chunk``).
 
-What it covers today is what MiniCPM-SALA, SmallThinker and GLM-5 need
-(docs/models.md): RMS norms, an untied head, MiniCPM's embedding, residual
-and logit scalings, bfloat16 parameters, a feed-forward chosen by the spec,
+What it covers today is what MiniCPM-SALA, SmallThinker, GLM-5 and
+GLM-5.3-Flash need (docs/models.md): RMS norms, an untied head, MiniCPM's
+embedding, residual and logit scalings, bfloat16 parameters, a plain
+residual or several residual streams mixed around every sublayer (mHC,
+``hc_streams``: a doubly stochastic mix of the streams by Sinkhorn
+iterations, under the scope ``mhc``), a feed-forward chosen by the spec,
 for the whole model or layer by layer (``"silu"``: gated SiLU; ``"moe"``:
 dropless top-k experts, ``ops/moe.py``, under a router that is data too: a
 softmax on the layer's input before attention with ReGLU experts, or
@@ -26,15 +29,21 @@ by layer:
 - ``"window"``: the same over the last ``window`` positions, with rotary
   positions. Its K/V page pool is a budget of its own: the pages that lie
   wholly behind a stream's window go back to the server's window free list.
+- ``"mla-dsa"``: latent attention over a latent page pool with a learned
+  selection (``ops/latent_attention.py``), with or without rotary parts,
+  over single positions or pooled blocks of keys.
+- ``"kda"``: gated delta-rule linear attention with a decay a channel
+  (``ops/delta_attention.py``), a short convolution on q, k and v, an
+  output norm and a low-rank output gate. Its cache is a float32 state
+  ``[slots, H, D, D]`` and the convolution's window a slot.
 
 Every kind of cache lives in the one list the server holds and donates.
 The model is prefilled in chunks (:meth:`LayeredDecoder.prefill_chunk`,
 ``chunked``): each chunk carries the states and the pages on from where the
 last one left them, so the server can run decode steps of the resident
 streams between two chunks of a joining prompt. ``recurrent`` says whether
-a slot also holds a state (a lightning layer). ``TransformerLM`` stays as
-it is for GPT-2-style models (ROADMAP
-D6)."""
+a slot also holds a state (a lightning or a kda layer). ``TransformerLM``
+stays as it is for GPT-2-style models (ROADMAP D6)."""
 from __future__ import annotations
 
 import dataclasses
@@ -44,7 +53,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import grouped_attention, latent_attention, moe
+from ..ops import delta_attention, grouped_attention, latent_attention, moe
 from ..ops.decode import _page_positions, _paged_write, init_paged_pool
 from ..ops.linear_attention import (lightning_slopes, linear_attention_chunk,
                                     linear_attention_step)
@@ -56,6 +65,7 @@ from ..ops.sparse_attention import (SparseSpec, attend_chunk, attend_step,
 LINEAR, SPARSE = "lightning-attn", "minicpm4"
 FULL, WINDOW = "full", "window"
 LATENT = "mla-dsa"
+KDA = "kda"
 SILU, MOE = "silu", "moe"
 
 
@@ -106,9 +116,21 @@ class DecoderSpec:
     shared_width: int = 0          # a shared expert every token reads
     #: the sizes of the ``"mla-dsa"`` layers
     latent: Optional[latent_attention.LatentSpec] = None
+    #: the sizes of the ``"kda"`` layers
+    delta: Optional[delta_attention.DeltaSpec] = None
+    #: residual streams: 1 is ``x <- x + residual_scale * f(N(x))``; n > 1
+    #: keeps n streams and mixes them around every sublayer (mHC):
+    #: ``x <- H_res x + H_post^T f(H_pre x)``, ``H_res`` doubly stochastic
+    #: after ``hc_sinkhorn_iters`` normalisations of rows and columns
+    hc_streams: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    #: every gated-SiLU product clamped, ``silu(min(gate, L)) * clip(up,
+    #: -L, L)`` (0: none)
+    swiglu_limit: float = 0.0
 
     def __post_init__(self):
-        known = (LINEAR, SPARSE, FULL, WINDOW, LATENT)
+        known = (LINEAR, SPARSE, FULL, WINDOW, LATENT, KDA)
         unknown = set(self.mixers) - set(known)
         if unknown:
             raise ValueError(f"no mixer named {sorted(unknown)}; have "
@@ -131,6 +153,14 @@ class DecoderSpec:
                              f"silu) is not known")
         if LATENT in self.mixers and self.latent is None:
             raise ValueError("an mla-dsa layer needs its sizes (latent)")
+        if KDA in self.mixers and self.delta is None:
+            raise ValueError("a kda layer needs its sizes (delta)")
+        if self.hc_streams > 1 and (self.residual_scale != 1.0
+                                    or self.router != "sigmoid"
+                                    and self.has_experts):
+            raise ValueError("several residual streams are wired for a "
+                             "residual scale of 1 and a router that reads "
+                             "the normed output of attention")
         if WINDOW in self.mixers and (self.window < 1
                                       or self.window % self.page):
             raise ValueError(f"a window layer's window must be whole pages "
@@ -166,7 +196,11 @@ class DecoderSpec:
         and the ``moe_*`` keys; ``page_len`` is the K/V page) or a
         ``glm_moe_dsa`` one (``kv_lora_rank``, ``index_*``,
         ``first_k_dense_replace``, ``n_routed_experts`` with
-        ``held_experts`` where the chip holds a share)."""
+        ``held_experts`` where the chip holds a share) or a
+        ``glm5_next_text`` one (the same, with ``layer_types``,
+        ``mlp_layer_types``, ``linear_attn_config``, ``mhc`` with
+        ``hc_mult``, ``mla_use_nope``, ``index_kpool`` and
+        ``swiglu_limit``)."""
         if "kv_lora_rank" in cfg:
             return cls._from_latent(cfg, max_len, page_len)
         if "sliding_window_layout" in cfg:
@@ -215,17 +249,52 @@ class DecoderSpec:
     def _from_latent(cls, cfg, max_len, page_len):
         n, dense = cfg["num_hidden_layers"], cfg["first_k_dense_replace"]
         held = cfg.get("held_experts")
+        mixers, ffns = (LATENT,) * n, (SILU,) * dense + (MOE,) * (n - dense)
+        if "layer_types" in cfg:
+            named = {"linear_attention": KDA,
+                     "deepseek_sparse_attention": LATENT}
+            mlp = {"dense": SILU, "sparse": MOE}
+            unknown = (set(cfg["layer_types"]) - set(named)) | (
+                set(cfg["mlp_layer_types"]) - set(mlp))
+            if unknown or len(cfg["layer_types"]) != n:
+                raise ValueError(f"layer_types and mlp_layer_types must name "
+                                 f"{n} layers of {sorted(named)} and "
+                                 f"{sorted(mlp)}; got {sorted(unknown)}")
+            mixers = tuple(named[t] for t in cfg["layer_types"])
+            ffns = tuple(mlp[t] for t in cfg["mlp_layer_types"])
+        lin = cfg.get("linear_attn_config")
+        delta = None if lin is None else delta_attention.DeltaSpec(
+            heads=lin["num_heads"], head_dim=lin["head_dim"],
+            conv=lin["short_conv_kernel_size"],
+            gate_lower_bound=lin["gate_lower_bound"], rank=lin["head_dim"])
+        more = {}
+        if cfg.get("mhc"):
+            more.update(hc_streams=cfg["hc_mult"],
+                        hc_sinkhorn_iters=cfg["hc_sinkhorn_iters"],
+                        hc_eps=cfg["hc_eps"])
+        if cfg.get("swiglu_limit"):
+            more["swiglu_limit"] = float(cfg["swiglu_limit"])
+        pooled = {}
+        if cfg.get("index_kpool", 1) > 1:
+            if not (cfg.get("index_kpool_compress")
+                    and cfg.get("index_kpool_always_select_tail")):
+                raise ValueError("pooled index keys are wired as their mean "
+                                 "with the query's own block always read "
+                                 "(index_kpool_compress and "
+                                 "index_kpool_always_select_tail)")
+            pooled = dict(index_kpool=cfg["index_kpool"])
         return cls(
             vocab_size=cfg["vocab_size"], hidden=cfg["hidden_size"],
             intermediate=cfg["intermediate_size"],
-            mixers=(LATENT,) * n, heads=cfg["num_attention_heads"],
+            mixers=mixers, heads=cfg["num_attention_heads"],
             kv_heads=cfg["num_key_value_heads"],
             head_dim=cfg["qk_head_dim"], linear_heads=0, linear_head_dim=0,
             max_len=max_len, rms_eps=cfg["rms_norm_eps"],
-            rope_theta=cfg["rope_parameters"]["rope_theta"],
+            rope_theta=cfg.get("rope_parameters", {}).get(
+                "rope_theta", 10000.0),
             param_dtype=cfg.get("param_dtype", "bfloat16"),
             init_std=cfg.get("initializer_range", 0.02),
-            ffns=(SILU,) * dense + (MOE,) * (n - dense),
+            ffns=ffns, delta=delta, **more,
             experts=cfg.get("n_routed_experts_published",
                             cfg["n_routed_experts"]),
             experts_per_token=cfg["num_experts_per_tok"],
@@ -244,7 +313,7 @@ class DecoderSpec:
                 index_heads=cfg["index_n_heads"],
                 index_dim=cfg["index_head_dim"],
                 index_topk=cfg["index_topk"],
-                index_rope_dim=cfg["qk_rope_head_dim"]))
+                index_rope_dim=cfg["qk_rope_head_dim"], **pooled))
 
     def layer_shapes(self, kind: str, ffn: Optional[str] = None):
         """``(matrices, vectors that start at 1)`` of a layer of mixer
@@ -255,6 +324,9 @@ class DecoderSpec:
         ones = {"norm1": (d,), "norm2": (d,)}
         if kind == LATENT:
             mats, more, _ = self.latent.shapes(d)
+            mats, ones = dict(mats), dict(ones, **more)
+        elif kind == KDA:
+            mats, more, _ = self.delta.shapes(d)
             mats, ones = dict(mats), dict(ones, **more)
         else:
             if kind == LINEAR:
@@ -281,15 +353,27 @@ class DecoderSpec:
                             shared_down=(self.shared_width, d))
         else:
             mats.update(gate_proj=(d, f), up_proj=(d, f), down_proj=(f, d))
+        n = self.hc_streams
+        if n > 1:   # each sublayer's mHC: pre, post and the n x n mix
+            for part in ("attn", "ffn"):
+                mats[f"hc_{part}_phi"] = (n * d, n * (n + 2))
+                ones[f"hc_{part}_alpha"] = (3,)
         return mats, ones
 
     def layer_zeros(self, kind: str, ffn: Optional[str] = None):
         """The vectors of such a layer that start at 0: the indexer's key
-        norm's bias, a sigmoid router's correction bias."""
+        norm's bias, a kda layer's ``A_log`` and decay bias, a sigmoid
+        router's correction bias, the mHC biases."""
         zeros = dict(self.latent.shapes(self.hidden)[2]) \
             if kind == LATENT else {}
+        if kind == KDA:
+            zeros.update(self.delta.shapes(self.hidden)[2])
         if (ffn or self.ffn) == MOE and self.router == "sigmoid":
             zeros["router_bias"] = (self.experts,)
+        n = self.hc_streams
+        if n > 1:
+            for part in ("attn", "ffn"):
+                zeros[f"hc_{part}_bias"] = (n * (n + 2),)
         return zeros
 
 
@@ -328,9 +412,9 @@ class LayeredDecoder:
     def __init__(self, spec: DecoderSpec, seed: int = 0,
                  prefill_chunk: int = 2048):
         self.spec = spec
-        #: a slot holds a state beside its pages (a lightning layer): the
-        #: server refuses what would need a snapshot of it
-        self.recurrent = LINEAR in spec.mixers
+        #: a slot holds a state beside its pages (a lightning or a kda
+        #: layer): the server refuses what would need a snapshot of it
+        self.recurrent = LINEAR in spec.mixers or KDA in spec.mixers
         #: positions a window layer sees, 0 where the model has none: the
         #: server then keeps a second page budget (:meth:`window_pages`)
         self.window_len = spec.window if WINDOW in spec.mixers else 0
@@ -342,7 +426,9 @@ class LayeredDecoder:
              if spec.has_experts else ())
             + (("sparse_positions_read",)
                if reads or not spec.has_experts else ())
-            + (("dsa_positions_scored",) if LATENT in spec.mixers else ()))
+            + (((("dsa_blocks_scored",) if spec.latent.index_kpool > 1
+                 else ("dsa_positions_scored",)))
+               if LATENT in spec.mixers else ()))
         #: a prompt is fed in chunks of ``prefill_chunk`` positions, the
         #: last padded to one of these: a closed set of compiled programs
         self.chunk_buckets = (prefill_chunk // 4, prefill_chunk // 2,
@@ -412,7 +498,8 @@ class LayeredDecoder:
         pool with its compressed-key pool, a full layer's K/V page pool
         (``num_pages`` each), a window layer's (:meth:`window_pages`), a
         lightning layer's state ``[slots, H, D, D]`` in float32, a latent
-        layer's latent pool with its index-key pool (``num_pages`` each)."""
+        layer's latent pool with its index-key pool (``num_pages`` each), a
+        kda layer's state ``[slots, H, D, D]`` and convolution window."""
         spec = self.spec
         if int8:
             raise NotImplementedError(
@@ -435,6 +522,9 @@ class LayeredDecoder:
             elif kind == LATENT:
                 caches.append(latent_attention.init_latent_pool(
                     num_pages, page_len, spec.latent, dtype))
+            elif kind == KDA:
+                caches.append(delta_attention.init_delta_cache(
+                    slots, spec.delta))
             else:
                 caches.append({"state": jnp.zeros(
                     (slots, spec.linear_heads, spec.linear_head_dim,
@@ -454,14 +544,119 @@ class LayeredDecoder:
 
     # -- the block ------------------------------------------------------------
 
-    def _ffn(self, p, x):
+    def _residual(self, x, y):
+        """``x + residual_scale * y``; ``y`` alone where ``x`` is ``None``
+        (several residual streams: the mix adds it, :meth:`_hc_leave`)."""
+        return y if x is None else x + self.spec.residual_scale * y
+
+    def _ffn(self, p, x, residual=True):
+        """``x + ffn(N(x))``, or ``ffn(N(x))`` alone (several residual
+        streams: the mix adds it)."""
         spec = self.spec
         with jax.named_scope("layer_norm"):
             h = _rms_norm(p["norm2"], x, spec.rms_eps)
         with jax.named_scope("ffn"):
-            mid = jax.nn.silu(_product(h, p["gate_proj"])) \
-                * _product(h, p["up_proj"])
-            return x + spec.residual_scale * _product(mid, p["down_proj"])
+            if spec.swiglu_limit:
+                mid = moe.swiglu(_product(h, p["gate_proj"]),
+                                 _product(h, p["up_proj"]), spec.swiglu_limit)
+            else:
+                mid = jax.nn.silu(_product(h, p["gate_proj"])) \
+                    * _product(h, p["up_proj"])
+            return self._residual(x if residual else None,
+                                  _product(mid, p["down_proj"]))
+
+    # -- several residual streams (mHC) ---------------------------------------
+
+    def _hc_enter(self, p, part, x):
+        """``(input of the sublayer, what its output is added to, the
+        mix)``: with one stream ``(x, x, None)``; with n, ``x [N, n, d]``
+        gives ``H_pre x [N, d]``, ``None`` and ``(H_post [N, n], H_res [N,
+        n, n])``, all from ``RMSNorm(vec x)`` through the part's ``phi``
+        (float32 products at highest), its ``alpha`` and its bias."""
+        spec, n = self.spec, self.spec.hc_streams
+        if n == 1:
+            return x, x, None
+        with jax.named_scope("mhc"):
+            flat = x.reshape(x.shape[0], -1)
+            flat = flat * jax.lax.rsqrt(
+                jnp.mean(jnp.square(flat), axis=-1, keepdims=True)
+                + spec.rms_eps)
+            coef = jnp.dot(flat, p[f"hc_{part}_phi"].astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST)
+            alpha, bias = p[f"hc_{part}_alpha"], p[f"hc_{part}_bias"]
+            pre = jax.nn.sigmoid(alpha[0] * coef[:, :n] + bias[:n])
+            post = 2.0 * jax.nn.sigmoid(alpha[1] * coef[:, n:2 * n]
+                                        + bias[n:2 * n])
+            res = jnp.exp(alpha[2] * coef[:, 2 * n:] + bias[2 * n:]).reshape(
+                -1, n, n)
+            # sums over n written as adds of slices: elementwise, so that
+            # the compiler fuses all the iterations into one pass
+            for _ in range(spec.hc_sinkhorn_iters):
+                res = res / (sum(res[:, :, j] for j in range(n))[:, :, None]
+                             + spec.hc_eps)
+                res = res / (sum(res[:, i] for i in range(n))[:, None]
+                             + spec.hc_eps)
+            h = sum(pre[:, i, None] * x[:, i] for i in range(n))
+        return h, None, (post, res)
+
+    def _hc_leave(self, x, y, mix):
+        """The residual after a sublayer whose output is ``y``: ``y`` itself
+        with one stream (the sublayer added it), else ``H_res x + H_post^T
+        y`` (float32, elementwise)."""
+        if mix is None:
+            return y
+        post, res = mix
+        with jax.named_scope("mhc"):
+            mixed = sum(res[:, :, j, None] * x[:, None, j]
+                        for j in range(self.spec.hc_streams))
+            return mixed + post[..., None] * y[:, None]
+
+    def _hc_streams(self, x):
+        """The embedding copied into every stream."""
+        n = self.spec.hc_streams
+        if n == 1:
+            return x
+        with jax.named_scope("mhc"):
+            return jnp.broadcast_to(x[:, None], (x.shape[0], n, x.shape[1]))
+
+    # -- kda layers -----------------------------------------------------------
+
+    def _delta_project(self, p, u):
+        """The q, k, v projections side by side ``[N, 3C]`` (before their
+        convolution), the write strength ``b [N, H]``, the log-decay ``g
+        [N, H, D] = lower_bound * sigmoid(exp(A_log) (W_f2 W_f1 u +
+        dt_bias))`` and the output gate ``sigmoid(W_g2 W_g1 u) [N, C]``."""
+        dl = self.spec.delta
+        n = u.shape[0]
+        with jax.named_scope("attention"):
+            qkv = _product(u, p["qkv"])
+            beta = jax.nn.sigmoid(_product(u, p["beta"]))
+            f = _product(_product(u, p["f_a"]), p["f_b"]) + p["dt_bias"]
+            g = dl.gate_lower_bound * jax.nn.sigmoid(
+                jnp.exp(p["A_log"])[:, None]
+                * f.reshape(n, dl.heads, dl.head_dim))
+            gate = jax.nn.sigmoid(_product(_product(u, p["g_a"]), p["g_b"]))
+        return qkv, beta, g, gate
+
+    def _delta_heads(self, mixed):
+        """q (L2-normalised, scaled by ``D^-1/2``), k (L2-normalised) and v
+        ``[N, H, D]`` from the convolved projections."""
+        dl = self.spec.delta
+        with jax.named_scope("attention"):
+            q, k, v = (part.reshape(-1, dl.heads, dl.head_dim)
+                       for part in jnp.split(mixed, 3, axis=-1))
+
+            def l2(a):
+                return a * jax.lax.rsqrt(
+                    jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
+            return l2(q) / math.sqrt(dl.head_dim), l2(k), v
+
+    def _delta_out(self, p, x, o, gate):
+        """``x + W_o (RMSNorm_head(o) * gate)``."""
+        with jax.named_scope("attention"):
+            o = _rms_norm(p["o_norm"], o, self.spec.rms_eps)
+            return self._residual(
+                x, _product(o.reshape(o.shape[0], -1) * gate, p["o"]))
 
     def _project(self, p, u, heads, kv_heads, head_dim, positions):
         """q (scaled), k, v of ``u [..., d]`` as ``[..., heads, head_dim]``;
@@ -483,7 +678,7 @@ class LayeredDecoder:
     def _mix_out(self, p, x, u, o):
         """``x + c W_o (o * sigmoid(W_g u))``, ``o [..., H*D]``."""
         gate = jax.nn.sigmoid(_product(u, p["g"]))
-        return x + self.spec.residual_scale * _product(o * gate, p["o"])
+        return self._residual(x, _product(o * gate, p["o"]))
 
     def _attend_paged(self, kind, p, x, u, cache, table, positions, attend):
         """A full or window layer over ``u [T, d]``: project, write the new
@@ -503,13 +698,14 @@ class LayeredDecoder:
                              inline_amax=False)
         o = attend(q, cache)
         with jax.named_scope("attention"):
-            x = x + spec.residual_scale * _product(
-                o.reshape(o.shape[0], -1), p["o"])
+            x = self._residual(x, _product(o.reshape(o.shape[0], -1),
+                                           p["o"]))
         return x, cache
 
-    def _moe(self, p, x, valid, routed=None):
+    def _moe(self, p, x, valid, routed=None, residual=True):
         """``x + experts(h)`` (and the shared expert's ``shared(h)`` where
-        the model has one), ``h = N(x)``, under the routing ``routed``
+        the model has one; the sum alone where ``residual`` is false), ``h
+        = N(x)``, under the routing ``routed``
         (``(choice, gates)``, made from the layer's input before
         attention) or, where there is none, a routing made from ``h``
         itself; also each held expert's assignments."""
@@ -522,18 +718,21 @@ class LayeredDecoder:
         y, sizes = moe.experts(
             h, choice, gates, p["w_gate"], p["w_up"], p["w_down"],
             spec.held_experts, valid,
-            jax.nn.silu if spec.expert_act == "silu" else jax.nn.relu)
+            jax.nn.silu if spec.expert_act == "silu" else jax.nn.relu,
+            spec.swiglu_limit)
         if spec.shared_width:
             y = y + moe.shared(h, p["shared_gate"], p["shared_up"],
-                               p["shared_down"])
-        return x + spec.residual_scale * y, sizes
+                               p["shared_down"], spec.swiglu_limit)
+        return self._residual(x if residual else None, y), sizes
 
-    def _attend_latent(self, p, x, u, cache, table, positions, attend):
+    def _attend_latent(self, p, x, u, cache, table, positions, attend,
+                       real=None):
         """An ``mla-dsa`` layer over ``u [N, d]`` at ``positions`` (``[S,
-        1]`` of a step, ``[1, T]`` of a chunk): the latent products, the
-        row and the index key written through ``table``, the indexer, the
-        selection and the read (``attend(q_nope, q_rope, q_index, w_index,
-        cache)`` gives ``(o, stats)``), the output product."""
+        1]`` of a step, ``[1, T]`` of a chunk, whose ``real`` positions
+        alone add to a pooled index key): the latent products, the row and
+        the index key written through ``table``, the indexer, the selection
+        and the read (``attend(q_nope, q_rope, q_index, w_index, cache)``
+        gives ``(o, stats)``), the output product."""
         spec = self.spec
         lat, at = spec.latent, positions.reshape(-1)
         q_nope, q_rope, row, c_q = latent_attention.project(
@@ -543,10 +742,11 @@ class LayeredDecoder:
         pages, offs = _page_positions(table, positions, self.page_len)
         cache = latent_attention.write(
             cache, pages, offs, row.reshape(positions.shape + (-1,)),
-            k_i.reshape(positions.shape + (-1,)))
+            k_i.reshape(positions.shape + (-1,)),
+            real if lat.index_kpool > 1 else None)
         o, stats = attend(q_nope, q_rope, q_i, w_i, cache)
         with jax.named_scope("mla_project"):
-            x = x + spec.residual_scale * _product(o, p["o"])
+            x = self._residual(x, _product(o, p["o"]))
         return x, cache, stats
 
     def _embed(self, params, tokens):
@@ -579,27 +779,38 @@ class LayeredDecoder:
             active = jnp.ones(tokens.shape, bool)
         table, window_table = table if isinstance(table, (tuple, list)) \
             else (table, table)
-        x = self._embed(params, tokens)                         # [S, d]
+        x = self._hc_streams(self._embed(params, tokens))   # [S, (n,) d]
         new_caches, reads, loads, scored = [], [], [], []
         for kind, ffn, p, cache in zip(spec.mixers, spec.layer_ffns,
                                        params["layers"], caches):
             # a softmax router sees the layer's input as it is
             routed = moe.route(x, p["router"], spec.experts_per_token) \
                 if ffn == MOE and spec.router == "softmax" else None
+            streams = x
+            x, base, mix = self._hc_enter(p, "attn", streams)
             with jax.named_scope("layer_norm"):
                 u = _rms_norm(p["norm1"], x, spec.rms_eps)
             if kind == LATENT:
                 x, cache, (read, seen) = self._attend_latent(
-                    p, x, u, cache, table, lengths[:, None],
+                    p, base, u, cache, table, lengths[:, None],
                     lambda *q: self._latent_step(p, *q, table, lengths,
                                                  active))
                 reads.append(read)
                 scored.append(seen)
+            elif kind == KDA:
+                qkv, beta, g, gate = self._delta_project(p, u)
+                mixed, window = delta_attention.conv_step(
+                    qkv, cache["conv"], p["conv"], active)
+                q, k, v = self._delta_heads(mixed)
+                o, state = delta_attention.delta_attention_step(
+                    q, k, v, g, beta, cache["state"], active)
+                cache = {"state": state, "conv": window}
+                x = self._delta_out(p, base, o, gate)
             elif kind in (FULL, WINDOW):
                 window = spec.window if kind == WINDOW else None
                 tab = window_table if kind == WINDOW else table
                 x, cache = self._attend_paged(
-                    kind, p, x, u, cache, tab, lengths[:, None],
+                    kind, p, base, u, cache, tab, lengths[:, None],
                     lambda q, c: grouped_attention.attend_step(
                         q, c, tab, lengths, active, window))
             elif kind == LINEAR:
@@ -614,7 +825,7 @@ class LayeredDecoder:
                 with jax.named_scope("attention"):
                     o = _rms_norm(p["o_norm"], o.reshape(o.shape[0], -1),
                                   spec.rms_eps)
-                    x = self._mix_out(p, x, u, o)
+                    x = self._mix_out(p, base, u, o)
             else:
                 with jax.named_scope("attention"):
                     q, k, v = self._project(p, u, spec.heads, spec.kv_heads,
@@ -635,12 +846,16 @@ class LayeredDecoder:
                                       active, blocks, valid)
                 reads.append(read)
                 with jax.named_scope("attention"):
-                    x = self._mix_out(p, x, u, o.reshape(s, -1))
+                    x = self._mix_out(p, base, u, o.reshape(s, -1))
+            x = self._hc_leave(streams, x, mix)
+            streams = x
+            x, _, mix = self._hc_enter(p, "ffn", streams)
             if ffn == MOE:
-                x, sizes = self._moe(p, x, active, routed)
+                x, sizes = self._moe(p, x, active, routed, mix is None)
                 loads.append(moe.load_stats(sizes))
             else:
-                x = self._ffn(p, x)
+                x = self._ffn(p, x, mix is None)
+            x = self._hc_leave(streams, x, mix)
             new_caches.append(cache)
         parts = []  # what :attr:`step_stats` names, in its order
         if loads:
@@ -664,17 +879,25 @@ class LayeredDecoder:
         means over the active slots)."""
         lat = self.spec.latent
         scores = latent_attention.index_step(
-            lat, q_i, w_i, cache["index"], table, lengths, active)
-        at, real = latent_attention.select_step(lat, scores)
+            lat, q_i, w_i, cache["index"], table, lengths, active,
+            cache["latent"].dtype)
+        at, real = latent_attention.select_step(lat, scores, lengths)
         o = latent_attention.attend_step(lat, p, q_nope, q_rope,
                                          cache["latent"], table, at, real)
         live = jnp.maximum(jnp.sum(active), 1).astype(jnp.float32)
         read = jnp.sum(jnp.where(active[:, None], real, False)) / live
-        seen = jnp.sum(jnp.where(active, lengths + 1, 0)) / live
+        # positions scored (the context), or the pooled blocks wholly
+        # before the query
+        seen = lengths + 1 if lat.index_kpool == 1 \
+            else lengths // lat.index_kpool
+        seen = jnp.sum(jnp.where(active, seen, 0)) / live
         return o, (read, seen.astype(jnp.float32))
 
     def _head(self, params, x):
         spec = self.spec
+        if spec.hc_streams > 1:   # the streams summed before the final norm
+            with jax.named_scope("mhc"):
+                x = jnp.sum(x, axis=1)
         with jax.named_scope("layer_norm"):
             x = _rms_norm(params["norm_f"], x, spec.rms_eps) \
                 / spec.logit_divisor
@@ -683,7 +906,7 @@ class LayeredDecoder:
                               params["head"],
                               preferred_element_type=jnp.float32)
 
-    # -- prefill: a chunk of one stream's prompt --------------------------------
+    # -- prefill: a chunk of one stream's prompt ------------------------------
 
     def chunk_plan(self, fed: int) -> List[Tuple[int, int]]:
         """``[(start, padded length)]`` of the chunks that prefill ``fed``
@@ -716,22 +939,29 @@ class LayeredDecoder:
         row, window_row = row if isinstance(row, (tuple, list)) \
             else (row, row)
         real = jnp.arange(t) < n_valid
+        x = self._hc_streams(x)
         new_caches = []
         for kind, ffn, p, cache in zip(spec.mixers, spec.layer_ffns,
                                        params["layers"], caches):
             routed = moe.route(x, p["router"], spec.experts_per_token) \
                 if ffn == MOE and spec.router == "softmax" else None
+            streams = x
+            x, base, mix = self._hc_enter(p, "attn", streams)
             with jax.named_scope("layer_norm"):
                 u = _rms_norm(p["norm1"], x, spec.rms_eps)
             if kind == LATENT:
                 x, cache, _ = self._attend_latent(
-                    p, x, u, cache, row[None], positions[None],
-                    lambda *q: (self._latent_chunk(p, *q, row, start), None))
+                    p, base, u, cache, row[None], positions[None],
+                    lambda *q: (self._latent_chunk(p, *q, row, start), None),
+                    real)
+            elif kind == KDA:
+                x, cache = self._delta_chunk(p, base, u, cache, slot, start,
+                                             n_valid)
             elif kind in (FULL, WINDOW):
                 window = spec.window if kind == WINDOW else None
                 pages = window_row if kind == WINDOW else row
                 x, cache = self._attend_paged(
-                    kind, p, x, u, cache, pages[None], positions[None],
+                    kind, p, base, u, cache, pages[None], positions[None],
                     lambda q, c: grouped_attention.attend_chunk(
                         q, c, pages, start, window))
             elif kind == LINEAR:
@@ -752,7 +982,7 @@ class LayeredDecoder:
                 with jax.named_scope("attention"):
                     o = _rms_norm(p["o_norm"], o.reshape(t, -1),
                                   spec.rms_eps)
-                    x = self._mix_out(p, x, u, o)
+                    x = self._mix_out(p, base, u, o)
             else:
                 with jax.named_scope("attention"):
                     q, k, v = self._project(p, u, spec.heads, spec.kv_heads,
@@ -769,20 +999,47 @@ class LayeredDecoder:
                 allowed = select_chunk(spec.sparse, q, kc, row, start)
                 o = attend_chunk(spec.sparse, q, cache, row, start, allowed)
                 with jax.named_scope("attention"):
-                    x = self._mix_out(p, x, u, o.reshape(t, -1))
+                    x = self._mix_out(p, base, u, o.reshape(t, -1))
+            x = self._hc_leave(streams, x, mix)
+            streams = x
+            x, _, mix = self._hc_enter(p, "ffn", streams)
             if ffn == MOE:
-                x, _ = self._moe(p, x, real, routed)
+                x, _ = self._moe(p, x, real, routed, mix is None)
             else:
-                x = self._ffn(p, x)
+                x = self._ffn(p, x, mix is None)
+            x = self._hc_leave(streams, x, mix)
             new_caches.append(cache)
         return new_caches
+
+    def _delta_chunk(self, p, x, u, cache, slot, start, n_valid):
+        """A kda layer over a chunk: the slot's state and window carried on
+        from the chunk before (a chunk at 0 starts both anew)."""
+        dl = self.spec.delta
+        qkv, beta, g, gate = self._delta_project(p, u)
+        with jax.named_scope("delta_conv"):
+            window = jnp.where(start == 0, 0.0, jax.lax.dynamic_index_in_dim(
+                cache["conv"], slot, 0, False))
+        mixed, window = delta_attention.conv_chunk(qkv, window, p["conv"],
+                                                   n_valid)
+        q, k, v = self._delta_heads(mixed)
+        with jax.named_scope("delta_attn"):
+            before = jnp.where(start == 0, 0.0, jax.lax.dynamic_index_in_dim(
+                cache["state"], slot, 0, False))
+        o, after = delta_attention.delta_attention_chunk(
+            q, k, v, g, beta, before, n_valid, dl)
+        with jax.named_scope("delta_attn"):
+            cache = {"state": jax.lax.dynamic_update_index_in_dim(
+                         cache["state"], after, slot, 0),
+                     "conv": jax.lax.dynamic_update_index_in_dim(
+                         cache["conv"], window, slot, 0)}
+        return self._delta_out(p, x, o, gate), cache
 
     def _latent_chunk(self, p, q_nope, q_rope, q_i, w_i, cache, row, start):
         """The indexer, the selection and the plain masked read of a chunk's
         queries, each over its own selection."""
         lat = self.spec.latent
         bits = latent_attention.index_chunk(lat, q_i, w_i, cache["index"],
-                                            row, start)
+                                            row, start, cache["latent"].dtype)
         threshold, last = latent_attention.select_chunk(lat, bits, start)
         return latent_attention.attend_chunk(
             lat, p, q_nope, q_rope, cache["latent"], row, start, bits,

@@ -59,7 +59,8 @@ _HISTOGRAM_UNITLESS_OK = {"serving.sparse_positions_read",
                            "serving.paged_pages_read",
                            "serving.moe_experts_touched",
                            "serving.moe_expert_load",
-                           "serving.dsa_positions_scored"}
+                           "serving.dsa_positions_scored",
+                           "serving.dsa_blocks_scored"}
 
 
 def _is_registration(node: ast.Call) -> bool:

@@ -43,6 +43,15 @@ position, so a row's mask is ``score > threshold or (score == threshold
 and position <= last)``: the set ``lax.top_k`` would return, never an
 approximation of it.
 
+A layer may have no rotary part (``rope_dim`` and ``index_rope_dim`` 0:
+the row is ``c_kv`` alone) and may pool its index keys (``index_kpool``):
+the index pool then holds the float32 sum of each whole block of
+``index_kpool`` positions' keys (a decode step adds its key to its block's
+sum, a chunk writes whole blocks), scores are taken against the blocks'
+means for the blocks wholly before the query, and the selection is the
+``index_blocks`` best blocks and the block that holds the query, whose
+positions up to the query's own are read.
+
 Scopes on the device (docs/observability.md): ``mla_project`` (the latent
 products with their norms and rotary), ``dsa_index`` (the indexer's
 products and scores), ``dsa_select`` (the top-k), ``mla_attend`` (the
@@ -79,6 +88,10 @@ class LatentSpec:
     index_topk: int = 2048
     index_rope_dim: int = 64
     index_norm_eps: float = 1e-6
+    #: positions an index key stands for (their sum, scored as their mean;
+    #: 1: one a position); over pooled keys the block that holds the query
+    #: is read whatever its score, and is never scored
+    index_kpool: int = 1
     #: positions of a tile of the decode step's scoring loop, of a chunk's
     #: scoring and selection loops, and of a chunk's attention loop
     step_tile: int = 2560
@@ -90,6 +103,16 @@ class LatentSpec:
                 or self.index_rope_dim > self.index_dim:
             raise ValueError("rotary widths must be even and lie inside "
                              "the head")
+        if self.index_kpool < 1 or self.index_topk % self.index_kpool:
+            raise ValueError(f"index_topk {self.index_topk} is no whole "
+                             f"number of pooled blocks of {self.index_kpool}")
+
+    @property
+    def index_blocks(self) -> int:
+        """Pooled blocks the selection takes by score: ``index_topk`` in
+        blocks, less the query's own block, which is read anyway, so that
+        at most ``index_topk`` positions are read."""
+        return self.index_topk // self.index_kpool - 1
 
     @property
     def row(self) -> int:
@@ -131,10 +154,20 @@ def init_latent_pool(num_pages: int, page_len: int, spec: LatentSpec,
                      dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
     """The latent pool ``[P, page_len, pool_row]`` (``kv_rank + rope_dim``
     numbers a position, in whole tiles of 128 lanes) and the index key
-    pool ``[P, page_len, index_dim]``; page 0 is the null page."""
+    pool ``[P, page_len, index_dim]``, or, where keys are pooled, ``[P,
+    page_len / index_kpool, index_dim]`` float32 sums of a block's keys;
+    page 0 is the null page."""
     if num_pages < 2:
         raise ValueError(f"num_pages must be >= 2 (page 0 is the reserved "
                          f"null page), got {num_pages}")
+    if spec.index_kpool > 1:
+        if page_len % spec.index_kpool:
+            raise ValueError(f"a page of {page_len} is no whole number of "
+                             f"pooled blocks of {spec.index_kpool}")
+        return {"latent": jnp.zeros((num_pages, page_len, spec.pool_row),
+                                    dtype),
+                "index": jnp.zeros((num_pages, page_len // spec.index_kpool,
+                                    spec.index_dim), jnp.float32)}
     return {"latent": jnp.zeros((num_pages, page_len, spec.pool_row), dtype),
             "index": jnp.zeros((num_pages, page_len, spec.index_dim), dtype)}
 
@@ -185,9 +218,13 @@ def project(spec: LatentSpec, p, u: jax.Array, positions: jax.Array,
     c_q = _rms_norm(p["q_a_norm"], _product(u, p["q_a"]), eps)
     q = _product(c_q, p["q_b"]).reshape(n, spec.heads, spec.qk_dim)
     q = q / math.sqrt(spec.qk_dim)
-    q_rope = rotary_interleaved(q[..., spec.nope_dim:], positions, theta)
+    q_rope = q[..., spec.nope_dim:]
+    if spec.rope_dim:
+        q_rope = rotary_interleaved(q_rope, positions, theta)
     kv = _product(u, p["kv_a"])
     c_kv = _rms_norm(p["kv_a_norm"], kv[:, :spec.kv_rank], eps)
+    if not spec.rope_dim:   # no rotary part: the row is the latent alone
+        return q[..., :spec.nope_dim], q_rope, c_kv, c_q
     k_rope = rotary_interleaved(kv[:, spec.kv_rank:], positions, theta)
     return (q[..., :spec.nope_dim], q_rope,
             jnp.concatenate([c_kv, k_rope], axis=-1), c_q)
@@ -208,10 +245,11 @@ def index_project(spec: LatentSpec, p, u: jax.Array, c_q: jax.Array,
     k = (k - mean) * lax.rsqrt(
         jnp.mean(jnp.square(k - mean), axis=-1, keepdims=True)
         + spec.index_norm_eps) * p["index_k_norm"] + p["index_k_bias"]
-    q = jnp.concatenate([rotary_interleaved(q[..., :r], positions, theta),
-                         q[..., r:]], axis=-1)
-    k = jnp.concatenate([rotary_interleaved(k[..., :r], positions, theta),
-                         k[..., r:]], axis=-1)
+    if r:
+        q = jnp.concatenate([rotary_interleaved(q[..., :r], positions,
+                                                theta), q[..., r:]], axis=-1)
+        k = jnp.concatenate([rotary_interleaved(k[..., :r], positions,
+                                                theta), k[..., r:]], axis=-1)
     w = _product(u, p["index_w"]) / math.sqrt(
         spec.index_heads * spec.index_dim)
     return q, k, w
@@ -219,16 +257,35 @@ def index_project(spec: LatentSpec, p, u: jax.Array, c_q: jax.Array,
 
 @jax.named_scope("kv_write")
 def write(cache: Dict[str, jax.Array], pages: jax.Array, offs: jax.Array,
-          rows: jax.Array, keys: jax.Array) -> Dict[str, jax.Array]:
+          rows: jax.Array, keys: jax.Array,
+          real: Optional[jax.Array] = None) -> Dict[str, jax.Array]:
     """Scatter the positions' latent ``rows`` and index ``keys`` (leading
     axes as ``pages`` / ``offs``) into their pools, rounded to the pools'
-    dtype."""
+    dtype. Where keys are pooled, a key is added to its block's sum (the
+    first position of a block starts it): a decode step's ``[S, 1]``
+    positions one at a time, a chunk's ``[1, T]`` (whole blocks from a
+    block's start; ``real [T]`` leaves its padding out) a block at a
+    time."""
     pad = cache["latent"].shape[-1] - rows.shape[-1]
     rows = jnp.pad(rows, ((0, 0),) * (rows.ndim - 1) + ((0, pad),))
-    return {"latent": cache["latent"].at[pages, offs].set(
-                rows.astype(cache["latent"].dtype)),
-            "index": cache["index"].at[pages, offs].set(
-                keys.astype(cache["index"].dtype))}
+    latent = cache["latent"].at[pages, offs].set(
+        rows.astype(cache["latent"].dtype))
+    kpool = cache["latent"].shape[1] // cache["index"].shape[1]
+    if kpool == 1:
+        return {"latent": latent, "index": cache["index"].at[pages, offs].set(
+            keys.astype(cache["index"].dtype))}
+    index, keys = cache["index"], keys.astype(jnp.float32)
+    if pages.shape[1] == 1:
+        old = index[pages, offs // kpool]
+        keys = jnp.where((offs % kpool == 0)[..., None], keys, old + keys)
+        return {"latent": latent,
+                "index": index.at[pages, offs // kpool].set(keys)}
+    if real is not None:
+        keys = jnp.where(real[:, None], keys, 0.0)
+    b, t = pages.shape
+    keys = jnp.sum(keys.reshape(b, t // kpool, kpool, -1), axis=2)
+    return {"latent": latent, "index": index.at[
+        pages[:, ::kpool], offs[:, ::kpool] // kpool].set(keys)}
 
 
 def _tile_pages(tile: int, page_len: int, width: int) -> int:
@@ -258,12 +315,16 @@ def _pages_of(rows: jax.Array, i, tile_pages: int) -> jax.Array:
 @jax.named_scope("dsa_index")
 def index_step(spec: LatentSpec, q: jax.Array, w: jax.Array,
                index_pool: jax.Array, table: jax.Array, lengths: jax.Array,
-               active: jax.Array) -> jax.Array:
+               active: jax.Array, dtype=None) -> jax.Array:
     """Index scores of every slot's query (at position ``lengths[s]``, its
     own key written) against the positions ``0 .. lengths[s]``: ``[S, L]``
     float32, ``-inf`` past the slot's length. Tiles of pages from the first
     to the longest live stream's last; ``L`` is whole tiles over the
-    table's width."""
+    table's width. Over pooled keys see :func:`_index_step_pooled`
+    (``dtype``: the products' operands)."""
+    if spec.index_kpool > 1:
+        return _index_step_pooled(spec, q, w, index_pool, table, lengths,
+                                  active, dtype or q.dtype)
     s = q.shape[0]
     page_len = index_pool.shape[1]
     tp = _tile_pages(spec.step_tile, page_len, table.shape[1])
@@ -285,15 +346,56 @@ def index_step(spec: LatentSpec, q: jax.Array, w: jax.Array,
                          jnp.full((s, n_max * tile), -jnp.inf, jnp.float32))
 
 
+def _index_step_pooled(spec, q, w, index_pool, table, lengths, active,
+                       dtype):
+    """:func:`index_step` over pooled keys: each slot's scores of the
+    blocks wholly before its query, ``[S, L / index_kpool]``, ``-inf``
+    elsewhere (the block that holds the query is not scored)."""
+    s, kpool = q.shape[0], spec.index_kpool
+    per_page = index_pool.shape[1]                    # blocks a page
+    page_len = per_page * kpool
+    tp = _tile_pages(spec.step_tile, page_len, table.shape[1])
+    tile = tp * per_page                              # blocks a tile
+    n_max = -(-table.shape[1] // tp)
+    qc = q.astype(dtype)
+    whole = lengths // kpool
+    longest = jnp.max(jnp.where(active, lengths, 0))
+
+    def body(i, scores):
+        keys = jnp.take(index_pool, _pages_of(table, i, tp), axis=0,
+                        mode="clip").reshape(s, tile, -1) * (1.0 / kpool)
+        dots = jnp.einsum("shd,snd->shn", qc, keys.astype(qc.dtype),
+                          preferred_element_type=jnp.float32)
+        got = jnp.einsum("shn,sh->sn", jax.nn.relu(dots), w)
+        blk = i * tile + jnp.arange(tile)
+        got = jnp.where(blk[None] < whole[:, None], got, -jnp.inf)
+        return lax.dynamic_update_slice(scores, got, (0, i * tile))
+    return lax.fori_loop(0, longest // (tile * kpool) + 1, body,
+                         jnp.full((s, n_max * tile), -jnp.inf, jnp.float32))
+
+
 @jax.named_scope("dsa_select")
-def select_step(spec: LatentSpec, scores: jax.Array
+def select_step(spec: LatentSpec, scores: jax.Array,
+                lengths: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, jax.Array]:
     """The ``index_topk`` positions of largest score a slot (ties to the
     lower position; every position while there are fewer): ``(positions
-    [S, K], which of them are real [S, K])``."""
-    k = min(spec.index_topk, scores.shape[1])
-    top, at = lax.top_k(scores, k)
-    return at.astype(jnp.int32), top > -jnp.inf
+    [S, K], which of them are real [S, K])``. Over pooled keys: the
+    positions of the ``index_blocks`` blocks of largest score and of the
+    block that holds the query (``lengths [S]``), up to the query's own."""
+    if spec.index_kpool == 1:
+        k = min(spec.index_topk, scores.shape[1])
+        top, at = lax.top_k(scores, k)
+        return at.astype(jnp.int32), top > -jnp.inf
+    kpool = spec.index_kpool
+    top, blk = lax.top_k(scores, min(spec.index_blocks, scores.shape[1]))
+    blk = jnp.concatenate([blk, (lengths // kpool)[:, None]], axis=1)
+    real = jnp.concatenate([top > -jnp.inf, jnp.ones_like(top[:, :1], bool)],
+                           axis=1)
+    at = (blk[..., None] * kpool + jnp.arange(kpool)).reshape(
+        blk.shape[0], -1).astype(jnp.int32)
+    real = jnp.repeat(real, kpool, axis=1) & (at <= lengths[:, None])
+    return at, real
 
 
 @jax.named_scope("mla_attend")
@@ -325,9 +427,10 @@ def attend_step(spec: LatentSpec, p, q_nope: jax.Array, q_rope: jax.Array,
                        w_kv[..., :spec.nope_dim],
                        preferred_element_type=jnp.float32)
     scores = jnp.einsum("shc,skc->shk", q_lat.astype(dtype), c,
-                        preferred_element_type=jnp.float32) \
-        + jnp.einsum("shr,skr->shk", q_rope.astype(dtype), r,
-                     preferred_element_type=jnp.float32)
+                        preferred_element_type=jnp.float32)
+    if spec.rope_dim:
+        scores = scores + jnp.einsum("shr,skr->shk", q_rope.astype(dtype), r,
+                                     preferred_element_type=jnp.float32)
     scores = jnp.where(real[:, None], scores, _NEG)
     top = jnp.max(scores, axis=-1, keepdims=True)
     probs = jnp.where(real[:, None], jnp.exp(scores - top), 0.0)
@@ -352,11 +455,17 @@ def _sortable(x: jax.Array) -> jax.Array:
 
 @jax.named_scope("dsa_index")
 def index_chunk(spec: LatentSpec, q: jax.Array, w: jax.Array,
-                index_pool: jax.Array, row: jax.Array, start) -> jax.Array:
+                index_pool: jax.Array, row: jax.Array, start,
+                dtype=None) -> jax.Array:
     """Index scores of the chunk's queries (positions ``start ..
     start+T-1``, their keys written) against the stream's positions so
     far, as sortable bits ``[T, L]`` uint32 (:func:`_sortable`; 0 where
-    ``s > t``). Tiles of pages up to the chunk's end."""
+    ``s > t``). Tiles of pages up to the chunk's end. Over pooled keys:
+    ``[T, L / index_kpool]``, the blocks wholly before each query
+    (``dtype``: the products' operands)."""
+    if spec.index_kpool > 1:
+        return _index_chunk_pooled(spec, q, w, index_pool, row, start,
+                                   dtype or q.dtype)
     t = q.shape[0]
     page_len = index_pool.shape[1]
     tp = _tile_pages(spec.chunk_tile, page_len, row.shape[0])
@@ -379,6 +488,30 @@ def index_chunk(spec: LatentSpec, q: jax.Array, w: jax.Array,
                          jnp.zeros((t, width), jnp.uint32))
 
 
+def _index_chunk_pooled(spec, q, w, index_pool, row, start, dtype):
+    t, kpool = q.shape[0], spec.index_kpool
+    per_page = index_pool.shape[1]
+    page_len = per_page * kpool
+    tp = _tile_pages(spec.chunk_tile, page_len, row.shape[0])
+    tile = tp * per_page
+    width = _bits_width(spec, page_len, row.shape[0]) // kpool
+    qc = q.astype(dtype)
+    whole = (start + jnp.arange(t)) // kpool
+
+    def body(i, bits):
+        keys = jnp.take(index_pool, _pages_of(row[None], i, tp)[0], axis=0,
+                        mode="clip").reshape(tile, -1) * (1.0 / kpool)
+        dots = jnp.einsum("thd,nd->thn", qc, keys.astype(dtype),
+                          preferred_element_type=jnp.float32)
+        got = jnp.einsum("thn,th->tn", jax.nn.relu(dots), w)
+        blk = i * tile + jnp.arange(tile)
+        got = jnp.where(blk[None] < whole[:, None], _sortable(got),
+                        jnp.uint32(0))
+        return lax.dynamic_update_slice(bits, got, (0, i * tile))
+    return lax.fori_loop(0, (start + t - 1) // (tile * kpool) + 1, body,
+                         jnp.zeros((t, width), jnp.uint32))
+
+
 @jax.named_scope("dsa_select")
 def select_chunk(spec: LatentSpec, bits: jax.Array, start
                  ) -> Tuple[jax.Array, jax.Array]:
@@ -390,13 +523,18 @@ def select_chunk(spec: LatentSpec, bits: jax.Array, start
     ``lax.top_k`` returns. Both come from 16-way searches, four bits a
     pass over the tiles of scores up to the chunk's end; a row that sees
     fewer than ``index_topk`` positions gets threshold 0, which selects
-    all it sees (the caller masks ``s > t``)."""
+    all it sees (the caller masks ``s > t``). Over pooled keys the same
+    of blocks, ``index_blocks`` of them."""
     t, width = bits.shape
     # whole tiles of the buffer, the widest of at most 8 scoring tiles
     tile = next(width // m for m in range(1, width + 1)
                 if width % m == 0 and width // m <= 8 * spec.chunk_tile)
-    n = (start + t - 1) // tile + 1
-    k = spec.index_topk
+    if spec.index_kpool > 1:
+        n = (start + t - 1) // spec.index_kpool // tile + 1
+        k = spec.index_blocks
+    else:
+        n = (start + t - 1) // tile + 1
+        k = spec.index_topk
     if width >= 1 << 16:
         raise ValueError(f"a context of {width} positions does not fit the "
                          f"selection's 16-bit search on the position")
@@ -630,6 +768,20 @@ def _selected(bits, pos, threshold, last, at_t):
     return ok & (pos[None] <= at_t[:, None])
 
 
+def _selected_pooled(spec, bits, first, threshold, last, at_t):
+    """:func:`_selected` over pooled keys: ``bits [T, n]`` of the blocks
+    from ``first`` on, the mask ``[T, n x index_kpool]`` of their
+    positions. A row selects the blocks :func:`select_chunk` chose among
+    those wholly before it and its own block, up to its own position."""
+    kpool = spec.index_kpool
+    blk = first + jnp.arange(bits.shape[1])
+    own = at_t // kpool
+    ok = _selected(bits, blk, threshold, last, own - 1) \
+        | (blk[None] == own[:, None])
+    pos = first * kpool + jnp.arange(bits.shape[1] * kpool)
+    return jnp.repeat(ok, kpool, axis=1) & (pos[None] <= at_t[:, None])
+
+
 @functools.partial(jax.jit, static_argnames=("spec", "blocks"))
 def _attend_chunk_kernel(spec, kv_b, q_nope, q_rope, latent_pool, row, start,
                          bits, threshold, last, blocks):
@@ -661,11 +813,16 @@ def _attend_chunk_kernel(spec, kv_b, q_nope, q_rope, latent_pool, row, start,
         wk = jnp.zeros((h, row_len, spec.qk_dim), w_kv.dtype)
         wk = wk.at[:, :spec.kv_rank, :spec.nope_dim].set(
             jnp.moveaxis(w_kv[..., :spec.nope_dim], 1, 0))
-        wk = wk.at[:, spec.kv_rank:spec.row, spec.nope_dim:].set(
-            jnp.eye(spec.rope_dim, dtype=w_kv.dtype))
+        if spec.rope_dim:
+            wk = wk.at[:, spec.kv_rank:spec.row, spec.nope_dim:].set(
+                jnp.eye(spec.rope_dim, dtype=w_kv.dtype))
         wv = w_kv[..., spec.nope_dim:].transpose(1, 2, 0)   # [H, v, rank]
-        ok = _selected(bits, jnp.arange(bits.shape[1]), threshold, last,
-                       start + jnp.arange(t))
+        if spec.index_kpool > 1:
+            ok = _selected_pooled(spec, bits, 0, threshold, last,
+                                  start + jnp.arange(t))
+        else:
+            ok = _selected(bits, jnp.arange(bits.shape[1]), threshold, last,
+                           start + jnp.arange(t))
         ok = ok.reshape(n_sub, sub, -1).transpose(0, 2, 1).astype(jnp.int8)
 
         def head(*shape):
@@ -718,7 +875,7 @@ def attend_chunk(spec: LatentSpec, p, q_nope: jax.Array, q_rope: jax.Array,
     if dispatch.on_tpu():
         rule = _chunk_kernel_rule(spec, q_nope.shape[0],
                                   latent_pool.shape[1], row.shape[0],
-                                  bits.shape[1])
+                                  bits.shape[1] * spec.index_kpool)
         if rule is None:
             return _attend_chunk_kernel(
                 spec, p["kv_b"], q_nope, q_rope, latent_pool, row, start,
@@ -754,8 +911,14 @@ def _attend_chunk_xla(spec, kv_b, q_nope, q_rope, latent_pool, row, start,
         k = jnp.concatenate(
             [kv[..., :spec.nope_dim],
              jnp.broadcast_to(r[None], (spec.heads,) + r.shape)], axis=-1)
-        ok = _selected(lax.dynamic_slice(bits, (0, i * tile), (t, tile)),
-                       i * tile + jnp.arange(tile), threshold, last, at_t)
+        if spec.index_kpool > 1:
+            blocks = tile // spec.index_kpool
+            ok = _selected_pooled(
+                spec, lax.dynamic_slice(bits, (0, i * blocks), (t, blocks)),
+                i * blocks, threshold, last, at_t)
+        else:
+            ok = _selected(lax.dynamic_slice(bits, (0, i * tile), (t, tile)),
+                           i * tile + jnp.arange(tile), threshold, last, at_t)
         o, new = _tile_attend_xla(q, k, kv[..., spec.nope_dim:], ok)
         both = jnp.logaddexp(lse, new)
         acc = acc * jnp.exp(lse - both)[..., None] \

@@ -20,8 +20,10 @@ The router is data (:func:`route`): ``"softmax"`` (the gates are the
 softmax over the chosen logits) or ``"sigmoid"`` (sigmoid scores, a
 correction bias that moves the choice and not the gate, the gates divided
 by their sum over the chosen and scaled). So is the experts' activation
-(ReLU or SiLU on the gate product), and a model may add a shared expert
-that every token reads (:func:`shared`)."""
+(ReLU or SiLU on the gate product), and so is a clamp of a gated-SiLU
+expert (:func:`swiglu`: ``silu(min(gate, L)) * clip(up, -L, L)`` where a
+model sets ``swiglu_limit`` ``L``); a model may add a shared expert that
+every token reads (:func:`shared`)."""
 from __future__ import annotations
 
 from typing import Optional, Sequence, Tuple
@@ -59,15 +61,24 @@ def route(x: jax.Array, w_r: jax.Array, k: int, scoring: str = "softmax",
     return choice.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
+def swiglu(gate: jax.Array, up: jax.Array, limit: float) -> jax.Array:
+    """``silu(min(gate, limit)) * clip(up, -limit, limit)``: a gated-SiLU
+    product whose gate is clamped from above and whose up projection is
+    clamped both ways (``swiglu_limit``)."""
+    return jax.nn.silu(jnp.minimum(gate, limit)) \
+        * jnp.clip(up, -limit, limit)
+
+
 @jax.named_scope("moe_experts")
 def experts(h: jax.Array, choice: jax.Array, gates: jax.Array,
             w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
             held: Optional[Sequence[int]] = None,
-            valid: Optional[jax.Array] = None, act=jax.nn.relu
-            ) -> Tuple[jax.Array, jax.Array]:
+            valid: Optional[jax.Array] = None, act=jax.nn.relu,
+            limit: float = 0.0) -> Tuple[jax.Array, jax.Array]:
     """``sum_j gates[t, j] W_down,e (act(W_gate,e h_t) * W_up,e h_t)``,
     ``e = choice[t, j]``, over the experts whose tables were handed in
-    (``act``: ReLU, or SiLU for a gated-SiLU expert).
+    (``act``: ReLU, or SiLU for a gated-SiLU expert, clamped by
+    :func:`swiglu` where ``limit`` is set).
 
     ``held`` names those experts in the tables' order (``None``: all, in
     order); an assignment to any other expert is left out, for the chip
@@ -92,7 +103,10 @@ def experts(h: jax.Array, choice: jax.Array, gates: jax.Array,
     def grouped(a, w):
         return lax.ragged_dot(a, w, sizes,
                               preferred_element_type=jnp.float32)
-    mid = act(grouped(rows, w_gate)) * grouped(rows, w_up)
+    if limit:
+        mid = swiglu(grouped(rows, w_gate), grouped(rows, w_up), limit)
+    else:
+        mid = act(grouped(rows, w_gate)) * grouped(rows, w_up)
     out = grouped(mid.astype(w_down.dtype), w_down)
     # rows past the last group belong to no expert held here
     kept = jnp.arange(t * k) < jnp.sum(sizes)
@@ -103,14 +117,18 @@ def experts(h: jax.Array, choice: jax.Array, gates: jax.Array,
 
 @jax.named_scope("moe_shared")
 def shared(h: jax.Array, w_gate: jax.Array, w_up: jax.Array,
-           w_down: jax.Array) -> jax.Array:
+           w_down: jax.Array, limit: float = 0.0) -> jax.Array:
     """The shared expert that every token reads: a plain gated-SiLU
-    product ``W_down (silu(W_gate h) * W_up h)`` of ``h [T, d]``, float32
-    accumulation. Every chip of an expert-parallel layer computes it alike,
-    so the shares of the routed experts add up with it counted once."""
+    product ``W_down (silu(W_gate h) * W_up h)`` of ``h [T, d]`` (clamped
+    by :func:`swiglu` where ``limit`` is set), float32 accumulation. Every
+    chip of an expert-parallel layer computes it alike, so the shares of
+    the routed experts add up with it counted once."""
     def product(a, w):
         return jnp.dot(a.astype(w.dtype), w,
                        preferred_element_type=jnp.float32)
+    if limit:
+        return product(swiglu(product(h, w_gate), product(h, w_up), limit),
+                       w_down)
     return product(jax.nn.silu(product(h, w_gate)) * product(h, w_up),
                    w_down)
 

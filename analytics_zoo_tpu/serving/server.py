@@ -198,6 +198,12 @@ _M_DSA_SCORED = _metrics.histogram(
     "one decode step (the stream's context), the mean over the active "
     "slots and the layers, as the step program counted them: one "
     "observation a step.", labels=("server",))
+_M_DSA_BLOCKS = _metrics.histogram(
+    "serving.dsa_blocks_scored",
+    "Pooled key blocks that a latent layer's indexer scored for one live "
+    "stream in one decode step (the blocks wholly before its query), the "
+    "mean over the active slots and the layers, as the step program "
+    "counted them: one observation a step.", labels=("server",))
 _M_PAGES_READ = _metrics.histogram(
     "serving.paged_pages_read",
     "KV pages that the decode step's attention read for all slots in one "
@@ -1874,6 +1880,12 @@ class GenerativeServing:
         self._m_window_released = _M_WINDOW_RELEASED.labels(
             server=self.metrics_label)
         self._m_dsa_scored = _M_DSA_SCORED.labels(server=self.metrics_label)
+        # a model without pooled index keys takes no slots of the
+        # registry for it
+        self._m_dsa_blocks = (
+            _M_DSA_BLOCKS.labels(server=self.metrics_label)
+            if "dsa_blocks_scored" in getattr(lm, "step_stats", ())
+            else None)
         self._m_moe_touched = _M_MOE_TOUCHED.labels(
             server=self.metrics_label)
         self._m_moe_load = _M_MOE_LOAD.labels(server=self.metrics_label)
@@ -1884,6 +1896,8 @@ class GenerativeServing:
         self._step_observers = {
             "sparse_positions_read": self._m_sparse_read.observe,
             "dsa_positions_scored": self._m_dsa_scored.observe,
+            "dsa_blocks_scored": getattr(self._m_dsa_blocks, "observe",
+                                         None),
             "moe_experts_touched": self._m_moe_touched.observe,
             "moe_expert_load": self._m_moe_load.observe,
             "moe_assignments": self._m_moe_assignments.inc}
@@ -3280,6 +3294,9 @@ class GenerativeServing:
                 if self._recurrent else None),
             "sparse_positions_read": _mean_of(self._m_sparse_read),
             "dsa_positions_scored": _mean_of(self._m_dsa_scored),
+            "dsa_blocks_scored": (_mean_of(self._m_dsa_blocks)
+                                  if self._m_dsa_blocks is not None
+                                  else None),
             "kv_pages_in_use": {
                 "full": self.num_pages - 1 - len(self._free_pages),
                 "window": (self._window.in_use()

@@ -738,14 +738,21 @@ _LOWERED = {
     "smallthinker.chunk_32": "cc3bb262807dcd68",
     "sala.step": "97f003074592f864",
     "sala.chunk_8": "6fcd59c24b75e7b5",
-    "sala.chunk_32": "ac903f110dd545a1"}
+    "sala.chunk_32": "ac903f110dd545a1",
+    # GLM-5's, hashed at commit 180eaaa, before GLM-5.3-Flash's
+    # layers came into the same files
+    "glm5.step": "ca5690f0c1b1b239",
+    "glm5.chunk_8": "23cac81076fbef67",
+    "glm5.chunk_32": "a2b1056d8ccfeab0"}
 
 
-@pytest.mark.parametrize("model", ["smallthinker", "sala"])
+@pytest.mark.parametrize("model", ["smallthinker", "sala", "glm5"])
 def test_older_decoders_lower_to_the_programs_they_were(tmp_path, model):
     """MiniCPM-SALA's and SmallThinker's decode step and chunk programs, at a
     tiny size on the CPU, lower to the text they lowered to before GLM-5's
-    layers came into ``capture/decoder.py`` and ``ops/moe.py`` (PR 33): the
+    layers came into ``capture/decoder.py`` and ``ops/moe.py``, and
+    GLM-5's to theirs before GLM-5.3-Flash's layers came into those files
+    and ``ops/latent_attention.py``: the
     text with its scope names is the compile cache's key
     (``common/context.py wire_compilation_cache`` keeps source lines out of
     it), so equal text means that neither model's cell compiles anything
@@ -770,6 +777,19 @@ def test_older_decoders_lower_to_the_programs_they_were(tmp_path, model):
             sliding_window_size=32, num_hidden_layers=4,
             sliding_window_layout=[0, 1, 1, 1], rope_layout=[0, 1, 1, 1],
             n_positions=128, param_dtype="float32")
+        spec = DecoderSpec.from_config(cfg, 128, page_len=8)
+    elif model == "glm5":
+        cfg = json.loads((root / "perfbench" / "configs"
+                          / "glm_5.json").read_text())
+        cfg.update(
+            vocab_size=97, hidden_size=64, intermediate_size=96,
+            moe_intermediate_size=32, num_attention_heads=4,
+            num_key_value_heads=4, q_lora_rank=32, kv_lora_rank=16,
+            qk_nope_head_dim=12, qk_rope_head_dim=4, qk_head_dim=16,
+            v_head_dim=16, index_n_heads=2, index_head_dim=8, index_topk=16,
+            n_routed_experts=16, n_routed_experts_published=16,
+            held_experts=None, num_experts_per_tok=4, num_hidden_layers=4,
+            first_k_dense_replace=1, n_positions=128, param_dtype="float32")
         spec = DecoderSpec.from_config(cfg, 128, page_len=8)
     else:
         cfg = json.loads((root / "perfbench" / "configs"
